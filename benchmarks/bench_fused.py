@@ -14,11 +14,13 @@ reports:
   ledgered ``fused_writeback_saved`` bytes — the intermediate activation
   round trip the fusion eliminated;
 * measured latency through the jitted forward (what serving runs);
-* bitwise equality of the fused output vs the unfused one at the same
-  precision (the fused kernel's parity contract, not an approximation).
+* parity of the fused output with the unfused one at the same
+  precision: bitwise at bf16/int8, within ``F32_REL_TOL`` of the
+  output's largest magnitude at f32 (the two paths block the f32
+  combination sum differently; see ``tests/test_fused.py``).
 
 ``--check`` gates the fusion claim: fused ledger DRAM < 0.8x unfused on
-every case at f32, outputs bitwise-identical at every precision, and
+every case at f32, outputs at parity at every precision, and
 every fused layer ledgered an explicit 0-byte activation writeback
 record.  Writes the standard BENCH json to
 ``results/bench/fused_layers.json`` (``REPRO_BENCH_DIR`` to relocate).
@@ -33,12 +35,22 @@ import os
 
 BENCH_DIR = os.environ.get("REPRO_BENCH_DIR", "results/bench")
 FUSED_DRAM_GATE = 0.8         # fused bytes must be < gate * unfused bytes
+F32_REL_TOL = 1e-5            # f32 parity bound, fraction of max |output|
 
 #              name       n    nnz   alpha  tau  fdim
 SMOKE_CASES = [("skewed", 256, 2_000, 2.5, 4, 32)]
 FULL_CASES = SMOKE_CASES + [("skewed-large", 512, 8_000, 2.5, 6, 64)]
 
 PRECISIONS = ("f32", "bf16", "int8")
+
+
+def _matches(a, b, precision: str) -> bool:
+    import numpy as np
+
+    if precision == "f32":
+        return bool(np.allclose(a, b, rtol=0,
+                                atol=F32_REL_TOL * np.abs(b).max()))
+    return bool(np.array_equal(a, b))
 
 
 def _bench_records(smoke: bool):
@@ -91,7 +103,7 @@ def _bench_records(smoke: bool):
                 fwd = jax.jit(lambda p, f, _pl=plan: gcn_forward(
                     p, graph, f, cfg, plan=_pl))
                 out = np.asarray(fwd(params, feats))     # warm/compile
-                assert np.array_equal(out, eager), \
+                assert _matches(out, eager, precision), \
                     "jitted forward diverged from eager"
                 t0 = time.perf_counter()
                 reps = 5
@@ -103,7 +115,7 @@ def _bench_records(smoke: bool):
                 row[f"{mode}_time_us"] = round(us, 1)
             row["dram_ratio"] = round(
                 row["fused_dram_bytes"] / row["unfused_dram_bytes"], 4)
-            row["bitwise"] = bool(np.array_equal(outs[True], outs[False]))
+            row["parity"] = _matches(outs[True], outs[False], precision)
             records.append(row)
     return records
 
@@ -113,8 +125,8 @@ def _gate(records) -> None:
     problems = []
     for r in records:
         tag = f"{r['case']}/{r['precision']}"
-        if not r["bitwise"]:
-            problems.append(f"{tag}: fused output not bitwise vs unfused")
+        if not r["parity"]:
+            problems.append(f"{tag}: fused output differs from unfused")
         if r["precision"] == "f32" and r["dram_ratio"] >= FUSED_DRAM_GATE:
             problems.append(
                 f"{tag}: fused DRAM ratio {r['dram_ratio']:.3f} >= "
@@ -134,13 +146,13 @@ def _gate(records) -> None:
 def run(csv=print, smoke: bool = True, check: bool = False,
         json_path: str | None = None) -> dict:
     csv("case,precision,unfused_dram,fused_dram,dram_ratio,"
-        "unfused_us,fused_us,bitwise")
+        "unfused_us,fused_us,parity")
     records = _bench_records(smoke)
     for r in records:
         csv(f"{r['case']},{r['precision']},{r['unfused_dram_bytes']},"
             f"{r['fused_dram_bytes']},{r['dram_ratio']:.3f},"
             f"{r['unfused_time_us']:.0f},{r['fused_time_us']:.0f},"
-            f"{int(r['bitwise'])}")
+            f"{int(r['parity'])}")
     payload = {"benchmark": "fused_layers", "smoke": smoke,
                "fused_dram_gate": FUSED_DRAM_GATE,
                "records": records}
@@ -160,7 +172,7 @@ def main() -> None:
     ap.add_argument("--check", action="store_true",
                     help="fail unless fused DRAM < "
                          f"{FUSED_DRAM_GATE}x unfused at f32 and fused "
-                         "outputs are bitwise-identical at every precision")
+                         "outputs are at parity at every precision")
     ap.add_argument("--json",
                     default=os.path.join(BENCH_DIR, "fused_layers.json"))
     args = ap.parse_args()

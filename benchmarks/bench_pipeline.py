@@ -27,11 +27,16 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmarks.cpu_child import (  # noqa: E402
+    print_device_line,
+    run_child,
+)
+
 BENCH_DIR = os.environ.get("REPRO_BENCH_DIR", "results/bench")
-N_VIRTUAL_DEVICES = 8
 DEVICE_COUNTS = (1, 2, 4)
 
 # (n, nnz, tau, hidden, out) — hidden >> out: the canonical GCN funnel
@@ -120,6 +125,7 @@ def _bench_records(smoke: bool):
 
 
 def _child_main(args) -> None:
+    print_device_line()
     records = _bench_records(args.smoke)
     os.makedirs(os.path.dirname(args.json), exist_ok=True)
     with open(args.json, "w") as f:
@@ -140,25 +146,7 @@ def run(csv=print, smoke: bool = True) -> dict:
     csv("case,n_devices,full_all_reduces,pipe_coll_bytes,base_coll_bytes,"
         "pipe_dram_bytes,base_dram_bytes,bitwise,ok")
     json_path = os.path.join(BENCH_DIR, "pipeline.json")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "") +
-        f" --xla_force_host_platform_device_count={N_VIRTUAL_DEVICES}"
-    ).strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    src = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    cmd = [sys.executable, os.path.abspath(__file__), "--child",
-           "--json", json_path, "--smoke" if smoke else "--full"]
-    r = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                       timeout=1800)
-    for line in (r.stdout or "").strip().splitlines():
-        csv(line)
-    if r.returncode != 0:
-        tail = (r.stderr or "").strip().splitlines()[-3:]
-        raise RuntimeError(
-            f"pipeline bench child failed: {' | '.join(tail)}")
+    run_child(__file__, json_path, smoke, csv, "pipeline")
     with open(json_path) as f:
         return json.load(f)
 

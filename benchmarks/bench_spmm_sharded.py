@@ -19,11 +19,16 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmarks.cpu_child import (  # noqa: E402
+    print_device_line,
+    run_child,
+)
+
 BENCH_DIR = os.environ.get("REPRO_BENCH_DIR", "results/bench")
-N_VIRTUAL_DEVICES = 8
 IMPLS = ("reference", "pallas", "pallas_sparse")
 DEVICE_COUNTS = (1, 2, 4)
 
@@ -89,6 +94,7 @@ def _bench_records(smoke: bool):
 
 
 def _child_main(args) -> None:
+    print_device_line()
     records = _bench_records(args.smoke)
     os.makedirs(os.path.dirname(args.json), exist_ok=True)
     with open(args.json, "w") as f:
@@ -106,25 +112,7 @@ def run(csv=print, smoke: bool = True) -> dict:
     """Spawn the multi-device child and emit its CSV block."""
     csv("case,impl,n_devices,us,max_abs_err_vs_reference,ok")
     json_path = os.path.join(BENCH_DIR, "spmm_sharded.json")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "") +
-        f" --xla_force_host_platform_device_count={N_VIRTUAL_DEVICES}"
-    ).strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    src = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    cmd = [sys.executable, os.path.abspath(__file__), "--child",
-           "--json", json_path, "--smoke" if smoke else "--full"]
-    r = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                       timeout=1800)
-    for line in (r.stdout or "").strip().splitlines():
-        csv(line)
-    if r.returncode != 0:
-        tail = (r.stderr or "").strip().splitlines()[-3:]
-        raise RuntimeError(
-            f"sharded bench child failed: {' | '.join(tail)}")
+    run_child(__file__, json_path, smoke, csv, "sharded")
     with open(json_path) as f:
         return json.load(f)
 

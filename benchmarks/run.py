@@ -16,8 +16,15 @@ The same run also exports a unified telemetry snapshot through
 counters land in ``BENCH_metrics.json`` and (Prometheus text format)
 ``BENCH_metrics.prom`` beside the summary, written even when a bench
 fails so a broken run still leaves its telemetry behind.
+
+The three benches that need several devices (sharded SpMM, autoplan,
+pipeline) run first, each in a child process forced onto the CPU's
+virtual devices (``benchmarks/cpu_child.py``).  Bench modules are
+imported one at a time, so the parent has not imported JAX when it
+starts those children, and only then turns on the compile cache.
 """
 
+import importlib
 import json
 import os
 import sys
@@ -25,27 +32,30 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks import (  # noqa: E402
-    bench_ablation,
-    bench_area,
-    bench_buffer_sizes,
-    bench_fleet,
-    bench_flexible_k,
-    bench_fused,
-    bench_pipeline,
-    bench_plan,
-    bench_quant,
-    bench_queue,
-    bench_serve,
-    bench_spmm_kernel,
-    bench_spmm_sharded,
-    bench_vlen_depth,
-)
-
 BENCH_DIR = os.environ.get("REPRO_BENCH_DIR", "results/bench")
 SUMMARY_PATH = os.path.join(BENCH_DIR, "BENCH_summary.json")
 METRICS_JSON_PATH = os.path.join(BENCH_DIR, "BENCH_metrics.json")
 METRICS_PROM_PATH = os.path.join(BENCH_DIR, "BENCH_metrics.prom")
+
+# (title, module under benchmarks/), in run order.
+CHILD_BENCHES = [
+    ("SpMM sharded (1 vs N devices)", "bench_spmm_sharded"),
+    ("Autoplan vs static plan", "bench_plan"),
+    ("Pipelined multi-layer forward (sharded activations)", "bench_pipeline"),
+]
+IN_PROCESS_BENCHES = [
+    ("Fig 9 (area)", "bench_area"),
+    ("Fig 10 (ablation)", "bench_ablation"),
+    ("Fig 11 (flexible k)", "bench_flexible_k"),
+    ("Fig 12 (buffer sizes)", "bench_buffer_sizes"),
+    ("Fig 13 (VLEN/depth)", "bench_vlen_depth"),
+    ("SpMM kernel", "bench_spmm_kernel"),
+    ("Fused combination+aggregation layers", "bench_fused"),
+    ("Quantized serving (f32/bf16/int8)", "bench_quant"),
+    ("Serving engine", "bench_serve"),
+    ("Async queue (open-loop Poisson)", "bench_queue"),
+    ("Fleet (multi-tenant hot/cold isolation)", "bench_fleet"),
+]
 
 
 def export_metrics(registry,
@@ -99,25 +109,14 @@ def main() -> None:
     print(f"# datasets: {os.environ.get('REPRO_DATASETS', 'all five')}")
     metrics = MetricsRegistry()
     records = []
-    for name, mod in [
-        ("Fig 9 (area)", bench_area),
-        ("Fig 10 (ablation)", bench_ablation),
-        ("Fig 11 (flexible k)", bench_flexible_k),
-        ("Fig 12 (buffer sizes)", bench_buffer_sizes),
-        ("Fig 13 (VLEN/depth)", bench_vlen_depth),
-        ("SpMM kernel", bench_spmm_kernel),
-        ("SpMM sharded (1 vs N devices)", bench_spmm_sharded),
-        ("Autoplan vs static plan", bench_plan),
-        ("Pipelined multi-layer forward (sharded activations)", bench_pipeline),
-        ("Fused combination+aggregation layers", bench_fused),
-        ("Quantized serving (f32/bf16/int8)", bench_quant),
-        ("Serving engine", bench_serve),
-        ("Async queue (open-loop Poisson)", bench_queue),
-        ("Fleet (multi-tenant hot/cold isolation)", bench_fleet),
-    ]:
+    for name, bench in CHILD_BENCHES + IN_PROCESS_BENCHES:
+        if bench == IN_PROCESS_BENCHES[0][1]:
+            from repro.serve.cache import enable_compile_cache
+
+            print(f"# compile cache: {enable_compile_cache()}")
+        mod = importlib.import_module(f"benchmarks.{bench}")
         print(f"\n## {name}")
         t = time.time()
-        bench = mod.__name__.split(".")[-1]
         rec = {"run_at": run_at, "bench": bench, "title": name}
         try:
             rec["summary"] = _jsonable(mod.run())

@@ -77,6 +77,16 @@ def select_spec(mesh, shape: Sequence[int], specs: Sequence[Spec]):
     return None
 
 
+def _place(x: jax.Array, mesh, spec) -> jax.Array:
+    """Apply ``spec`` on ``mesh``: a constraint on Auto axes, a reshard
+    on Explicit ones (``jax.make_mesh`` makes Explicit axes by default,
+    and ``with_sharding_constraint`` refuses them)."""
+    sharding = NamedSharding(mesh, spec)
+    if jax.sharding.AxisType.Explicit in tuple(mesh.axis_types):
+        return jax.sharding.reshard(x, sharding)
+    return jax.lax.with_sharding_constraint(x, sharding)
+
+
 def constrain(x: jax.Array, specs: Sequence[Spec]) -> jax.Array:
     """Constrain ``x`` to the first viable candidate spec, if any."""
     mesh = active_mesh()
@@ -85,7 +95,7 @@ def constrain(x: jax.Array, specs: Sequence[Spec]) -> jax.Array:
     spec = select_spec(mesh, x.shape, specs)
     if spec is None:
         return x
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    return _place(x, mesh, spec)
 
 
 def constrain_ranked(x: jax.Array, specs: Sequence[Spec]) -> jax.Array:
@@ -111,5 +121,4 @@ def constrain_ranked(x: jax.Array, specs: Sequence[Spec]) -> jax.Array:
 
     spec = viable[rank_specs(
         mesh, x.shape, viable, dtype_bytes=x.dtype.itemsize)]
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(*spec)))
+    return _place(x, mesh, P(*spec))

@@ -33,14 +33,5 @@ def viable_mesh_shapes(n_chips: int,
 
 def abstract_mesh(axis_sizes: Sequence[int],
                   axis_names: Sequence[str]) -> jax.sharding.AbstractMesh:
-    """Device-free mesh for shape/sharding planning, across jax versions.
-
-    jax <= 0.4.x spells it ``AbstractMesh((("data", 4), ...))``, newer
-    releases ``AbstractMesh((4, ...), ("data", ...))``.
-    """
-    try:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_sizes)))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(axis_sizes), tuple(axis_names))
+    """Device-free mesh for shape/sharding planning."""
+    return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))

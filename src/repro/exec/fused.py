@@ -14,14 +14,17 @@ activation never exists in DRAM; the ledger records an explicit 0-byte
 writeback (`CollectiveLedger.record_fused_writeback`) so fused and
 unfused runs stay count-comparable.
 
-Parity contract: for every impl and storage precision the fused path is
-*bitwise identical* to the unfused two-launch path.  The in-kernel
-combination replicates ``exec.quant.affine`` per k-tile (pre-cast bf16
-inputs, f32 accumulate, f32 bias add, storage-dtype round-trip), the
-per-row-block aggregation dots have exactly the unfused kernels' shapes,
-and the fused sparse schedule visits k-tiles in the same global
-hot-first order the unfused sparse grid applies per row block — each row
-block's accumulation sequence is preserved element-for-element.
+Parity contract: for every impl the fused path equals the unfused
+two-launch path, bitwise at bf16 and int8 storage and to f32 rounding at
+f32.  The in-kernel combination replicates ``exec.quant.affine`` per
+k-tile (pre-cast bf16 inputs, f32 accumulate, f32 bias add,
+storage-dtype round-trip), the per-row-block aggregation dots have
+exactly the unfused kernels' shapes, and the fused sparse schedule visits
+k-tiles in the same global hot-first order the unfused sparse grid
+applies per row block.  What differs is the combination's shape — one
+``(block_k, F_in)`` tile against one XLA dot over all of ``X`` — and the
+compiler blocks an f32 contraction by shape, so f32 sums round
+differently (``tests/test_fused.py`` states the bound).
 
 Routing lives in ``exec.dispatch.execute_layer``: a resolved plan with
 ``fused=True`` and a pallas impl lands here; the reference impl and
@@ -284,7 +287,6 @@ def _execute_fused_sharded(
     The segment-psum / segment-reduce-scatter epilogues are exactly those
     of ``exec.sharded.execute_sharded``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels import flexvector_spmm as fv
@@ -413,13 +415,13 @@ def _execute_fused_sharded(
             )[:, :f_out]
             return epilogue(sub, m)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis)) + sc_specs
             + (P(axis), x_spec, P(None, None), P(None, None)),
             out_specs=out_spec,
-            check_rep=False,
+            check_vma=False,
         )
         return fn(
             jnp.asarray(kb_ids), cols, vals, *sc_args, rmap, x_eff, w_eff, b2
@@ -433,13 +435,13 @@ def _execute_fused_sharded(
         )[:, :f_out]
         return epilogue(sub, m)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), P(axis)) + sc_specs
         + (P(axis), x_spec, P(None, None), P(None, None)),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(cols, vals, *sc_args, rmap, x_eff, w_eff, b2)
 

@@ -53,7 +53,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.collectives import (
@@ -222,13 +221,13 @@ def execute_sharded(
                 v = v.astype(jnp.float32)  # f32 accumulation, as the kernels
             return epilogue(_sub_row_products_ref(c, v, prologue(d)), m)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(row_spec), P(row_spec)) + sc_specs
             + (P(row_spec), dense_spec),
             out_specs=out_spec,
-            check_rep=False,  # psum replicates; pallas has no rep rule anyway
+            check_vma=False,  # psum replicates; pallas has no vma rule anyway
         )
         return fn(cols, vals, *sc_args, rmap, dense)[:, :f]
 
@@ -255,13 +254,13 @@ def execute_sharded(
             )[:r_loc, :f_local]
             return epilogue(sub, m)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(row_spec), P(row_spec)) + sc_specs
             + (P(row_spec), dense_spec),
             out_specs=out_spec,
-            check_rep=False,
+            check_vma=False,
         )
         return fn(cols, vals, *sc_args, rmap, dense)[:, :f]
 
@@ -306,13 +305,13 @@ def execute_sharded(
         )[:r_loc, :f_local]
         return epilogue(sub, m)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(row_spec), P(row_spec), P(row_spec), P(row_spec),
                   P(row_spec)) + sc_specs + (P(row_spec), dense_spec),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(
         jnp.asarray(rb), jnp.asarray(kb), jnp.asarray(first), cols, vals,

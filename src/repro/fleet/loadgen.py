@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from concurrent.futures import TimeoutError as FutureTimeoutError
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -83,6 +84,8 @@ def run_open_loop_mix(
     for req in pending:
         try:
             req.future.result(timeout=result_timeout_s)
+        except FutureTimeoutError:
+            raise             # never resolved: counted nowhere, so surface it
         except Exception:
             pass              # shed while queued / failed; also counted
     return rt.clock.now() - t_start

@@ -23,10 +23,12 @@ Two launch schedules:
   ordered first within each row block (``hot_k_first``) so high-reuse dense
   tiles stay VMEM-resident — the VRF fixed region, at tile granularity.
 
-VMEM budget per grid step (dtype bytes b): BR*tau*(4+b) sparse table +
-BK*BF*b dense tile + BR*BF*4 accumulator + BR*BK*4 scratch.  The defaults
-(BR=BK=BF=128, tau<=16, f32) total ~200 KiB, comfortably inside the 16 MiB
-VMEM of a v5e core with double buffering.
+VMEM budget per grid step (dtype bytes b): BR*128*(4+b) sparse table
+(the tau lanes pad to 128) + BK*BF*b dense tile + BR*BF*4 accumulator +
+BR*BK*4 scratch.  The defaults (BR=BK=BF=128, f32) total about 0.5 MiB
+with double-buffered inputs, well inside the 16 MiB of scoped VMEM a v5e
+kernel gets by default.  The fused kernels hold a whole (R, BF) slab
+instead; ``plan.cost.fused_vmem_bytes`` counts it.
 """
 
 from __future__ import annotations
@@ -62,8 +64,21 @@ def _expand_block(cols, vals, kb_base, block_k, acc_dtype):
     return a_blk
 
 
-def _dense_grid_kernel(cols_ref, vals_ref, dense_ref, out_ref, *, block_k):
-    kb = pl.program_id(2)
+def _split_scales(refs, scaled):
+    """``(scales_ref or None, other refs)`` from a kernel's ref list, where
+    an int8 launch passes the SMEM scale vector as the third input."""
+    if not scaled:
+        return None, refs
+    return refs[2], refs[:2] + refs[3:]
+
+
+def _dense_grid_kernel(*refs, block_k, scaled):
+    """Masked full-grid step; with ``scaled`` the int8 values are widened
+    by ``_expand_block`` and multiplied by their row block's scale (read
+    from SMEM) before the MXU, so int8 lives only on the DRAM->VMEM path."""
+    scales_ref, (cols_ref, vals_ref, dense_ref, out_ref) = _split_scales(
+        refs, scaled)
+    rb, kb = pl.program_id(1), pl.program_id(2)
 
     @pl.when(kb == 0)
     def _init():
@@ -73,6 +88,8 @@ def _dense_grid_kernel(cols_ref, vals_ref, dense_ref, out_ref, *, block_k):
     a_blk = _expand_block(
         cols_ref[...], vals_ref[...], kb * block_k, block_k, acc
     )
+    if scales_ref is not None:
+        a_blk = a_blk * scales_ref[rb].astype(acc)
     out_ref[...] += jax.lax.dot_general(
         a_blk,
         dense_ref[...].astype(acc),
@@ -81,46 +98,32 @@ def _dense_grid_kernel(cols_ref, vals_ref, dense_ref, out_ref, *, block_k):
     )
 
 
-def _dense_grid_kernel_scaled(
-    cols_ref, vals_ref, scales_ref, dense_ref, out_ref, *, block_k
-):
-    """Dense-grid kernel over int8 values: dequantize on load.
-
-    ``scales_ref`` is the (1, 1) per-row-block scale slab; the expanded
-    block is widened to the f32 accumulator dtype by ``_expand_block``
-    and multiplied by its block scale before hitting the MXU, so int8
-    lives only on the DRAM->VMEM path.
-    """
-    kb = pl.program_id(2)
-
-    @pl.when(kb == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    acc = _acc_dtype(out_ref.dtype)
-    a_blk = _expand_block(
-        cols_ref[...], vals_ref[...], kb * block_k, block_k, acc
-    )
-    a_blk = a_blk * scales_ref[0, 0].astype(acc)
-    out_ref[...] += jax.lax.dot_general(
-        a_blk,
-        dense_ref[...].astype(acc),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=out_ref.dtype,
-    )
-
-
-def _block_scales_2d(scales, r: int, block_rows: int) -> jax.Array:
-    """Shape per-row-block scales for the kernel: (r // block_rows, 1) f32.
+def _block_scales(scales, r: int, block_rows: int) -> jax.Array:
+    """Per-row-block scales for the kernel: ``(r // block_rows,)`` f32.
 
     Pads with 1.0 for trailing all-padding row blocks (their values are
-    zero, so the scale is immaterial but must exist for the BlockSpec).
+    zero, so the scale is immaterial but must exist).  The vector rides in
+    SMEM whole: a ``(1, 1)`` VMEM block of it would break the TPU's
+    (8, 128) tiling rule.
     """
     n_rb = r // block_rows
     s = jnp.asarray(scales, jnp.float32).reshape(-1)
     if s.shape[0] < n_rb:
         s = jnp.pad(s, ((0, n_rb - s.shape[0]),), constant_values=1.0)
-    return s[:n_rb].reshape(n_rb, 1)
+    return s[:n_rb]
+
+
+_SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _with_scales(in_specs, args, scales, r, block_rows):
+    """Insert the SMEM scale vector as the third kernel input (int8)."""
+    if scales is None:
+        return in_specs, args
+    return (
+        in_specs[:2] + [_SMEM_SPEC] + in_specs[2:],
+        args[:2] + (_block_scales(scales, r, block_rows),) + args[2:],
+    )
 
 
 def spmm_ell_dense_grid(
@@ -146,40 +149,30 @@ def spmm_ell_dense_grid(
     if r % block_rows or k % block_k or f % block_f:
         raise ValueError("operands must be padded to block multiples")
     out_dtype = out_dtype or _acc_dtype(dense.dtype)
-    interpret = _default_interpret(interpret)
-    grid = (f // block_f, r // block_rows, k // block_k)
-    out_shape = jax.ShapeDtypeStruct((r, f), out_dtype)
-    out_specs = pl.BlockSpec((block_rows, block_f), lambda fi, rb, kb: (rb, fi))
     ell_spec = pl.BlockSpec((block_rows, tau), lambda fi, rb, kb: (rb, 0))
     dense_spec = pl.BlockSpec((block_k, block_f), lambda fi, rb, kb: (kb, fi))
-    if scales is None:
-        return pl.pallas_call(
-            functools.partial(_dense_grid_kernel, block_k=block_k),
-            grid=grid,
-            in_specs=[ell_spec, ell_spec, dense_spec],
-            out_specs=out_specs,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(cols, vals, dense)
+    in_specs, args = _with_scales(
+        [ell_spec, ell_spec, dense_spec], (cols, vals, dense), scales, r,
+        block_rows,
+    )
     return pl.pallas_call(
-        functools.partial(_dense_grid_kernel_scaled, block_k=block_k),
-        grid=grid,
-        in_specs=[
-            ell_spec,
-            ell_spec,
-            pl.BlockSpec((1, 1), lambda fi, rb, kb: (rb, 0)),
-            dense_spec,
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(cols, vals, _block_scales_2d(scales, r, block_rows), dense)
+        functools.partial(
+            _dense_grid_kernel, block_k=block_k, scaled=scales is not None
+        ),
+        grid=(f // block_f, r // block_rows, k // block_k),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec(
+            (block_rows, block_f), lambda fi, rb, kb: (rb, fi)
+        ),
+        out_shape=jax.ShapeDtypeStruct((r, f), out_dtype),
+        interpret=_default_interpret(interpret),
+    )(*args)
 
 
-def _sparse_grid_kernel(
-    rb_ids_ref, kb_ids_ref, first_ref, cols_ref, vals_ref, dense_ref, out_ref,
-    *, block_k,
-):
+def _sparse_grid_kernel(rb_ids_ref, kb_ids_ref, first_ref, *refs, block_k,
+                        scaled):
+    scales_ref, (cols_ref, vals_ref, dense_ref, out_ref) = _split_scales(
+        refs, scaled)
     s = pl.program_id(1)
 
     @pl.when(first_ref[s] == 1)
@@ -190,29 +183,8 @@ def _sparse_grid_kernel(
     a_blk = _expand_block(
         cols_ref[...], vals_ref[...], kb_ids_ref[s] * block_k, block_k, acc
     )
-    out_ref[...] += jax.lax.dot_general(
-        a_blk,
-        dense_ref[...].astype(acc),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=out_ref.dtype,
-    )
-
-
-def _sparse_grid_kernel_scaled(
-    rb_ids_ref, kb_ids_ref, first_ref, cols_ref, vals_ref, scales_ref,
-    dense_ref, out_ref, *, block_k,
-):
-    s = pl.program_id(1)
-
-    @pl.when(first_ref[s] == 1)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    acc = _acc_dtype(out_ref.dtype)
-    a_blk = _expand_block(
-        cols_ref[...], vals_ref[...], kb_ids_ref[s] * block_k, block_k, acc
-    )
-    a_blk = a_blk * scales_ref[0, 0].astype(acc)
+    if scales_ref is not None:
+        a_blk = a_blk * scales_ref[rb_ids_ref[s]].astype(acc)
     out_ref[...] += jax.lax.dot_general(
         a_blk,
         dense_ref[...].astype(acc),
@@ -242,58 +214,41 @@ def spmm_ell_sparse_grid(
     consecutive (``plan_kernel_grid`` guarantees it) so the output block is
     revisited contiguously while it stays resident in VMEM.  ``scales``
     enables int8 dequantize-on-load, as in :func:`spmm_ell_dense_grid`.
+    The three prefetched lists live in SMEM, which bounds ``n_steps``
+    (about 40,000 steps fit a v5e core's 1 MiB SMEM; 400,000 do not).
     """
     r, tau = cols.shape
     k, f = dense.shape
     if r % block_rows or k % block_k or f % block_f:
         raise ValueError("operands must be padded to block multiples")
     out_dtype = out_dtype or _acc_dtype(dense.dtype)
-    interpret = _default_interpret(interpret)
     n_steps = int(rb_ids.shape[0])
-    grid = (f // block_f, n_steps)
     ell_spec = pl.BlockSpec(
         (block_rows, tau), lambda fi, s, rb, kb, fs: (rb[s], 0)
     )
     dense_spec = pl.BlockSpec(
         (block_k, block_f), lambda fi, s, rb, kb, fs: (kb[s], fi)
     )
-    out_specs = pl.BlockSpec(
-        (block_rows, block_f), lambda fi, s, rb, kb, fs: (rb[s], fi)
+    in_specs, args = _with_scales(
+        [ell_spec, ell_spec, dense_spec], (cols, vals, dense), scales, r,
+        block_rows,
     )
-    out_shape = jax.ShapeDtypeStruct((r, f), out_dtype)
-    if scales is None:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[ell_spec, ell_spec, dense_spec],
-            out_specs=out_specs,
-        )
-        return pl.pallas_call(
-            functools.partial(_sparse_grid_kernel, block_k=block_k),
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(rb_ids, kb_ids, first, cols, vals, dense)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[
-            ell_spec,
-            ell_spec,
-            pl.BlockSpec((1, 1), lambda fi, s, rb, kb, fs: (rb[s], 0)),
-            dense_spec,
-        ],
-        out_specs=out_specs,
+        grid=(f // block_f, n_steps),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec(
+            (block_rows, block_f), lambda fi, s, rb, kb, fs: (rb[s], fi)
+        ),
     )
     return pl.pallas_call(
-        functools.partial(_sparse_grid_kernel_scaled, block_k=block_k),
+        functools.partial(
+            _sparse_grid_kernel, block_k=block_k, scaled=scales is not None
+        ),
         grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(
-        rb_ids, kb_ids, first, cols, vals,
-        _block_scales_2d(scales, r, block_rows), dense,
-    )
+        out_shape=jax.ShapeDtypeStruct((r, f), out_dtype),
+        interpret=_default_interpret(interpret),
+    )(rb_ids, kb_ids, first, *args)
 
 
 def _combine_tile(x_ref, w_ref, b_ref, kb, block_k, k_real, cast_xw):
@@ -301,10 +256,10 @@ def _combine_tile(x_ref, w_ref, b_ref, kb, block_k, k_real, cast_xw):
 
     Replicates ``exec.quant.affine`` per tile (bf16 inputs arrive
     pre-cast, accumulation is f32, bias added in f32), then zeroes the
-    rows past ``k_real`` so the tile is bitwise-identical to the padded
-    activation the unfused path would have read from HBM.  ``cast_xw``
-    rounds through the storage dtype (bf16 under bf16/int8 plans) the
-    way ``quant.cast_dense`` does between the two unfused launches.
+    rows past ``k_real`` so the tile matches the padded activation the
+    unfused path would have read from HBM.  ``cast_xw`` rounds through
+    the storage dtype (bf16 under bf16/int8 plans) the way
+    ``quant.cast_dense`` does between the two unfused launches.
     """
     xw = jax.lax.dot_general(
         x_ref[...],
@@ -320,37 +275,39 @@ def _combine_tile(x_ref, w_ref, b_ref, kb, block_k, k_real, cast_xw):
     return xw
 
 
-def _fused_accumulate(cols, vals, scales, xw, out_ref, kb_base, *, block_rows, block_k):
+def _fused_accumulate(cols_ref, vals_ref, scales_ref, xw, out_ref, kb_base,
+                      *, block_rows, block_k):
     """Aggregate one combined k-tile into the resident output slab.
 
     Per row block the expansion + dot shapes are exactly those of the
     unfused kernels — (BR, tau) -> (BR, BK) @ (BK, BF) — so each output
     element accumulates through the same sequence of partial products.
+    The row blocks run in a loop that writes each product straight into
+    its slice of the slab: unrolled, the products of a large graph would
+    all be live at once and spill far past VMEM.
     """
     acc = _acc_dtype(out_ref.dtype)
-    n_rb = cols.shape[0] // block_rows
-    parts = []
-    for rb in range(n_rb):  # static: r // block_rows
-        lo = rb * block_rows
+    xw = xw.astype(acc)
+
+    def body(rb, carry):
+        rows = pl.ds(pl.multiple_of(rb * block_rows, block_rows), block_rows)
         a_blk = _expand_block(
-            cols[lo:lo + block_rows], vals[lo:lo + block_rows],
-            kb_base, block_k, acc,
+            cols_ref[rows, :], vals_ref[rows, :], kb_base, block_k, acc
         )
-        if scales is not None:
-            a_blk = a_blk * scales[rb, 0].astype(acc)
-        parts.append(jax.lax.dot_general(
-            a_blk,
-            xw.astype(acc),
-            (((1,), (0,)), ((), ())),
+        if scales_ref is not None:
+            a_blk = a_blk * scales_ref[rb].astype(acc)
+        out_ref[rows, :] += jax.lax.dot_general(
+            a_blk, xw, (((1,), (0,)), ((), ())),
             preferred_element_type=out_ref.dtype,
-        ))
-    out_ref[...] += parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
+        )
+        return carry
+
+    jax.lax.fori_loop(0, cols_ref.shape[0] // block_rows, body, 0)
 
 
-def _fused_dense_kernel(
-    cols_ref, vals_ref, x_ref, w_ref, b_ref, out_ref,
-    *, block_rows, block_k, k_real, cast_xw,
-):
+def _fused_dense_kernel(*refs, block_rows, block_k, k_real, cast_xw, scaled):
+    scales_ref, (cols_ref, vals_ref, x_ref, w_ref, b_ref, out_ref) = (
+        _split_scales(refs, scaled))
     kb = pl.program_id(1)
 
     @pl.when(kb == 0)
@@ -359,25 +316,8 @@ def _fused_dense_kernel(
 
     xw = _combine_tile(x_ref, w_ref, b_ref, kb, block_k, k_real, cast_xw)
     _fused_accumulate(
-        cols_ref[...], vals_ref[...], None, xw, out_ref, kb * block_k,
+        cols_ref, vals_ref, scales_ref, xw, out_ref, kb * block_k,
         block_rows=block_rows, block_k=block_k,
-    )
-
-
-def _fused_dense_kernel_scaled(
-    cols_ref, vals_ref, scales_ref, x_ref, w_ref, b_ref, out_ref,
-    *, block_rows, block_k, k_real, cast_xw,
-):
-    kb = pl.program_id(1)
-
-    @pl.when(kb == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    xw = _combine_tile(x_ref, w_ref, b_ref, kb, block_k, k_real, cast_xw)
-    _fused_accumulate(
-        cols_ref[...], vals_ref[...], scales_ref[...], xw, out_ref,
-        kb * block_k, block_rows=block_rows, block_k=block_k,
     )
 
 
@@ -412,52 +352,35 @@ def spmm_ell_fused_dense_grid(
     f_out = w.shape[1]
     if r % block_rows or k % block_k or f_out % block_f:
         raise ValueError("operands must be padded to block multiples")
-    out_dtype = out_dtype or jnp.float32
-    interpret = _default_interpret(interpret)
-    k_real = k if k_real is None else k_real
-    grid = (f_out // block_f, k // block_k)
     ell_spec = pl.BlockSpec((r, tau), lambda fi, kb: (0, 0))
-    x_spec = pl.BlockSpec((block_k, f_in), lambda fi, kb: (kb, 0))
-    w_spec = pl.BlockSpec((f_in, block_f), lambda fi, kb: (0, fi))
-    b_spec = pl.BlockSpec((1, block_f), lambda fi, kb: (0, fi))
-    out_specs = pl.BlockSpec((r, block_f), lambda fi, kb: (0, fi))
-    out_shape = jax.ShapeDtypeStruct((r, f_out), out_dtype)
-    if scales is None:
-        return pl.pallas_call(
-            functools.partial(
-                _fused_dense_kernel, block_rows=block_rows, block_k=block_k,
-                k_real=k_real, cast_xw=cast_xw,
-            ),
-            grid=grid,
-            in_specs=[ell_spec, ell_spec, x_spec, w_spec, b_spec],
-            out_specs=out_specs,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(cols, vals, x, w, b)
+    in_specs, args = _with_scales(
+        [
+            ell_spec,
+            ell_spec,
+            pl.BlockSpec((block_k, f_in), lambda fi, kb: (kb, 0)),
+            pl.BlockSpec((f_in, block_f), lambda fi, kb: (0, fi)),
+            pl.BlockSpec((1, block_f), lambda fi, kb: (0, fi)),
+        ],
+        (cols, vals, x, w, b), scales, r, block_rows,
+    )
     return pl.pallas_call(
         functools.partial(
-            _fused_dense_kernel_scaled, block_rows=block_rows,
-            block_k=block_k, k_real=k_real, cast_xw=cast_xw,
+            _fused_dense_kernel, block_rows=block_rows, block_k=block_k,
+            k_real=k if k_real is None else k_real, cast_xw=cast_xw,
+            scaled=scales is not None,
         ),
-        grid=grid,
-        in_specs=[
-            ell_spec,
-            ell_spec,
-            pl.BlockSpec((r // block_rows, 1), lambda fi, kb: (0, 0)),
-            x_spec,
-            w_spec,
-            b_spec,
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(cols, vals, _block_scales_2d(scales, r, block_rows), x, w, b)
+        grid=(f_out // block_f, k // block_k),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((r, block_f), lambda fi, kb: (0, fi)),
+        out_shape=jax.ShapeDtypeStruct((r, f_out), out_dtype or jnp.float32),
+        interpret=_default_interpret(interpret),
+    )(*args)
 
 
-def _fused_sparse_kernel(
-    kb_ids_ref, cols_ref, vals_ref, x_ref, w_ref, b_ref, out_ref,
-    *, block_rows, block_k, k_real, cast_xw,
-):
+def _fused_sparse_kernel(kb_ids_ref, *refs, block_rows, block_k, k_real,
+                         cast_xw, scaled):
+    scales_ref, (cols_ref, vals_ref, x_ref, w_ref, b_ref, out_ref) = (
+        _split_scales(refs, scaled))
     s = pl.program_id(1)
 
     @pl.when(s == 0)
@@ -469,28 +392,8 @@ def _fused_sparse_kernel(
         kb = kb_ids_ref[s]
         xw = _combine_tile(x_ref, w_ref, b_ref, kb, block_k, k_real, cast_xw)
         _fused_accumulate(
-            cols_ref[...], vals_ref[...], None, xw, out_ref, kb * block_k,
+            cols_ref, vals_ref, scales_ref, xw, out_ref, kb * block_k,
             block_rows=block_rows, block_k=block_k,
-        )
-
-
-def _fused_sparse_kernel_scaled(
-    kb_ids_ref, cols_ref, vals_ref, scales_ref, x_ref, w_ref, b_ref, out_ref,
-    *, block_rows, block_k, k_real, cast_xw,
-):
-    s = pl.program_id(1)
-
-    @pl.when(s == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(kb_ids_ref[s] >= 0)
-    def _step():
-        kb = kb_ids_ref[s]
-        xw = _combine_tile(x_ref, w_ref, b_ref, kb, block_k, k_real, cast_xw)
-        _fused_accumulate(
-            cols_ref[...], vals_ref[...], scales_ref[...], xw, out_ref,
-            kb * block_k, block_rows=block_rows, block_k=block_k,
         )
 
 
@@ -525,56 +428,35 @@ def spmm_ell_fused_sparse_grid(
     f_out = w.shape[1]
     if r % block_rows or k % block_k or f_out % block_f:
         raise ValueError("operands must be padded to block multiples")
-    out_dtype = out_dtype or jnp.float32
-    interpret = _default_interpret(interpret)
-    k_real = k if k_real is None else k_real
-    n_steps = int(kb_ids.shape[0])
-    grid = (f_out // block_f, n_steps)
     ell_spec = pl.BlockSpec((r, tau), lambda fi, s, kb: (0, 0))
-    x_spec = pl.BlockSpec(
-        (block_k, f_in), lambda fi, s, kb: (jnp.maximum(kb[s], 0), 0)
+    in_specs, args = _with_scales(
+        [
+            ell_spec,
+            ell_spec,
+            pl.BlockSpec(
+                (block_k, f_in), lambda fi, s, kb: (jnp.maximum(kb[s], 0), 0)
+            ),
+            pl.BlockSpec((f_in, block_f), lambda fi, s, kb: (0, fi)),
+            pl.BlockSpec((1, block_f), lambda fi, s, kb: (0, fi)),
+        ],
+        (cols, vals, x, w, b), scales, r, block_rows,
     )
-    w_spec = pl.BlockSpec((f_in, block_f), lambda fi, s, kb: (0, fi))
-    b_spec = pl.BlockSpec((1, block_f), lambda fi, s, kb: (0, fi))
-    out_specs = pl.BlockSpec((r, block_f), lambda fi, s, kb: (0, fi))
-    out_shape = jax.ShapeDtypeStruct((r, f_out), out_dtype)
-    kernel_kw = dict(
-        block_rows=block_rows, block_k=block_k, k_real=k_real, cast_xw=cast_xw
-    )
-    if scales is None:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[ell_spec, ell_spec, x_spec, w_spec, b_spec],
-            out_specs=out_specs,
-        )
-        return pl.pallas_call(
-            functools.partial(_fused_sparse_kernel, **kernel_kw),
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(kb_ids, cols, vals, x, w, b)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            ell_spec,
-            ell_spec,
-            pl.BlockSpec((r // block_rows, 1), lambda fi, s, kb: (0, 0)),
-            x_spec,
-            w_spec,
-            b_spec,
-        ],
-        out_specs=out_specs,
+        grid=(f_out // block_f, int(kb_ids.shape[0])),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((r, block_f), lambda fi, s, kb: (0, fi)),
     )
     return pl.pallas_call(
-        functools.partial(_fused_sparse_kernel_scaled, **kernel_kw),
+        functools.partial(
+            _fused_sparse_kernel, block_rows=block_rows, block_k=block_k,
+            k_real=k if k_real is None else k_real, cast_xw=cast_xw,
+            scaled=scales is not None,
+        ),
         grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(
-        kb_ids, cols, vals, _block_scales_2d(scales, r, block_rows), x, w, b
-    )
+        out_shape=jax.ShapeDtypeStruct((r, f_out), out_dtype or jnp.float32),
+        interpret=_default_interpret(interpret),
+    )(kb_ids, *args)
 
 
 def _default_interpret(interpret: Optional[bool]) -> bool:

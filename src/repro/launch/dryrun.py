@@ -67,15 +67,6 @@ def mesh_label(shape: tuple) -> str:
     return "x".join(str(s) for s in shape)
 
 
-def _mesh_context(mesh):
-    """``jax.set_mesh`` across jax versions: older releases (<= 0.4.x) use
-    the Mesh object itself as the context manager."""
-    import jax
-
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
 def _lower_and_analyze(cfg, shape, mesh, plan, donate: bool):
     """Lower+compile one step for (cfg, shape) -> (record_fields, compiled)."""
     import jax
@@ -102,13 +93,11 @@ def _lower_and_analyze(cfg, shape, mesh, plan, donate: bool):
         donate_argnums = (1,) if donate else ()
 
     t0 = time.time()
-    with _mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, donate_argnums=donate_argnums).lower(*args)
         t1 = time.time()
         compiled = lowered.compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax <= 0.4.x: per-device dict list
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
     return {

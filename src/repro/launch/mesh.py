@@ -30,7 +30,10 @@ def make_production_mesh(
     """
     shape = (pods, data, model) if multi_pod else (data, model)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: model code places arrays with with_sharding_constraint
+    # (dist.policy.constrain), which only Auto axes accept.
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_data_mesh(n_data: int) -> jax.sharding.Mesh:

@@ -8,6 +8,9 @@ Usage:
       --requests 32 --reduced          # CI smoke configuration
   PYTHONPATH=src python -m repro.launch.serve_gcn --dataset cora \
       --requests 64 --reduced --runtime-async --deadline-ms 200 --qps 100
+
+The async and fleet scenarios exit non-zero when any admitted request
+failed (load shed by admission or deadline is reported, not failed).
 """
 
 import argparse
@@ -18,7 +21,10 @@ import numpy as np
 from repro.serve import ServeEngine
 
 
-def build_engine(args, feedback=None) -> ServeEngine:
+def build_engine(args, feedback=None, **overrides) -> ServeEngine:
+    """The engine the CLI serves with; ``overrides`` replace any
+    ``ServeEngine`` keyword the arguments set (or add one, such as
+    ``interpret``)."""
     mesh = None
     if args.mesh > 1:
         from repro.launch.mesh import make_data_mesh
@@ -28,8 +34,7 @@ def build_engine(args, feedback=None) -> ServeEngine:
     if args.ladder_growth:
         growth = "auto" if args.ladder_growth == "auto" \
             else float(args.ladder_growth)
-    return ServeEngine.from_dataset(
-        args.dataset,
+    kw = dict(
         hidden_dim=16 if args.reduced else args.hidden,
         spmm_impl=args.impl,
         fanout=args.fanout,
@@ -43,6 +48,8 @@ def build_engine(args, feedback=None) -> ServeEngine:
         accuracy_budget=args.accuracy_budget,
         feedback=feedback,
     )
+    kw.update(overrides)
+    return ServeEngine.from_dataset(args.dataset, **kw)
 
 
 def make_tracer(args):
@@ -69,6 +76,21 @@ def export_observability(args, tracer, metrics) -> None:
     if args.metrics_json:
         write_metrics_json(args.metrics_json, metrics)
         print(f"[metrics] snapshot written to {args.metrics_json}")
+
+
+def device_note() -> str:
+    """``platform/kind xcount`` of the devices JAX runs on."""
+    import jax
+
+    devs = jax.devices()
+    return f"{devs[0].platform}/{devs[0].device_kind} x{len(devs)}"
+
+
+def exit_on_failures(scenario: str, counters) -> None:
+    """Exit non-zero when the runtime counted any failed request."""
+    failed = counters.get("failed", 0)
+    if failed:
+        raise SystemExit(f"{scenario}: {failed} requests failed")
 
 
 def run_async_scenario(engine: ServeEngine, requests, args) -> None:
@@ -108,6 +130,7 @@ def run_async_scenario(engine: ServeEngine, requests, args) -> None:
         print(f"[obs] {len(engine.feedback)} measured plan latencies "
               f"saved to {args.plan_feedback}")
     export_observability(args, tracer, rt.metrics)
+    exit_on_failures("async", c)
 
 
 def run_fleet_scenario(args) -> None:
@@ -202,9 +225,10 @@ def run_fleet_scenario(args) -> None:
               f"met, quota-shed {quota}, e2e p50 {e2e['p50']:.2f} ms "
               f"p99 {e2e['p99']:.2f} ms")
     export_observability(args, tracer, rt.metrics)
+    exit_on_failures("fleet", c)
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="cora")
     ap.add_argument("--requests", type=int, default=64)
@@ -217,8 +241,10 @@ def main() -> None:
                     help="skip warmup of bucket rungs above this node count; "
                          "0 = let the engine derive the reachable bound from "
                          "fanout/hops (uncapped fanout warms every rung)")
-    ap.add_argument("--impl", default="reference",
-                    choices=["reference", "pallas", "pallas_sparse"])
+    ap.add_argument("--impl", default="pallas_sparse",
+                    choices=["reference", "pallas", "pallas_sparse"],
+                    help="SpMM impl; the Pallas kernels run compiled on a "
+                         "TPU and in interpret mode elsewhere")
     ap.add_argument("--precision", default="f32",
                     choices=["f32", "bf16", "int8", "auto"],
                     help="serving numerics: f32 keeps the baseline "
@@ -282,7 +308,14 @@ def main() -> None:
                          "fleet (servables + tenant policies + loads); "
                          "runs the fleet scenario instead of the "
                          "single-engine ones")
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> None:
+    from repro.serve.cache import enable_compile_cache
+
+    args = build_parser().parse_args()
+    enable_compile_cache()
 
     if args.fleet_config:
         run_fleet_scenario(args)
@@ -302,10 +335,14 @@ def main() -> None:
     plan = engine.batcher.plan
     impl_note = plan.effective_impl + (
         f" (degraded from {plan.impl})" if plan.degraded else "")
+    full_impl = (engine.full_plan.effective_impl if engine.full_plan
+                 else "autoplanned")
     print(f"[warmup] {built} bucket executables compiled in "
           f"{time.perf_counter() - t0:.1f}s; ladder "
           f"{[ (b.nodes, b.rows) for b in engine.batcher.ladder.entries ]}; "
-          f"impl {impl_note}; mesh data={args.mesh}; "
+          f"impl full-graph {full_impl}, buckets {impl_note}; "
+          f"mesh data={args.mesh}; "
+          f"device {device_note()}; "
           f"registry builds={reg.builds} disk_hits={reg.disk_hits}")
     if args.precision != "f32":
         errs = {p: round(e, 5)

@@ -12,6 +12,7 @@ overload.
 from __future__ import annotations
 
 import time
+from concurrent.futures import TimeoutError as FutureTimeoutError
 from typing import Sequence
 
 import numpy as np
@@ -60,6 +61,8 @@ def run_open_loop(
     for req in pending:
         try:
             req.future.result(timeout=result_timeout_s)
+        except FutureTimeoutError:
+            raise             # never resolved: counted nowhere, so surface it
         except Exception:
             pass              # shed while queued / failed; also counted
     return rt.clock.now() - t_start

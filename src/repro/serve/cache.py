@@ -96,16 +96,35 @@ _REPO_ROOT = os.path.dirname(
 )
 
 
-def default_cache_dir() -> str:
-    env = os.environ.get("REPRO_CACHE")
-    if env:
-        return env
+def _checkout_cache_dir() -> str:
     # Four levels up is the repo root only for an src-layout checkout or
     # editable install; from site-packages fall back to a user cache dir
     # instead of dumping pickles next to the interpreter.
     if os.path.isdir(os.path.join(_REPO_ROOT, "src", "repro")):
         return os.path.join(_REPO_ROOT, ".cache")
     return os.path.join(os.path.expanduser("~"), ".cache", "repro")
+
+
+def default_cache_dir() -> str:
+    return os.environ.get("REPRO_CACHE") or _checkout_cache_dir()
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting: it is
+    left as it is.  Otherwise the cache goes to ``<repo>/.cache/jax``: a
+    fixed path, so the next process finds what this one wrote.  It never
+    follows ``REPRO_CACHE``, a temp name, a pid or the time.
+    """
+    import jax
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(_checkout_cache_dir(), "jax")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def cache_path(key: str, cache_dir: Optional[str] = None) -> str:

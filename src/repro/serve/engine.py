@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from repro.core.sparse_formats import CSRMatrix
+from repro.exec import plan_for_config
 from repro.models.gcn import GCNConfig, init_params
 from repro.serve.batcher import BucketLadder, MicroBatcher, PaddedRequest
 from repro.serve.registry import ArtifactRegistry
@@ -118,11 +119,14 @@ class ServeEngine:
         # multi-layer pipeline planner (per-layer impl/blocks + activation
         # layouts chosen jointly); the static config plan otherwise.
         self.graph = self.registry.get_or_build(adj_norm, cfg, persist=True)
-        self._plan_arg = "auto" if autoplan else None
-        self._full_step = self.registry.forward_step(
-            adj_norm, cfg, plan=self._plan_arg,
-            precision=self._static_precision,
-        )
+        # The full graph's operand is a host TiledELL, so its static plan
+        # resolves schedulable: pallas_sparse keeps its block-skipping
+        # grid here (served buckets degrade, see ``batcher.plan``).
+        self.interpret = interpret
+        self.full_plan = None if autoplan else plan_for_config(
+            cfg, interpret=interpret).resolve(schedulable=True)
+        self._plan_arg = "auto" if autoplan else self.full_plan
+        self._full_step = self._forward_step(self._static_precision)
         self.sampler = SubgraphSampler(
             adj_norm,
             cfg,
@@ -273,8 +277,7 @@ class ServeEngine:
         if len(self.precision_errors) <= 1:
             ref = np.asarray(self._full_step(self.params, self.features))
             for p in ("bf16", "int8"):
-                step = self.registry.forward_step(
-                    self.adj_norm, self.cfg, plan=self._plan_arg, precision=p)
+                step = self._forward_step(p)
                 out = np.asarray(step(self.params, self.features))
                 self.precision_errors[p] = quant.logit_error(ref, out)
         admissible = tuple(
@@ -302,9 +305,14 @@ class ServeEngine:
         # swap costs nothing.
         full = admissible[-1] if len(admissible) > 1 else "f32"
         if full != self._static_precision:
-            self._full_step = self.registry.forward_step(
-                self.adj_norm, self.cfg, plan=self._plan_arg, precision=full)
+            self._full_step = self._forward_step(full)
             self._static_precision = full
+
+    def _forward_step(self, precision: str):
+        """The registry's jitted full-graph step at ``precision``."""
+        return self.registry.forward_step(
+            self.adj_norm, self.cfg, plan=self._plan_arg,
+            precision=precision, interpret=self.interpret)
 
     # ------------------------------------------------------------------
     # Scenarios

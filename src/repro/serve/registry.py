@@ -101,7 +101,7 @@ class ArtifactRegistry:
 
     def forward_step(
         self, adj: CSRMatrix, cfg: GCNConfig, persist: bool = True,
-        plan=None, precision: str = "f32",
+        plan=None, precision: str = "f32", interpret: Optional[bool] = None,
     ) -> Callable:
         """Jitted full-graph forward ``step(params, features) -> logits``
         bound to the registered preprocessed operand.
@@ -113,9 +113,11 @@ class ArtifactRegistry:
         stack through ``repro.exec.pipeline`` once at build time
         (host-side, so the traced step carries the already-chosen
         per-layer plans); a plan object keys the cache by identity.
+        ``interpret`` pins Pallas interpret mode for an ``"auto"`` plan
+        (``None``: from the backend); a plan object carries its own.
         """
         gkey = graph_key(adj, cfg)
-        key = (gkey, cfg, precision,
+        key = (gkey, cfg, precision, interpret,
                plan if (plan is None or isinstance(plan, str)) else id(plan))
         fwd = self._forwards.get(key)
         if fwd is not None:
@@ -128,7 +130,8 @@ class ArtifactRegistry:
             from repro.exec.pipeline import plan_pipeline
 
             step_plan = plan_pipeline(cfg, graph.pre.ell,
-                                      precision=precision)
+                                      precision=precision,
+                                      interpret=interpret)
         fwd = jax.jit(
             lambda params, feats: gcn_forward(
                 params, graph, feats, cfg, plan=step_plan,

@@ -527,3 +527,74 @@ def test_fleet_config_round_trip(toy_engine_parts, tmp_path):
     rt.drain()
     assert r.future.result(timeout=0).shape == (rt.manager.servable(
         "lm").cfg.vocab,)
+
+
+# ---------------------------------------------------------------------------
+# serve_gcn: a failed request makes the async and fleet scenarios exit
+# non-zero; shed load alone does not
+# ---------------------------------------------------------------------------
+
+
+def _raise_runner(*_a, **_k):
+    raise RuntimeError("runner failure injected by the test")
+
+
+def _serve_gcn_args(*argv):
+    from repro.launch.serve_gcn import build_parser
+
+    return build_parser().parse_args(
+        ["--qps", "400", "--deadline-ms", "5000", *argv])
+
+
+def _run_async(toy_engine_parts, monkeypatch, fail: bool):
+    from repro.launch.serve_gcn import run_async_scenario
+
+    engine = _toy_engine(toy_engine_parts)
+    engine.warmup()
+    if fail:
+        monkeypatch.setattr(engine.batcher, "run", _raise_runner)
+    rng = np.random.default_rng(2)
+    requests = [rng.choice(400, size=2, replace=False) for _ in range(6)]
+    run_async_scenario(engine, requests, _serve_gcn_args())
+
+
+def _run_fleet(tmp_path, monkeypatch, fail: bool):
+    import json
+
+    from repro.fleet import LmServable
+    from repro.launch.serve_gcn import run_fleet_scenario
+
+    if fail:
+        monkeypatch.setattr(LmServable, "run_batch", _raise_runner)
+    config = {
+        "servables": [{"kind": "lm", "key": "lm", "arch": "internlm2-1.8b",
+                       "seq_buckets": [8], "max_batch": 2}],
+        "capacity_units": 2.0,
+        "tenants": [{"name": "t", "deadline_s": 5.0}],
+        "loads": [{"tenant": "t", "servable": "lm", "qps": 400,
+                   "requests": 4, "deadline_ms": 5000, "seq_len": 5}],
+    }
+    path = tmp_path / "fleet.json"
+    path.write_text(json.dumps(config))
+    run_fleet_scenario(_serve_gcn_args("--fleet-config", str(path)))
+
+
+@pytest.mark.parametrize("scenario", ["async", "fleet"])
+def test_serve_gcn_exits_nonzero_when_a_request_fails(
+        scenario, toy_engine_parts, tmp_path, monkeypatch):
+    with pytest.raises(SystemExit) as exc:
+        if scenario == "async":
+            _run_async(toy_engine_parts, monkeypatch, fail=True)
+        else:
+            _run_fleet(tmp_path, monkeypatch, fail=True)
+    # a message exits with status 1
+    assert exc.value.code and "requests failed" in str(exc.value.code)
+
+
+@pytest.mark.parametrize("scenario", ["async", "fleet"])
+def test_serve_gcn_returns_when_every_request_succeeds(
+        scenario, toy_engine_parts, tmp_path, monkeypatch):
+    if scenario == "async":
+        _run_async(toy_engine_parts, monkeypatch, fail=False)
+    else:
+        _run_fleet(tmp_path, monkeypatch, fail=False)

@@ -1,8 +1,9 @@
 """Parity + planning tests for the fused combination+aggregation layer.
 
-The fused path's contract is *bitwise* equality with the classic
-two-launch path (combination matmul, intermediate activation, SpMM) at
-the same plan — not an approximation.  This suite pins that contract
+The fused path's contract is equality with the classic two-launch path
+(combination matmul, intermediate activation, SpMM) at the same plan:
+bitwise at bf16 and int8, and to f32 rounding at f32 (see
+:func:`assert_fused_parity`).  This suite pins that contract
 across all three impls (the reference oracle must *route* unfused — a
 gather has no launch to fuse), all three storage precisions, and 1/2/4
 devices (in-process virtual devices plus one subprocess cell that does
@@ -65,12 +66,35 @@ def _forward(graph, cfg, params, feats, *, precision, fused):
     return np.asarray(gcn_forward(params, graph, feats, cfg, plan=plan))
 
 
+#: f32 bound, as a fraction of the output's largest magnitude (~80 ulps).
+F32_REL_TOL = 1e-5
+
+
+def assert_fused_parity(fused, unfused, precision="f32"):
+    """Fused equals unfused: bitwise at bf16/int8, to rounding at f32.
+
+    The fused kernel computes ``X @ W`` per ``(block_k, F_in)`` tile in
+    VMEM, the unfused path as one XLA dot over all of ``X``.  Compilers
+    block an f32 contraction by its shapes (XLA:CPU does, and so does the
+    TPU, whose MXU runs f32 as several bf16 passes), so the two sums round
+    differently: at most 1.5e-5 on logits of magnitude 200 on the CPU.
+    At bf16 and int8 both paths round the combination through bf16
+    before the aggregation, and the outputs agree bit for bit.
+    """
+    if precision == "f32":
+        scale = float(np.abs(unfused).max())
+        np.testing.assert_allclose(fused, unfused, rtol=0,
+                                   atol=F32_REL_TOL * scale)
+    else:
+        np.testing.assert_array_equal(fused, unfused)
+
+
 def _data_mesh(n_dev):
     return jax.sharding.Mesh(np.array(jax.devices()[:n_dev]), ("data",))
 
 
 # ---------------------------------------------------------------------------
-# bitwise parity: impls x precisions, single device
+# parity: impls x precisions, single device
 # ---------------------------------------------------------------------------
 
 
@@ -82,7 +106,7 @@ def test_fused_bitwise_parity(impl, precision):
                        fused=False)
     fused = _forward(graph, cfg, params, feats, precision=precision,
                      fused=True)
-    np.testing.assert_array_equal(fused, unfused)
+    assert_fused_parity(fused, unfused, precision)
     assert np.isfinite(fused).all()
 
 
@@ -94,8 +118,8 @@ def test_fused_bitwise_parity_jit(impl):
     plan_f = dataclasses.replace(plan_u, fused=True)
     f_u = jax.jit(lambda p, x: gcn_forward(p, graph, x, cfg, plan=plan_u))
     f_f = jax.jit(lambda p, x: gcn_forward(p, graph, x, cfg, plan=plan_f))
-    np.testing.assert_array_equal(np.asarray(f_f(params, feats)),
-                                  np.asarray(f_u(params, feats)))
+    assert_fused_parity(np.asarray(f_f(params, feats)),
+                        np.asarray(f_u(params, feats)))
 
 
 def test_reference_impl_routes_unfused():
@@ -154,7 +178,7 @@ def test_fused_parity_sharded_pipeline(n_dev):
         pplan = static_pipeline(cfg, mesh, fused=fused)
         outs[fused] = np.asarray(
             pipeline_forward(params, graph, feats, pplan))
-    np.testing.assert_array_equal(outs[True], outs[False])
+    assert_fused_parity(outs[True], outs[False])
 
 
 @pytest.mark.parametrize("precision", ["bf16", "int8"])
@@ -199,14 +223,17 @@ for n_dev in (2, 4):
         LEDGER.reset()
         outs[fused] = np.asarray(pipeline_forward(
             params, graph, feats, static_pipeline(cfg, mesh, fused=fused)))
-    np.testing.assert_array_equal(outs[True], outs[False])
+    # f32: equal to rounding, as assert_fused_parity states
+    scale = float(np.abs(outs[False]).max())
+    np.testing.assert_allclose(outs[True], outs[False], rtol=0,
+                               atol=1e-5 * scale)
     print(f"ok x{n_dev}")
 """
 
 
 def test_fused_parity_multidevice_subprocess():
-    """Real 2-/4-device fused-vs-unfused bitwise parity, independent of
-    the parent process's pinned device count."""
+    """Real 2-/4-device fused-vs-unfused f32 parity, independent of the
+    parent process's pinned device count."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = (

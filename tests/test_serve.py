@@ -403,3 +403,41 @@ def test_bench_serve_smoke(monkeypatch, capsys):
     assert "p50_ms,p99_ms" in out and "tok_equiv_per_s" in out
     lines = [l for l in out.strip().splitlines() if l.startswith("cora,")]
     assert {l.split(",")[1] for l in lines} == {"full", "query", "batch"}
+
+
+_COMPILE_CACHE_CHILD = r"""
+import jax, jax.numpy as jnp
+from repro.serve.cache import enable_compile_cache
+path = enable_compile_cache()
+jax.block_until_ready(jax.jit(lambda x: jnp.tanh(x) * 3.0 + 1.0)(
+    jnp.ones((8, 128))))
+print(path)
+print(jax.config.jax_compilation_cache_dir)
+"""
+
+
+@pytest.mark.parametrize("env_set", [True, False], ids=["env-set", "unset"])
+def test_compile_cache_lands_in_env_dir_or_checkout(env_set, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` is used as set; unset, the cache is
+    the fixed ``<repo>/.cache/jax``, whatever ``REPRO_CACHE`` says."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               REPRO_CACHE=str(tmp_path / "artifacts"),
+               PYTHONPATH=os.path.join(root, "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(root, ".cache", "jax")
+    if env_set:
+        want = str(tmp_path / "jax-cache")
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    r = subprocess.run([sys.executable, "-c", _COMPILE_CACHE_CHILD],
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr
+    reported, configured = r.stdout.split()[-2:]
+    assert reported == configured == want
+    assert any(name.endswith("-cache") for name in os.listdir(want))
+    assert not os.path.exists(tmp_path / "artifacts" / "jax")

@@ -1,0 +1,160 @@
+"""The main path's Pallas kernels compile for a TPU v5e at pubmed widths.
+
+Interpret mode on the CPU cannot show what the TPU compiler refuses: a
+block that breaks the (8, 128) tiling rule, a scalar-prefetch list past
+SMEM, a kernel past VMEM.  These tests compile each kernel for a
+described (not attached) v5e chip, at pubmed's Table III size cut into
+the serving config's tiles: 25,984 vertex-cut rows of ``tau=6``, 19,717
+dense rows padded to 19,840, 500 input features, 128-wide feature
+tiles, and the 14,809-step block-skipping pair list pubmed's ELL gets.
+
+The topology is described inside a fixture, never at import, so only
+the test worker that runs this file loads the TPU compiler; the
+persistent compile cache is off around these compiles (an entry written
+for a described chip cannot be read back without one).
+"""
+
+import os
+
+import pytest
+
+ROWS, TAU, K, K_REAL, F, F_IN = 25_984, 6, 19_840, 19_717, 128, 500
+PAIRS, FUSED_STEPS, BLOCK = 14_809, 155, 128
+PRECISIONS = ("f32", "bf16", "int8")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def shape(topo):
+    """``shape(dims, dtype)`` -> an abstract array on one described chip."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
+                                                    sharding=one_chip)
+
+
+def _dtypes(precision):
+    import jax.numpy as jnp
+
+    vals = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
+    act = jnp.float32 if precision == "f32" else jnp.bfloat16
+    return vals[precision], act
+
+
+def _compile(fn, *args):
+    """Compile for the described chip; returns the compiled text."""
+    import jax
+
+    args = [a for a in args if a is not None]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _scales(shape, precision, rows=ROWS):
+    import jax.numpy as jnp
+
+    return shape((rows // BLOCK,), jnp.float32) if precision == "int8" \
+        else None
+
+
+@pytest.mark.parametrize("precision", PRECISIONS)
+def test_dense_grid_compiles(shape, precision):
+    import jax.numpy as jnp
+
+    from repro.kernels import flexvector_spmm as fv
+
+    vdt, adt = _dtypes(precision)
+    sc = _scales(shape, precision)
+    text = _compile(
+        lambda c, v, d, *s: fv.spmm_ell_dense_grid(
+            c, v, d, interpret=False, scales=s[0] if s else None),
+        shape((ROWS, TAU), jnp.int32), shape((ROWS, TAU), vdt),
+        shape((K, F), adt), sc)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("precision", PRECISIONS)
+def test_sparse_grid_compiles(shape, precision):
+    import jax.numpy as jnp
+
+    from repro.kernels import flexvector_spmm as fv
+
+    vdt, adt = _dtypes(precision)
+    steps = shape((PAIRS,), jnp.int32)
+    text = _compile(
+        lambda c, v, d, rb, kb, fs, *s: fv.spmm_ell_sparse_grid(
+            c, v, d, rb, kb, fs, interpret=False,
+            scales=s[0] if s else None),
+        shape((ROWS, TAU), jnp.int32), shape((ROWS, TAU), vdt),
+        shape((K, F), adt), steps, steps, steps,
+        _scales(shape, precision))
+    assert "tpu_custom_call" in text
+
+
+def _fused(shape, kind, precision, rows, f_in=F_IN):
+    import jax.numpy as jnp
+
+    from repro.kernels import flexvector_spmm as fv
+
+    vdt, adt = _dtypes(precision)
+    kw = dict(interpret=False, k_real=K_REAL,
+              cast_xw=None if precision == "f32" else jnp.bfloat16)
+    ell = (shape((rows, TAU), jnp.int32), shape((rows, TAU), vdt))
+    layer = (shape((K, f_in), adt), shape((f_in, F), adt),
+             shape((1, F), jnp.float32))
+    sc = _scales(shape, precision, rows)
+    if kind == "dense":
+        return _compile(
+            lambda c, v, x, w, b, *s: fv.spmm_ell_fused_dense_grid(
+                c, v, x, w, b, scales=s[0] if s else None, **kw),
+            *ell, *layer, sc)
+    return _compile(
+        lambda c, v, x, w, b, kb, *s: fv.spmm_ell_fused_sparse_grid(
+            c, v, x, w, b, kb, scales=s[0] if s else None, **kw),
+        *ell, *layer, shape((FUSED_STEPS,), jnp.int32), sc)
+
+
+@pytest.mark.parametrize("precision", PRECISIONS)
+@pytest.mark.parametrize("kind", ["dense", "sparse"])
+def test_fused_kernels_compile_at_pubmed_rows(shape, kind, precision):
+    assert "tpu_custom_call" in _fused(shape, kind, precision, ROWS)
+
+
+def test_largest_launch_fused_viable_admits_compiles(shape):
+    """The VMEM gate is sound at its edge: the largest row count
+    ``fused_viable`` admits at pubmed's input width compiles."""
+    from repro.plan import cost
+
+    rows = BLOCK
+    while cost.fused_viable(_Stats(rows + BLOCK), F_IN):
+        rows += BLOCK
+    assert rows > BLOCK
+    assert not cost.fused_viable(_Stats(ROWS), F_IN)   # pubmed does not fit
+    assert "tpu_custom_call" in _fused(shape, "dense", "f32", rows)
+
+
+class _Stats:
+    """The two ``GraphStats`` fields ``fused_viable`` reads."""
+
+    def __init__(self, rows):
+        self.padded_rows, self.tau = rows, TAU
