@@ -210,25 +210,23 @@ def execute_layer(
     ``layer`` holds ``"w"``/``"b"`` and optionally ``"w_scale"`` with
     ``w_block_rows`` granularity (see ``quant.quantize_params``).
 
-    When a ``repro.obs`` span is active on this thread (eager path
-    only — traced operands never observe host state), the layer runs
-    under an ``execute_layer`` child span stamped with the resolved
-    plan's attributes, and the ledger records fired inside land on it
-    as events.
+    On the eager path (concrete operands) the layer is the
+    ``exec.execute_layer`` span: inside a request trace it opens an
+    ``execute_layer`` child stamped with the resolved plan's attributes,
+    and the ledger records fired inside land on it as events.
     """
     plan = plan.resolve(schedulable=operands.schedulable)
-    span = None
     if operands.concrete and not isinstance(x, jax.core.Tracer):
-        from repro.obs.trace import start_layer_span  # deferred: no cycle
+        from repro.obs.trace import plan_attributes, span  # deferred
 
-        span = start_layer_span(plan)
-    try:
-        return _execute_layer_inner(
-            plan, operands, x, layer, w_block_rows=w_block_rows
-        )
-    finally:
-        if span is not None:
-            span.finish()
+        with span("exec.execute_layer") as sp:
+            sp.set(**plan_attributes(plan))
+            return _execute_layer_inner(
+                plan, operands, x, layer, w_block_rows=w_block_rows
+            )
+    return _execute_layer_inner(
+        plan, operands, x, layer, w_block_rows=w_block_rows
+    )
 
 
 def _execute_layer_inner(
@@ -249,7 +247,8 @@ def _execute_layer_inner(
         return execute_fused(
             plan, operands, x, layer, w_block_rows=w_block_rows
         )
-    xw = quant.affine(x, layer, plan.precision, w_block_rows)
+    with jax.named_scope("combine"):
+        xw = quant.affine(x, layer, plan.precision, w_block_rows)
     if operands.concrete and not isinstance(x, jax.core.Tracer):
         from repro.exec.fused import record_combination_dram
 
@@ -287,8 +286,11 @@ def execute(plan: SpmmPlan, operands: SpmmOperands, dense: jax.Array) -> jax.Arr
             scales = None
         elif plan.precision != "f32":
             vals = vals.astype(jnp.float32)
-        return _ref_spmm(cols, vals, row_map, dense, operands.n_out_rows)
-    sub = sub_row_products(
-        plan, cols, vals, dense, ell=operands.ell, scales=scales
-    )
-    return segment_accumulate(sub, row_map, operands.n_out_rows)
+        with jax.named_scope("aggregate"):
+            return _ref_spmm(cols, vals, row_map, dense, operands.n_out_rows)
+    with jax.named_scope("aggregate"):
+        sub = sub_row_products(
+            plan, cols, vals, dense, ell=operands.ell, scales=scales
+        )
+    with jax.named_scope("fold"):
+        return segment_accumulate(sub, row_map, operands.n_out_rows)

@@ -256,16 +256,19 @@ def execute_fused(
             operands.ell, plan.block_rows, plan.block_k,
             hot_k_first=plan.hot_k_first,
         )
-        sub = fv.spmm_ell_fused_sparse_grid(
-            cols, vals, x_eff, w_eff, b2, jnp.asarray(kb_ids), **common
-        )
+        with jax.named_scope("aggregate"):
+            sub = fv.spmm_ell_fused_sparse_grid(
+                cols, vals, x_eff, w_eff, b2, jnp.asarray(kb_ids), **common
+            )
     else:  # pallas: masked full k sweep
-        sub = fv.spmm_ell_fused_dense_grid(
-            cols, vals, x_eff, w_eff, b2, **common
+        with jax.named_scope("aggregate"):
+            sub = fv.spmm_ell_fused_dense_grid(
+                cols, vals, x_eff, w_eff, b2, **common
+            )
+    with jax.named_scope("fold"):
+        return segment_accumulate(
+            sub[:r, :f_out], row_map, operands.n_out_rows
         )
-    return segment_accumulate(
-        sub[:r, :f_out], row_map, operands.n_out_rows
-    )
 
 
 def _execute_fused_sharded(

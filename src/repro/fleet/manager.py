@@ -268,17 +268,6 @@ class FleetRuntime:
 
     def _run_batch(self, batch: ClosedBatch) -> List:
         sv = self.manager.resolve(batch.bucket.servable)
-        if self.tracer is not None:
-            engine = getattr(sv, "engine", None)
-            if engine is not None:
-                # Host-side modeled DRAM ledgering (the AOT executables
-                # never fire eager records); gated on tracing so untraced
-                # fleets leave the global LEDGER untouched.
-                engine.batcher.record_batch_dram(
-                    batch.bucket.inner,
-                    self.scheduler.padded_width(len(batch.requests),
-                                                batch.bucket),
-                    int(engine.features.shape[1]))
         return sv.run_batch([r.padded for r in batch.requests])
 
     def submit(

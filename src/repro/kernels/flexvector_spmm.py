@@ -166,6 +166,7 @@ def spmm_ell_dense_grid(
         ),
         out_shape=jax.ShapeDtypeStruct((r, f), out_dtype),
         interpret=_default_interpret(interpret),
+        name="flexvector_dense_grid",
     )(*args)
 
 
@@ -248,6 +249,7 @@ def spmm_ell_sparse_grid(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, f), out_dtype),
         interpret=_default_interpret(interpret),
+        name="flexvector_sparse_grid",
     )(rb_ids, kb_ids, first, *args)
 
 
@@ -374,6 +376,7 @@ def spmm_ell_fused_dense_grid(
         out_specs=pl.BlockSpec((r, block_f), lambda fi, kb: (0, fi)),
         out_shape=jax.ShapeDtypeStruct((r, f_out), out_dtype or jnp.float32),
         interpret=_default_interpret(interpret),
+        name="flexvector_fused_dense_grid",
     )(*args)
 
 
@@ -456,6 +459,7 @@ def spmm_ell_fused_sparse_grid(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, f_out), out_dtype or jnp.float32),
         interpret=_default_interpret(interpret),
+        name="flexvector_fused_sparse_grid",
     )(kb_ids, *args)
 
 

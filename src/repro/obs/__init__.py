@@ -3,10 +3,12 @@ measured-latency feedback into the planner.
 
 Three pieces:
 
-* :mod:`repro.obs.trace` — ``Tracer`` / ``Trace`` / ``Span``: one
-  structured trace per served request, clocked by ``runtime.clock``
-  (deterministic under ``VirtualClock``), with ``CollectiveLedger``
-  records adopted as span events.
+* :mod:`repro.obs.trace` — ``span``, the one primitive at every layer
+  boundary (a profiler annotation ``repro.<layer>.<stage>``, optional
+  wall/CPU-time counters in a ``MetricsRegistry``, and a child span in
+  the current request traces), and ``Tracer`` / ``Trace`` / ``Span``:
+  one structured trace per served request, clocked by
+  ``runtime.clock`` (deterministic under ``VirtualClock``).
 * :mod:`repro.obs.export` — registry snapshots + drained spans as
   JSON and Prometheus text exposition.
 * :mod:`repro.obs.feedback` — ``PlanFeedback``: per-(bucket, plan)
@@ -34,11 +36,13 @@ from repro.obs.trace import (
     Trace,
     Tracer,
     current_span,
+    current_spans,
     engine_batch_info,
     install_ledger_listener,
     plan_attributes,
-    start_layer_span,
+    span,
     use_span,
+    use_spans,
 )
 
 __all__ = [
@@ -46,11 +50,13 @@ __all__ = [
     "SpanEvent",
     "Trace",
     "Tracer",
+    "span",
     "current_span",
+    "current_spans",
     "use_span",
+    "use_spans",
     "plan_attributes",
     "engine_batch_info",
-    "start_layer_span",
     "install_ledger_listener",
     "PlanFeedback",
     "bucket_key",

@@ -1,25 +1,42 @@
-"""Structured request tracing for the serving vertical.
+"""In-process instrumentation: profiler spans, span counters, request traces.
 
-One :class:`Trace` follows a request end to end: admission in
-``runtime.queue``, queue wait + batch close in ``runtime.scheduler`` /
-``runtime.loop``, batch execution, and per-layer execute spans stamped
-with the :class:`~repro.exec.plan.SpmmPlan` attributes (impl,
-precision, fused, mesh width, block sizes) that actually served it.
-``CollectiveLedger`` records become span events, so the modeled DRAM /
-collective bytes of a batch are attributed to the request that paid
-for them.
+:func:`span` is the one primitive every layer boundary uses.  ``with
+span("<layer>.<stage>")`` gives up to three outputs:
+
+* a ``jax.profiler.TraceAnnotation`` named ``repro.<layer>.<stage>``, so
+  host work lands in the profiler's trace beside the device ops, on
+  their clock (it costs next to nothing while no profile is taken);
+* with ``metrics=`` a :class:`~repro.runtime.metrics.MetricsRegistry`,
+  three integer counters: ``<stage>_n``, ``<stage>_ns`` (wall,
+  ``time.perf_counter_ns``) and ``<stage>_cpu_ns`` (this thread's CPU
+  time, ``time.thread_time_ns``), so wall minus CPU is time the thread
+  spent waiting;
+* while a request :class:`Trace` is current on the thread (see
+  :func:`use_span` / :func:`use_spans`), a child :class:`Span` named
+  ``<stage>`` on the trace's own clock, so spans nest by call structure
+  and stay exact under ``VirtualClock``.
+
+One :class:`Trace` follows a request end to end: prepare (and its
+sampling, induction, vertex-cut and padding stages) on the submitting
+thread, admission in ``runtime.queue``, queue wait, batch close and
+execute (stack, dispatch, fetch) on the worker, with the execute span
+stamped with the :class:`~repro.exec.plan.SpmmPlan` attributes
+(impl, precision, fused, mesh width, block sizes) that served it.
+Device time per layer comes from the profiler's trace, under the named
+scopes of the compiled steps, not from these spans.
 
 Design constraints, in order:
 
-* **Clock-faithful.** Every timestamp comes from a
+* **Clock-faithful.** Every trace timestamp comes from a
   :class:`~repro.runtime.clock.Clock` — under ``VirtualClock`` a trace
   is bit-for-bit deterministic, so tests assert exact span edges.
-* **Zero cost when off.** Nothing in the hot path allocates unless a
-  tracer was handed to the runtime; instrumented call sites only do a
-  ``getattr(request, "trace", None)`` check.
+* **Cheap when off.** With no profile being taken, no registry passed
+  and no trace current, a span costs one annotation object and a
+  thread-local read: a microsecond or two.
 * **No upward imports.** This module depends only on
-  ``runtime.clock``; the ledger hookup is lazy so ``dist`` stays a
-  leaf layer.
+  ``runtime.clock``; JAX's profiler and the ledger hookup are imported
+  lazily, so ``runtime`` (which imports this module) stays free of JAX
+  at import time and ``dist`` stays a leaf layer.
 
 Span ids and trace ids are deterministic counters (no randomness, no
 wall-clock salt) — resumable tests and virtual-clock runs stay exact.
@@ -30,8 +47,9 @@ from __future__ import annotations
 import contextlib
 import itertools
 import threading
+import time
 from collections import deque
-from typing import Callable, Dict, Iterable, List, Optional
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from repro.runtime.clock import Clock, RealClock
 
@@ -40,11 +58,13 @@ __all__ = [
     "Span",
     "Trace",
     "Tracer",
+    "span",
     "current_span",
+    "current_spans",
     "use_span",
+    "use_spans",
     "plan_attributes",
     "engine_batch_info",
-    "start_layer_span",
     "install_ledger_listener",
 ]
 
@@ -243,9 +263,11 @@ class Tracer:
 
 
 # --------------------------------------------------------------------------
-# Thread-local active span: lets deep call sites (exec.dispatch, the
-# ledger) attach children/events without threading a trace through
-# every signature.
+# Thread-local current spans: let deep call sites (the span primitive,
+# exec.dispatch, the ledger) attach children/events without threading a
+# trace through every signature.  Each stack entry is a tuple of spans:
+# one per request on the submitting thread, one per traced request of
+# the batch on the worker, so a batch's stages land in every trace.
 
 _tls = threading.local()
 
@@ -258,57 +280,107 @@ def _stack() -> list:
     return stack
 
 
-def current_span() -> Optional[Span]:
+def _pop(entry: tuple) -> None:
     stack = _stack()
-    return stack[-1] if stack else None
+    if stack and stack[-1] is entry:
+        stack.pop()
+    elif entry in stack:  # pragma: no cover - unbalanced exit
+        stack.remove(entry)
+
+
+def current_spans() -> Tuple[Span, ...]:
+    stack = _stack()
+    return stack[-1] if stack else ()
+
+
+def current_span() -> Optional[Span]:
+    spans = current_spans()
+    return spans[0] if spans else None
+
+
+@contextlib.contextmanager
+def use_spans(spans: Sequence[Span]):
+    """Make ``spans`` the thread's current spans for the duration (no-op
+    when empty): :func:`span` then opens a child under each of them."""
+    entry = tuple(spans)
+    if not entry:
+        yield entry
+        return
+    _stack().append(entry)
+    try:
+        yield entry
+    finally:
+        _pop(entry)
 
 
 @contextlib.contextmanager
 def use_span(span: Span):
     """Make ``span`` the thread's current span for the duration."""
-    stack = _stack()
-    stack.append(span)
-    try:
+    with use_spans((span,)):
         yield span
-    finally:
-        if stack and stack[-1] is span:
-            stack.pop()
-        elif span in stack:  # pragma: no cover - unbalanced exit
-            stack.remove(span)
 
 
-class _OpenSpan:
-    """Handle for an eagerly-opened layer span: finish() pops + ends."""
-
-    __slots__ = ("span",)
-
-    def __init__(self, span: Span):
-        self.span = span
-
-    def finish(self, at: Optional[float] = None) -> None:
-        stack = _stack()
-        if stack and stack[-1] is self.span:
-            stack.pop()
-        elif self.span in stack:  # pragma: no cover - unbalanced exit
-            stack.remove(self.span)
-        self.span.finish(at=at)
+_TraceAnnotation = None
 
 
-def start_layer_span(plan) -> Optional[_OpenSpan]:
-    """Open an ``execute_layer`` child of the current span, if any.
+def _annotation_cls():
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
 
-    Used by ``exec.dispatch.execute_layer`` on the eager (concrete)
-    path; returns ``None`` when no span is active so the uninstrumented
-    path costs one thread-local read. The opened span becomes current,
-    so ledger records fired inside the layer land on it as events.
-    """
-    cur = current_span()
-    if cur is None:
-        return None
-    span = cur.trace.span("execute_layer", parent=cur,
-                          **plan_attributes(plan))
-    _stack().append(span)
-    return _OpenSpan(span)
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation
+
+
+class span:
+    """``with span("<layer>.<stage>", metrics=None) as s:`` — one layer
+    boundary, written to the profiler, the registry and the current
+    traces (module docstring).  ``s.set(**attrs)`` stamps attributes on
+    the trace children it opened.  Counters and child spans are written
+    whether the body returns or raises."""
+
+    __slots__ = ("name", "metrics", "_ann", "_children", "_t0", "_c0")
+
+    def __init__(self, name: str, metrics=None):
+        self.name = name
+        self.metrics = metrics
+
+    def __enter__(self) -> "span":
+        cls = _TraceAnnotation or _annotation_cls()
+        self._ann = None
+        if cls.is_enabled():          # a profile is being taken
+            self._ann = cls("repro." + self.name)
+            self._ann.__enter__()
+        parents = current_spans()
+        children = ()
+        if parents:
+            stage = self.name.rpartition(".")[2]
+            children = tuple(p.trace.span(stage, parent=p) for p in parents)
+            _stack().append(children)
+        self._children = children
+        if self.metrics is not None:
+            self._c0 = time.thread_time_ns()
+            self._t0 = time.perf_counter_ns()
+        return self
+
+    def set(self, **attrs: object) -> "span":
+        for child in self._children:
+            child.set(**attrs)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self.metrics is not None:
+            wall = time.perf_counter_ns() - self._t0
+            cpu = time.thread_time_ns() - self._c0
+            stage = self.name.rpartition(".")[2]
+            self.metrics.inc_many({f"{stage}_n": 1, f"{stage}_ns": wall,
+                                   f"{stage}_cpu_ns": cpu})
+        if self._children:
+            _pop(self._children)
+            for child in self._children:
+                child.finish()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
 
 
 # --------------------------------------------------------------------------
@@ -338,7 +410,7 @@ def engine_batch_info(engine, bucket) -> dict:
     Returns the dict ``RuntimeLoop`` consumes: ``bucket_key`` /
     ``plan_key`` (the :mod:`repro.obs.feedback` identities measured
     latency is filed under), ``attrs`` for the execute span, and one
-    attribute dict per layer for ``execute_layer`` child spans. Plans
+    attribute dict per layer (the execute span's ``layers``). Plans
     are read from the batcher's caches, so this reflects the plans the
     compiled executable was actually built from.
     """

@@ -32,6 +32,7 @@ import traceback
 from concurrent.futures import InvalidStateError
 from typing import Callable, List, Optional, Sequence
 
+from repro.obs.trace import span, use_spans
 from repro.runtime.clock import Clock, RealClock
 from repro.runtime.metrics import MetricsRegistry, labeled
 from repro.runtime.queue import (
@@ -85,23 +86,31 @@ class RuntimeLoop:
         with self._cond:
             self._cond.notify_all()
 
+    def _close(self, close) -> list:
+        """Run one scheduler close call (``poll``/``flush``) under the
+        ``runtime.close`` span; each batch carries the call's clock
+        readings, which traced requests get as their ``close`` span."""
+        c0 = self.clock.now()
+        with span("runtime.close"):
+            batches = close()
+        edges = (c0, self.clock.now())
+        return [(batch, edges) for batch in batches]
+
     def step(self, now: Optional[float] = None) -> int:
         """One poll-and-execute round on the calling thread."""
         executed = 0
-        for batch in self.scheduler.poll(now):
-            self.execute(batch)
+        for batch, edges in self._close(lambda: self.scheduler.poll(now)):
+            self.execute(batch, close_edges=edges)
             executed += 1
         return executed
 
     def drain(self) -> int:
         """Flush the queue and execute everything inline (sync path)."""
         executed = 0
-        for batch in self.scheduler.poll():
-            self.execute(batch)
-            executed += 1
-        for batch in self.scheduler.flush():
-            self.execute(batch)
-            executed += 1
+        for close in (self.scheduler.poll, self.scheduler.flush):
+            for batch, edges in self._close(close):
+                self.execute(batch, close_edges=edges)
+                executed += 1
         return executed
 
     @staticmethod
@@ -111,22 +120,39 @@ class RuntimeLoop:
             if r.trace is not None:
                 r.trace.finish(status="failed", at=at, error=error)
 
-    def execute(self, batch: ClosedBatch) -> None:
-        """Run one batch; on failure, fail only this batch's futures."""
+    def execute(self, batch: ClosedBatch,
+                close_edges: Optional[tuple] = None) -> None:
+        """Run one batch; on failure, fail only this batch's futures.
+
+        The runner call is the ``runtime.execute`` span (counted in the
+        registry's ``execute_*`` counters); with traced requests in the
+        batch it opens an ``execute`` child in each of their traces, so
+        the batcher's stages nest under it.  ``close_edges`` are the
+        clock readings around the close call that produced ``batch``.
+        """
         live = [r for r in batch.requests if not r.future.cancelled()]
-        traced = any(r.trace is not None for r in live)
+        traced = [r for r in live if r.trace is not None]
         info = None
         if (traced or self.feedback is not None) \
                 and self.batch_info is not None:
             info = self.batch_info(batch)
-        ledger_before = None
-        if traced:
-            from repro.dist.collectives import LEDGER
-
-            ledger_before = (dict(LEDGER.counts), dict(LEDGER.bytes))
+        padded = self.scheduler.padded_width(len(batch.requests),
+                                             batch.bucket)
+        for r in traced:
+            self._trace_close(r, batch, padded, close_edges)
         t0 = self.clock.now()
         try:
-            outputs = self.runner(batch)
+            with use_spans([r.trace.root for r in traced]), \
+                    span("runtime.execute", metrics=self.metrics) as sp:
+                if traced:
+                    info = info or {}
+                    sp.set(bucket_key=info.get("bucket_key"),
+                           plan_key=info.get("plan_key"),
+                           batch_size=len(batch.requests),
+                           padded_batch=padded,
+                           layers=info.get("layers", []),
+                           **info.get("attrs", {}))
+                outputs = self.runner(batch)
         except BaseException as e:  # noqa: BLE001 — must not kill the loop
             for r in live:
                 if not r.future.done():
@@ -156,8 +182,6 @@ class RuntimeLoop:
             self._fail_traces(live, str(err), self.clock.now())
             return
         t1 = self.clock.now()
-        padded = self.scheduler.padded_width(len(batch.requests),
-                                             batch.bucket)
         if self.scheduler.estimator is not None:
             self.scheduler.estimator.observe(batch.bucket, padded, t1 - t0)
         if self.feedback is not None and info and info.get("plan_key"):
@@ -166,17 +190,12 @@ class RuntimeLoop:
             # next warmup's choose_plan consults.
             self.feedback.record(info["bucket_key"], info["plan_key"],
                                  t1 - t0, batch=padded)
-        ledger_delta = []
-        if traced:
-            from repro.dist.collectives import LEDGER
+        with span("runtime.deliver"):
+            self._deliver(batch, outputs, t0, t1)
 
-            before_counts, before_bytes = ledger_before
-            for kind in sorted(set(LEDGER.counts) | set(before_counts)):
-                n = LEDGER.counts.get(kind, 0) - before_counts.get(kind, 0)
-                nbytes = LEDGER.bytes.get(kind, 0.0) \
-                    - before_bytes.get(kind, 0.0)
-                if n > 0 or nbytes != 0.0:
-                    ledger_delta.append((kind, nbytes, n))
+    def _deliver(self, batch: ClosedBatch, outputs: Sequence,
+                 t0: float, t1: float) -> None:
+        """Resolve each request's future and book its completion."""
         for r, out in zip(batch.requests, outputs):
             if r.future.cancelled() or r.future.done():
                 continue
@@ -209,46 +228,30 @@ class RuntimeLoop:
                 self.metrics.observe(
                     labeled("exec_s", servable=r.graph_key), r.exec_s)
             if r.trace is not None:
-                self._trace_completion(r, batch, t0, t1, padded, info,
-                                       ledger_delta, verdict)
+                if verdict is not None:
+                    r.trace.root.set(slo=verdict)
+                r.trace.finish(status="ok", at=t1)
 
-    def _trace_completion(self, r: Request, batch: ClosedBatch,
-                          t0: float, t1: float, padded: int,
-                          info: Optional[dict], ledger_delta,
-                          verdict: Optional[str]) -> None:
-        """Stamp queue-wait / execute / per-layer spans and close the trace.
+    @staticmethod
+    def _trace_close(r: Request, batch: ClosedBatch, padded: int,
+                     close_edges: Optional[tuple]) -> None:
+        """Stamp the queue-wait and close spans of a traced request.
 
-        The queue-wait span is written retroactively (arrival -> batch
-        close, carrying the close reason); the execute span covers the
-        runner call and owns the batch's ledger byte-delta events plus
-        one ``execute_layer`` child per layer with the serving plan's
-        attributes.  All timestamps are exact clock readings the loop
-        already took, so traces are deterministic under ``VirtualClock``.
+        Both are written retroactively from clock readings the runtime
+        already took: queue wait runs from arrival to the batch's close
+        instant (carrying the close reason); close covers the scheduler
+        call that closed the batch.  Exact under ``VirtualClock``.
         """
         trace = r.trace
-        queue_wait = trace.span(
+        trace.span(
             "queue_wait", start=r.arrival,
             close_reason=batch.reason,
             batch_size=len(batch.requests),
-            padded_batch=padded)
-        queue_wait.finish(at=batch.closed_at)
-        info = info or {}
-        execute = trace.span(
-            "execute", start=t0,
-            bucket_key=info.get("bucket_key"),
-            plan_key=info.get("plan_key"),
-            batch_size=len(batch.requests),
-            padded_batch=padded,
-            **info.get("attrs", {}))
-        for kind, nbytes, n in ledger_delta:
-            execute.event("ledger", at=t1, kind=kind, bytes=nbytes, n=n)
-        for i, layer_attrs in enumerate(info.get("layers", ())):
-            trace.span("execute_layer", parent=execute, start=t0,
-                       layer=i, **layer_attrs).finish(at=t1)
-        execute.finish(at=t1)
-        if verdict is not None:
-            trace.root.set(slo=verdict)
-        trace.finish(status="ok", at=t1)
+            padded_batch=padded).finish(at=batch.closed_at)
+        if close_edges is not None:
+            c0, c1 = close_edges
+            trace.span("close", start=c0,
+                       close_reason=batch.reason).finish(at=c1)
 
     # ------------------------------------------------------------------
 
@@ -400,17 +403,6 @@ class ServeRuntime:
         return engine_batch_info(self.engine, batch.bucket)
 
     def _run_batch(self, batch: ClosedBatch) -> List:
-        if self.tracer is not None:
-            # Ledger the batch's modeled DRAM traffic host-side: the AOT
-            # executables were traced long ago, so the per-dispatch
-            # records the eager path makes never fire here.  Gated on
-            # tracing so untraced serving leaves the global LEDGER
-            # exactly as before.
-            self.engine.batcher.record_batch_dram(
-                batch.bucket,
-                self.scheduler.padded_width(len(batch.requests),
-                                            batch.bucket),
-                int(self.engine.features.shape[1]))
         return self.engine.batcher.run(
             self.engine.params, [r.padded for r in batch.requests]
         )
@@ -434,33 +426,35 @@ class ServeRuntime:
         if deadline_s is not None and deadline is not None:
             raise ValueError("pass deadline_s (relative) or deadline "
                              "(absolute), not both")
-        t0 = self.clock.now()
-        key = graph_key if graph_key is not None else self.graph_key
-        abs_deadline = (t0 + deadline_s if deadline_s is not None
-                        else deadline)
-        trace = None
-        if self.tracer is not None:
-            trace = self.tracer.trace(
-                "request", graph_key=key, priority=priority,
-                deadline=abs_deadline, n_seeds=len(seeds))
-        padded = self.engine._prepare(seeds)
-        t_prep = self.clock.now()
-        if trace is not None:
-            trace.span("prepare", start=t0,
-                       bucket=str(padded.bucket)).finish(at=t_prep)
-        req = Request(
-            graph_key=key,
-            seeds=tuple(int(s) for s in seeds),
-            deadline=abs_deadline,
-            priority=priority,
-            trace=trace,
-            bucket=padded.bucket,
-            padded=padded,
-            prep_s=t_prep - t0,
-        )
-        self.queue.submit(req)
-        self.loop.notify()
-        return req
+        with span("runtime.submit"):
+            t0 = self.clock.now()
+            key = graph_key if graph_key is not None else self.graph_key
+            abs_deadline = (t0 + deadline_s if deadline_s is not None
+                            else deadline)
+            trace = None
+            if self.tracer is not None:
+                trace = self.tracer.trace(
+                    "request", graph_key=key, priority=priority,
+                    deadline=abs_deadline, n_seeds=len(seeds))
+            with use_spans([trace.root] if trace is not None else []), \
+                    span("engine.prepare", metrics=self.metrics) as sp:
+                padded = self.engine._prepare(seeds)
+                sp.set(bucket=str(padded.bucket))
+            t_prep = self.clock.now()
+            req = Request(
+                graph_key=key,
+                seeds=tuple(int(s) for s in seeds),
+                deadline=abs_deadline,
+                priority=priority,
+                trace=trace,
+                bucket=padded.bucket,
+                padded=padded,
+                prep_s=t_prep - t0,
+            )
+            with span("runtime.admit"):
+                self.queue.submit(req)
+            self.loop.notify()
+            return req
 
     def cancel(self, request: Request) -> bool:
         ok = self.queue.cancel(request)

@@ -109,6 +109,14 @@ COUNTERS = (
     "batches_flush",        # close reason: explicit flush/drain
     "slo_met",              # completed with deadline, on time
     "slo_missed",           # completed with deadline, late
+    # Span counters (``repro.obs.trace.span(..., metrics=)``): calls,
+    # wall ns and the calling thread's CPU ns of each stage.
+    "prepare_n",            # requests prepared on the submitting thread
+    "prepare_ns",
+    "prepare_cpu_ns",
+    "execute_n",            # batches run through the executable
+    "execute_ns",
+    "execute_cpu_ns",
 )
 
 
@@ -217,6 +225,13 @@ class MetricsRegistry:
     def inc(self, name: str, n: int = 1) -> None:
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + n
+
+    def inc_many(self, increments: Dict[str, int]) -> None:
+        """Several counter increments under one lock acquisition."""
+        with self._lock:
+            c = self._counters
+            for name, n in increments.items():
+                c[name] = c.get(name, 0) + n
 
     def count(self, name: str) -> int:
         with self._lock:

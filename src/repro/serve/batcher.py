@@ -34,6 +34,7 @@ from repro.core.sparse_formats import PAD_COL
 from repro.exec import SpmmOperands, plan_for_config, quant
 from repro.exec.dispatch import execute_layer
 from repro.models.gcn import GCNConfig, GCNGraph
+from repro.obs.trace import span
 from repro.serve.sampler import SampledSubgraph
 
 
@@ -332,44 +333,6 @@ class MicroBatcher:
             self._layer_plans[key] = plans
         return plans
 
-    def record_batch_dram(self, bucket: Bucket, batch: int,
-                          feature_dim: int) -> None:
-        """Ledger the modeled DRAM bytes of one coalesced forward.
-
-        The AOT executables were traced long ago, so the eager path's
-        per-dispatch ``record_spmm_dram`` never fires while serving;
-        this applies the same arithmetic host-side — one record per
-        layer over the coalesced block-diagonal operand at the rung's
-        precision and layer plans — so traced serving requests carry
-        ledgered-bytes span events.  Called by the runtimes only when
-        tracing is on, leaving the global ledger untouched otherwise.
-        """
-        from repro.exec.dispatch import record_spmm_dram
-        from repro.exec.fused import record_combination_dram
-
-        cfg = self.cfg
-        prec = self.precision_for_bucket(bucket)
-        plans = self.layer_plans_for_bucket(bucket, feature_dim)
-        if prec != "f32":
-            plans = [dataclasses.replace(p, precision=prec) for p in plans]
-        rows = int(batch) * bucket.rows
-        nodes = int(batch) * bucket.nodes
-        f_ins = [feature_dim] + [cfg.hidden_dim] * (cfg.n_layers - 1)
-        f_outs = [cfg.hidden_dim] * (cfg.n_layers - 1) + [cfg.out_dim]
-        for plan, f_in, f_out in zip(plans, f_ins, f_outs):
-            if plan.fused and plan.effective_impl != "reference":
-                # Same saved-writeback arithmetic the fused launch
-                # records eagerly: the intermediate activation's
-                # write + read-back (2 * K * F_out elements) never
-                # touches DRAM.
-                from repro.dist.collectives import LEDGER
-
-                ab = quant.activation_bytes(plan.precision)
-                LEDGER.record_fused_writeback(2.0 * nodes * f_out * ab)
-            else:
-                record_combination_dram(plan, nodes, f_in, f_out)
-            record_spmm_dram(plan, rows, cfg.tau, nodes, f_out, nodes)
-
     # ------------------------------------------------------------------
     # Request preparation
     # ------------------------------------------------------------------
@@ -446,6 +409,11 @@ class MicroBatcher:
         mesh = self.mesh
 
         def fwd_impl(params, cols, vals, scales, row_map, feats, seed_pos):
+            with jax.named_scope("gcn_bucket_step"):
+                return step(params, cols, vals, scales, row_map, feats,
+                            seed_pos)
+
+        def step(params, cols, vals, scales, row_map, feats, seed_pos):
             b, rows_b, tau = cols.shape
             f_in = feats.shape[-1]
             if mesh is not None:
@@ -606,22 +574,27 @@ class MicroBatcher:
                 arrs.extend([np.full_like(arrs[0], fill)] * pad)
             return np.stack(arrs)
 
-        feature_dim = reqs[0].feats.shape[1]
-        exe = self.executable(params, bucket, batch, feature_dim)
-        # int8 rungs carry a scales operand (padding slots get scale 1.0:
-        # their vals are all-zero int8, so any scale dequantizes to zero).
-        scale_args = ()
-        if self.precision_for_bucket(bucket) == "int8":
-            scale_args = (stack("scales", 1.0),)
-        out = exe(
-            params,
-            stack("cols", PAD_COL),
-            stack("vals", 0),
-            *scale_args,
-            stack("row_map", -1),
-            stack("feats", 0),
-            stack("seed_pos", -1),
-        )
-        out = np.asarray(out)  # blocks until ready
+        with span("batcher.stack"):
+            # int8 rungs carry a scales operand (padding slots get scale
+            # 1.0: their vals are all-zero int8, so any scale dequantizes
+            # to zero).
+            scale_args = ()
+            if self.precision_for_bucket(bucket) == "int8":
+                scale_args = (stack("scales", 1.0),)
+            args = (
+                stack("cols", PAD_COL),
+                stack("vals", 0),
+                *scale_args,
+                stack("row_map", -1),
+                stack("feats", 0),
+                stack("seed_pos", -1),
+            )
+        with span("batcher.dispatch"):
+            # The executable call, with its host-to-device copies.
+            exe = self.executable(params, bucket, batch,
+                                  reqs[0].feats.shape[1])
+            out = exe(params, *args)
+        with span("batcher.fetch"):
+            out = np.asarray(out)  # blocks until ready
         self.calls += 1
         return [out[i, : r.n_seeds] for i, r in enumerate(reqs)]

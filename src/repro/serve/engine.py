@@ -27,6 +27,7 @@ import numpy as np
 from repro.core.sparse_formats import CSRMatrix
 from repro.exec import plan_for_config
 from repro.models.gcn import GCNConfig, init_params
+from repro.obs.trace import span
 from repro.serve.batcher import BucketLadder, MicroBatcher, PaddedRequest
 from repro.serve.registry import ArtifactRegistry
 from repro.serve.sampler import SubgraphSampler
@@ -320,9 +321,16 @@ class ServeEngine:
 
     def full_forward(self) -> np.ndarray:
         """Full-graph logits for every node (original node order)."""
-        t0 = time.perf_counter()
-        out = np.asarray(self._full_step(self.params, self.features))
-        self._record("full", [time.perf_counter() - t0], self.graph.n_nodes)
+        with span("engine.full_forward"):
+            t0 = time.perf_counter()
+            with span("engine.dispatch"):
+                # The call into the jitted step; the numpy features are
+                # copied to the device inside it.
+                out = self._full_step(self.params, self.features)
+            with span("engine.fetch"):
+                out = np.asarray(out)  # blocks until the logits are home
+            self._record("full", [time.perf_counter() - t0],
+                         self.graph.n_nodes)
         return out
 
     def query(self, seeds: Sequence[int]) -> np.ndarray:
@@ -399,7 +407,8 @@ class ServeEngine:
 
     def _prepare(self, seeds: Sequence[int]) -> PaddedRequest:
         sub = self.sampler.extract(seeds)
-        return self.batcher.prepare(sub, self.features[sub.nodes])
+        with span("batcher.pad"):
+            return self.batcher.prepare(sub, self.features[sub.nodes])
 
     def _record(
         self, scenario: str, lats: List[float], seeds: int,

@@ -132,11 +132,12 @@ class ArtifactRegistry:
             step_plan = plan_pipeline(cfg, graph.pre.ell,
                                       precision=precision,
                                       interpret=interpret)
-        fwd = jax.jit(
-            lambda params, feats: gcn_forward(
-                params, graph, feats, cfg, plan=step_plan,
-                precision=precision)
-        )
+        def gcn_full_step(params, feats):
+            with jax.named_scope("gcn_full_step"):
+                return gcn_forward(params, graph, feats, cfg,
+                                   plan=step_plan, precision=precision)
+
+        fwd = jax.jit(gcn_full_step)
         self._forwards[key] = fwd
         return fwd
 

@@ -24,6 +24,7 @@ import numpy as np
 from repro.core.sparse_formats import CSRMatrix
 from repro.graphs.sampling import induced_subgraph, sample_k_hop
 from repro.models.gcn import GCNConfig, GCNGraph
+from repro.obs.trace import span
 from repro.serve.registry import ArtifactRegistry
 
 
@@ -72,17 +73,24 @@ class SubgraphSampler:
         # sampler state: identical seed sets draw identical neighbor
         # subsets, so their subgraphs content-hash to the same registry
         # entry and repeated queries actually skip the vertex-cut.
-        rng = np.random.default_rng(
-            [self.seed] + sorted(int(s) for s in np.unique(np.asarray(seeds)))
-        )
-        nodes = sample_k_hop(
-            self.adj_norm, seeds, self.hops, fanout=self.fanout, rng=rng
-        )
-        # Positions of the seeds in ``nodes``, preserving request order.
-        seed_local = np.searchsorted(nodes, np.asarray(seeds, dtype=np.int64))
-        sub_adj = induced_subgraph(self.adj_norm, nodes)
-        # Content-keyed: identical node sets reuse the preprocessed operand.
-        graph = self.registry.get_or_build(sub_adj, self.cfg, persist=False)
+        with span("sampler.sample"):
+            rng = np.random.default_rng(
+                [self.seed]
+                + sorted(int(s) for s in np.unique(np.asarray(seeds)))
+            )
+            nodes = sample_k_hop(
+                self.adj_norm, seeds, self.hops, fanout=self.fanout, rng=rng
+            )
+            # Positions of the seeds in ``nodes``, preserving request order.
+            seed_local = np.searchsorted(
+                nodes, np.asarray(seeds, dtype=np.int64))
+        with span("sampler.induce"):
+            sub_adj = induced_subgraph(self.adj_norm, nodes)
+        with span("registry.build"):
+            # Content-keyed (the hash is part of the stage): identical
+            # node sets reuse the preprocessed operand.
+            graph = self.registry.get_or_build(sub_adj, self.cfg,
+                                               persist=False)
         return SampledSubgraph(
             nodes=nodes,
             seed_local=seed_local.astype(np.int64),

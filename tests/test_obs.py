@@ -229,9 +229,9 @@ def _drive(rt, rounds=64):
 
 def test_serve_runtime_yields_complete_traces(toy_engine_parts):
     """Every request through ServeRuntime yields one trace covering the
-    whole vertical — prepare, admission, queue wait, execute with plan
-    attrs and ledgered bytes, one execute_layer child per layer — with
-    exact virtual-clock span edges."""
+    whole vertical — prepare (sample, induce, build, pad), admission,
+    queue wait, close, execute with plan attrs (stack, dispatch, fetch)
+    — with exact virtual-clock span edges."""
     engine = _toy_engine(toy_engine_parts)
     engine.warmup()
     clock = VirtualClock(start=100.0)
@@ -252,37 +252,50 @@ def test_serve_runtime_yields_complete_traces(toy_engine_parts):
         assert trace.root.attributes["slo"] == "slo_met"
         names = [s.name for s in trace.spans]
         for expected in ("request", "prepare", "admission", "queue_wait",
-                         "execute"):
+                         "close", "execute"):
             assert expected in names, f"missing {expected} in {names}"
+
+        [prep] = trace.find("prepare")
+        assert prep.parent_id == trace.root.span_id
+        assert prep.attributes["bucket"] == str(r.bucket)
+        assert prep.start == prep.end == r.arrival
+        for stage in ("sample", "induce", "build", "pad"):
+            [st] = trace.find(stage)
+            assert st.parent_id == prep.span_id
+            assert st.start == st.end == prep.start
 
         [adm] = trace.find("admission")
         assert adm.attributes["verdict"] == "admitted"
         [qw] = trace.find("queue_wait")
+        [close] = trace.find("close")
         [ex] = trace.find("execute")
         # exact virtual-clock edges: wait starts at arrival, ends at the
         # batch close instant, which is also when the (zero-duration
-        # under a virtual clock) execute span runs.
+        # under a virtual clock) close and execute spans run.
         assert qw.start == r.arrival
-        assert qw.end == ex.start == ex.end
+        assert qw.end == close.start == close.end == ex.start == ex.end
         assert qw.attributes["close_reason"] in (
             "full", "deadline", "flush")
+        assert close.attributes["close_reason"] == \
+            qw.attributes["close_reason"]
+        assert ex.parent_id == trace.root.span_id
         assert ex.attributes["bucket_key"] == bucket_key(r.bucket, fdim)
         assert ex.attributes["plan_key"]
         assert ex.attributes["impl"] == "reference"
         assert ex.attributes["precision"] == "f32"
         assert ex.attributes["mesh_width"] == 1
-        # ledgered bytes: the batch's modeled DRAM records land on the
-        # execute span as events
-        ledger = [ev for ev in ex.events if ev.name == "ledger"]
-        assert ledger and all(ev.attributes["bytes"] > 0 for ev in ledger)
-        assert {ev.attributes["kind"] for ev in ledger} >= {"spmm_dram"}
-
-        layers = trace.find("execute_layer")
+        # one plan-attribute dict per layer rides on the execute span
+        layers = ex.attributes["layers"]
         assert len(layers) == engine.cfg.n_layers
-        for i, ls in enumerate(layers):
-            assert ls.attributes["layer"] == i
-            assert ls.attributes["impl"] == "reference"
-            assert ls.parent_id == ex.span_id
+        assert all(ls["impl"] == "reference" for ls in layers)
+        # the batcher's stages nest under execute, in order
+        stages = [s for s in trace.spans if s.parent_id == ex.span_id]
+        assert [s.name for s in stages] == ["stack", "dispatch", "fetch"]
+        assert all(s.start == s.end == ex.start for s in stages)
+        # no fabricated per-layer spans or modeled-byte events
+        assert not trace.find("execute_layer")
+        assert not any(ev.name == "ledger" for s in trace.spans
+                       for ev in s.events)
     rt.shutdown()
 
 

@@ -158,3 +158,53 @@ class _Stats:
 
     def __init__(self, rows):
         self.padded_rows, self.tau = rows, TAU
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_steps_name_their_kernels_and_scopes(shape, fused):
+    """The full-graph step (block-skipping grid) and a served bucket step
+    (masked dense grid) carry the kernels' names and the steps' scopes
+    into the compiled HLO, where the profiler's op names come from."""
+    import dataclasses
+
+    import jax
+    import numpy as np
+
+    from repro.graphs.datasets import (
+        DatasetSpec,
+        gcn_normalize,
+        synthesize_adjacency,
+    )
+    from repro.models.gcn import GCNConfig
+    from repro.serve import ServeEngine
+
+    spec = DatasetSpec("toy", nodes=400, edges=1_600, feature_dim=32,
+                       classes=5)
+    adj = gcn_normalize(synthesize_adjacency(spec, seed=7))
+    feats = np.zeros((spec.nodes, spec.feature_dim), np.float32)
+    cfg = GCNConfig(in_dim=32, hidden_dim=16, out_dim=5,
+                    spmm_impl="pallas_sparse")
+    engine = ServeEngine(adj, feats, cfg, interpret=False, fused=fused,
+                         fanout=4, max_seeds=4, max_batch=2,
+                         base_bucket_nodes=128)
+    on = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: shape(a.shape, a.dtype), tree)
+    prefix = "flexvector_fused_" if fused else "flexvector_"
+
+    full = engine.registry.forward_step(
+        adj, cfg, plan=dataclasses.replace(engine.full_plan, fused=fused))
+    text = _compile(full, on(engine.params), shape(feats.shape, feats.dtype))
+    assert f"%{prefix}sparse_grid" in text
+    assert "gcn_full_step/" in text
+    if not fused:
+        assert "gcn_full_step/combine/" in text
+    assert "/aggregate/" in text and "/fold/" in text
+
+    batcher = engine.batcher
+    bucket = batcher.ladder.entries[0]
+    avals = batcher._avals(engine.params, bucket, 2, spec.feature_dim)
+    text = _compile(batcher._make_forward(bucket, spec.feature_dim),
+                    *on(avals))
+    assert f"%{prefix}dense_grid" in text
+    assert "gcn_bucket_step/" in text
+    assert "/aggregate/" in text and "/fold/" in text
