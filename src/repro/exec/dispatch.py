@@ -75,9 +75,9 @@ def sub_row_products(
             cols_p,
             vals_p,
             dense_p,
-            jnp.asarray(grid.pairs[:, 0], jnp.int32),
-            jnp.asarray(grid.pairs[:, 1], jnp.int32),
-            jnp.asarray(grid.first_k.astype(np.int32)),
+            grid.pairs[:, 0],   # host arrays: the resident launch's
+            grid.pairs[:, 1],   # run offsets become a constant
+            grid.first_k.astype(np.int32),
             block_rows=plan.block_rows,
             block_k=plan.block_k,
             block_f=plan.block_f,

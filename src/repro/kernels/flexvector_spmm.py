@@ -20,15 +20,33 @@ Two launch schedules:
 * ``spmm_ell_sparse_grid`` — block-skipping schedule: a scalar-prefetched
   (row_block, k_tile) pair list visits only non-empty cells, the grid-level
   analogue of never issuing MV_Dyn for absent rows.  Hot k-tiles are
-  ordered first within each row block (``hot_k_first``) so high-reuse dense
-  tiles stay VMEM-resident — the VRF fixed region, at tile granularity.
+  ordered first within each row block (``hot_k_first``).  It runs one of
+  two launches, chosen from the operands' size:
+
+  - ``flexvector_sparse_grid_resident`` (``sparse_grid_resident``) while
+    the dense (K, BF) column slab fits ``RESIDENT_VMEM_BUDGET``: the slab
+    is one single-buffered block, DMA'd once per f-tile and kept in VMEM —
+    the flexible VRF's fixed region, at slab granularity.  One grid step
+    per row block builds the row block's expansion tables once (the
+    broadcasts of its ELL slabs, which do not depend on the k-tile) and
+    loops over its visits, slicing each k-tile from the slab.
+  - ``flexvector_sparse_grid`` (the streamed schedule) past it, as for
+    reddit's 119 MB f32 slab: one grid step per pair, each DMA-ing its
+    own (BK, BF) dense tile, so high-reuse hot tiles stay VMEM-resident —
+    the VRF fixed region, at tile granularity.
+
+  Both visit the same pairs in the same order through the same expansion
+  and dot, so their outputs are bitwise equal.
 
 VMEM budget per grid step (dtype bytes b): BR*128*(4+b) sparse table
 (the tau lanes pad to 128) + BK*BF*b dense tile + BR*BF*4 accumulator +
 BR*BK*4 scratch.  The defaults (BR=BK=BF=128, f32) total about 0.5 MiB
 with double-buffered inputs, well inside the 16 MiB of scoped VMEM a v5e
-kernel gets by default.  The fused kernels hold a whole (R, BF) slab
-instead; ``plan.cost.fused_vmem_bytes`` counts it.
+kernel gets by default.  The resident launch holds the K*BF*b slab, the
+double-buffered ELL slabs and out block, and two (tau, BR, BK) expansion
+tables (``resident_vmem_bytes``): at pubmed f32 that is 10.2 MB of slab
+and 1.1 MB besides.  The fused kernels hold a whole (R, BF) slab instead;
+``plan.cost.fused_vmem_bytes`` counts it.
 """
 
 from __future__ import annotations
@@ -47,6 +65,29 @@ def _acc_dtype(dtype) -> jnp.dtype:
     return jnp.int32 if jnp.issubdtype(dtype, jnp.integer) else jnp.float32
 
 
+def _expansion_tables(cols, vals, block_k, acc_dtype):
+    """The k-tile-independent half of the expansion, per ELL slot ``t``:
+    ``offs[t] = cols[:, t] - iota`` and ``vals[:, t]``, each broadcast
+    along the (BR, BK) block's lanes."""
+    br, tau = cols.shape
+    iota = jax.lax.broadcasted_iota(jnp.int32, (br, block_k), 1)
+    offs = [cols[:, t][:, None] - iota for t in range(tau)]
+    vals = [jnp.broadcast_to(vals[:, t].astype(acc_dtype)[:, None],
+                             (br, block_k)) for t in range(tau)]
+    return offs, vals
+
+
+def _expand_tile(offs, vals, kb_base, tau):
+    """Scatter a bounded-RNZ sparse block into a dense (BR, BK) block from
+    its expansion tables (lists, or ``(tau, BR, BK)`` refs): slot ``t``
+    lands in lane ``cols - kb_base``; entries whose column falls outside
+    [kb_base, kb_base + BK) — including PAD_COL — drop out."""
+    a_blk = jnp.zeros(offs[0].shape, vals[0].dtype)
+    for t in range(tau):                                     # tau is static
+        a_blk = a_blk + jnp.where(offs[t] == kb_base, vals[t], 0)
+    return a_blk
+
+
 def _expand_block(cols, vals, kb_base, block_k, acc_dtype):
     """Scatter a bounded-RNZ sparse block into a dense (BR, BK) block.
 
@@ -54,14 +95,8 @@ def _expand_block(cols, vals, kb_base, block_k, acc_dtype):
     falls outside [kb_base, kb_base + block_k) — including PAD_COL — drop
     out via the iota-compare mask.
     """
-    br, tau = cols.shape
-    local = cols - kb_base                                   # (BR, tau)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (br, block_k), 1)
-    a_blk = jnp.zeros((br, block_k), acc_dtype)
-    for t in range(tau):                                     # tau is static
-        onehot = (iota == local[:, t][:, None]).astype(acc_dtype)
-        a_blk = a_blk + onehot * vals[:, t].astype(acc_dtype)[:, None]
-    return a_blk
+    offs, vals = _expansion_tables(cols, vals, block_k, acc_dtype)
+    return _expand_tile(offs, vals, kb_base, cols.shape[1])
 
 
 def _split_scales(refs, scaled):
@@ -217,12 +252,24 @@ def spmm_ell_sparse_grid(
     enables int8 dequantize-on-load, as in :func:`spmm_ell_dense_grid`.
     The three prefetched lists live in SMEM, which bounds ``n_steps``
     (about 40,000 steps fit a v5e core's 1 MiB SMEM; 400,000 do not).
+
+    While :func:`resident_vmem_bytes` fits ``RESIDENT_VMEM_BUDGET`` the
+    launch is :func:`sparse_grid_resident`: the same visits in the same
+    order, through the same expansion and dot, so the outputs are bitwise
+    equal.  ``first`` is then not read.
     """
     r, tau = cols.shape
     k, f = dense.shape
     if r % block_rows or k % block_k or f % block_f:
         raise ValueError("operands must be padded to block multiples")
     out_dtype = out_dtype or _acc_dtype(dense.dtype)
+    if resident_vmem_bytes(
+            k, tau, block_rows=block_rows, block_k=block_k, block_f=block_f,
+            dtype=dense.dtype, out_dtype=out_dtype) <= RESIDENT_VMEM_BUDGET:
+        return sparse_grid_resident(
+            cols, vals, dense, rb_ids, kb_ids, block_rows=block_rows,
+            block_k=block_k, block_f=block_f, out_dtype=out_dtype,
+            interpret=interpret, scales=scales)
     n_steps = int(rb_ids.shape[0])
     ell_spec = pl.BlockSpec(
         (block_rows, tau), lambda fi, s, rb, kb, fs: (rb[s], 0)
@@ -251,6 +298,125 @@ def spmm_ell_sparse_grid(
         interpret=_default_interpret(interpret),
         name="flexvector_sparse_grid",
     )(rb_ids, kb_ids, first, *args)
+
+
+# A v5e TensorCore has 128 MiB of VMEM and a kernel gets a 16 MiB scope of
+# it unless it asks for more.  The resident launch asks for its footprint
+# plus that scope, and is taken while the footprint stays within half the
+# VMEM (``tests/test_tpu_compile.py`` compiles one at this edge): pubmed's
+# f32 dense slab (10.2 MB) fits, reddit's (119 MB) does not.
+RESIDENT_VMEM_BUDGET = 64 * 2**20
+_DEFAULT_SCOPED_VMEM = 16 * 2**20
+# Visits the resident loop expands and multiplies before it adds their
+# products, in order, into the out block: the MXU work of one overlaps
+# the next one's expansion.
+_VISITS_PER_ITER = 4
+
+
+def resident_vmem_bytes(k, tau, *, block_rows, block_k, block_f, dtype,
+                        out_dtype) -> int:
+    """VMEM of :func:`sparse_grid_resident` for a ``(k, ·)`` dense operand
+    of ``dtype`` and ``tau`` ELL slots: the single-buffered ``(k,
+    block_f)`` slab, the double-buffered ELL slabs (the tau lanes pad to
+    128) and out block, and the two ``(tau, block_rows, block_k)``
+    expansion tables."""
+    lanes = -(-tau // 128) * 128
+    slab = k * block_f * jnp.dtype(dtype).itemsize
+    ell = 2 * 2 * block_rows * lanes * 4
+    out = 2 * block_rows * block_f * jnp.dtype(out_dtype).itemsize
+    return slab + ell + out + 2 * tau * block_rows * block_k * 4
+
+
+def _resident_kernel(kb_ids_ref, starts_ref, *refs, block_k, scaled):
+    """One row block: build its expansion tables once, then run its
+    visits ``kb_ids[starts[rb]:starts[rb + 1]]`` in the list's order,
+    ``_VISITS_PER_ITER`` at a time and the remainder one by one."""
+    scales_ref, (cols_ref, vals_ref, dense_ref, out_ref, offs_ref,
+                 vtab_ref) = _split_scales(refs, scaled)
+    rb = pl.program_id(1)
+    tau = cols_ref.shape[1]
+    acc = _acc_dtype(out_ref.dtype)
+    scale = None if scales_ref is None else scales_ref[rb].astype(acc)
+    offs, vals = _expansion_tables(cols_ref[...], vals_ref[...], block_k, acc)
+    for t in range(tau):
+        offs_ref[t], vtab_ref[t] = offs[t], vals[t]
+    out_ref[...] = jnp.zeros_like(out_ref)
+
+    def product(s):
+        kb = kb_ids_ref[s]
+        a_blk = _expand_tile(offs_ref, vtab_ref, kb * block_k, tau)
+        if scale is not None:
+            a_blk = a_blk * scale
+        tile = dense_ref[pl.ds(pl.multiple_of(kb * block_k, block_k),
+                               block_k), :]
+        return jax.lax.dot_general(
+            a_blk, tile.astype(acc), (((1,), (0,)), ((), ())),
+            preferred_element_type=out_ref.dtype,
+        )
+
+    def visits(i, carry):
+        s = start + i * _VISITS_PER_ITER
+        for p in [product(s + j) for j in range(_VISITS_PER_ITER)]:
+            out_ref[...] += p
+        return carry
+
+    def visit(s, carry):
+        out_ref[...] += product(s)
+        return carry
+
+    start, stop = starts_ref[rb], starts_ref[rb + 1]
+    n_iter = (stop - start) // _VISITS_PER_ITER
+    jax.lax.fori_loop(0, n_iter, visits, 0)
+    jax.lax.fori_loop(start + n_iter * _VISITS_PER_ITER, stop, visit, 0)
+
+
+def sparse_grid_resident(cols, vals, dense, rb_ids, kb_ids, *, block_rows,
+                         block_k, block_f, out_dtype, interpret,
+                         scales) -> jax.Array:
+    """Resident launch: grid (f-tile, row block).  The whole ``(K,
+    block_f)`` dense slab is a single-buffered block, DMA'd once per
+    f-tile and kept in VMEM; each step runs its row block's visits of the
+    pair list in order, slicing each k-tile from the slab.  The run
+    offsets ``starts`` come from ``rb_ids`` on the host when it is
+    concrete (inside ``shard_map`` it is not, and they are searched on
+    the device)."""
+    r, tau = cols.shape
+    k, f = dense.shape
+    with jax.ensure_compile_time_eval():
+        starts = jnp.searchsorted(
+            rb_ids, jnp.arange(r // block_rows + 1)).astype(jnp.int32)
+    ell_spec = pl.BlockSpec((block_rows, tau), lambda fi, rb, kb, st: (rb, 0))
+    dense_spec = pl.BlockSpec((k, block_f), lambda fi, rb, kb, st: (0, fi),
+                              pipeline_mode=pl.Buffered(1))
+    in_specs, args = _with_scales(
+        [ell_spec, ell_spec, dense_spec], (cols, vals, dense), scales, r,
+        block_rows,
+    )
+    acc = _acc_dtype(out_dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(f // block_f, r // block_rows),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec(
+            (block_rows, block_f), lambda fi, rb, kb, st: (rb, fi)
+        ),
+        scratch_shapes=[pltpu.VMEM((tau, block_rows, block_k), jnp.int32),
+                        pltpu.VMEM((tau, block_rows, block_k), acc)],
+    )
+    vmem = resident_vmem_bytes(
+        k, tau, block_rows=block_rows, block_k=block_k, block_f=block_f,
+        dtype=dense.dtype, out_dtype=out_dtype)
+    return pl.pallas_call(
+        functools.partial(
+            _resident_kernel, block_k=block_k, scaled=scales is not None
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((r, f), out_dtype),
+        interpret=_default_interpret(interpret),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem + _DEFAULT_SCOPED_VMEM),
+        name="flexvector_sparse_grid_resident",
+    )(kb_ids, starts, *args)
 
 
 def _combine_tile(x_ref, w_ref, b_ref, kb, block_k, k_real, cast_xw):

@@ -125,3 +125,111 @@ def test_pad_operands_alignment():
     assert cols.shape[0] % 32 == 0
     assert dense_p.shape[0] % 32 == 0 and dense_p.shape[1] % 16 == 0
     assert (np.asarray(cols[res.ell.padded_rows:]) == -1).all()
+
+
+def _parity_operands(case, br, bk, f):
+    """``[(cols, vals, rb_ids, kb_ids, first, k)]`` for the launch-parity
+    test: an ELL with all-empty row blocks, or each shard of a two-way
+    split whose shorter pair list is padded with no-op visits to the
+    trailing empty row block, as the sharded path pads it."""
+    import dataclasses
+
+    from repro.core.sparse_formats import PAD_COL
+    from repro.exec import SpmmPlan
+    from repro.exec.operands import SpmmOperands, shard_operands
+    from repro.exec.sharded import _padded_shard_schedules
+
+    res, _ = _problem(96, 800, 5, f, seed=11)
+    ell = res.ell
+    if case == "empty_row_blocks":
+        cols, vals = ell.cols.copy(), ell.vals.copy()
+        cols[br:3 * br], vals[br:3 * br] = PAD_COL, 0
+        ell = dataclasses.replace(ell, cols=cols, vals=vals)
+        g = plan_kernel_grid(ell, f, block_rows=br, block_k=bk, block_f=bk)
+        assert (ell.block_occupancy(br, bk).sum(axis=1) == 0).sum() >= 2
+        return [(ell.cols, ell.vals, g.pairs[:, 0], g.pairs[:, 1],
+                 g.first_k.astype(np.int32), ell.n_dense_rows)]
+    sh = shard_operands(SpmmOperands.from_ell(ell), 2, block_rows=br,
+                        reserve_empty_block=True)
+    plan = SpmmPlan(block_rows=br, block_k=bk, block_f=bk)
+    rb, kb, first = _padded_shard_schedules(plan, sh, f)
+    n, per = len(rb) // 2, sh.rows_per_shard
+    shards = [(sh.cols[s * per:(s + 1) * per], sh.vals[s * per:(s + 1) * per],
+               rb[s * n:(s + 1) * n], kb[s * n:(s + 1) * n],
+               first[s * n:(s + 1) * n], ell.n_dense_rows) for s in range(2)]
+    empty_rb = per // br - 1
+    assert any((s[2][-2:] == empty_rb).all() and s[4][-1] == 0
+               for s in shards), "no shard got no-op visits"
+    return shards
+
+
+@pytest.mark.parametrize("case", ["empty_row_blocks", "shard_padding"])
+@pytest.mark.parametrize("precision", ["f32", "bf16", "int8"])
+def test_resident_and_streamed_launches_bitwise_equal(precision, case,
+                                                      monkeypatch):
+    """The resident launch visits the streamed launch's pairs in the same
+    order through the same expansion and dot, so its sub-row products are
+    bitwise equal."""
+    from repro.exec import quant
+    from repro.kernels import flexvector_spmm as fv
+
+    br = bk = 16
+    f = 24
+    for cols, vals, rb, kb, first, k in _parity_operands(case, br, bk, f):
+        dense = np.random.default_rng(k).standard_normal((k, f))
+        scales = None
+        if precision == "int8":
+            vals, scales = quant.quantize_values(vals, br)
+            scales = jnp.asarray(scales)
+        elif precision == "bf16":
+            vals = jnp.asarray(vals, jnp.bfloat16)
+        dense = quant.cast_dense(jnp.asarray(dense, jnp.float32), precision)
+        c, v, d, _ = pad_operands(cols, vals, dense, br, bk, bk)
+        run = lambda: np.asarray(fv.spmm_ell_sparse_grid(  # noqa: E731
+            c, v, d, rb, kb, first, block_rows=br, block_k=bk, block_f=bk,
+            interpret=True, scales=scales))
+        resident = run()
+        monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", 0)
+        streamed = run()
+        monkeypatch.undo()
+        assert np.abs(streamed).max() > 0
+        np.testing.assert_array_equal(resident, streamed)
+
+
+@pytest.mark.parametrize("visits_per_iter", [1, 2, 3])
+def test_resident_launch_keeps_the_visit_order(visits_per_iter,
+                                               monkeypatch):
+    """However many visits one loop iteration runs, their products are
+    added in the pair list's order: the result is bitwise equal to the
+    streamed launch on a row block with an odd number of visits."""
+    from repro.kernels import flexvector_spmm as fv
+
+    res, dense = _problem(96, 900, 6, 16, seed=4)
+    g = plan_kernel_grid(res.ell, 16, block_rows=16, block_k=16, block_f=16)
+    assert (np.bincount(g.pairs[:, 0]) % 2 == 1).any()
+    c, v, d, _ = pad_operands(res.ell.cols, res.ell.vals,
+                              jnp.asarray(dense), 16, 16, 16)
+    args = (c, v, d, g.pairs[:, 0], g.pairs[:, 1],
+            g.first_k.astype(np.int32))
+    kw = dict(block_rows=16, block_k=16, block_f=16, interpret=True)
+    monkeypatch.setattr(fv, "_VISITS_PER_ITER", visits_per_iter)
+    resident = np.asarray(fv.spmm_ell_sparse_grid(*args, **kw))
+    monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", 0)
+    streamed = np.asarray(fv.spmm_ell_sparse_grid(*args, **kw))
+    np.testing.assert_array_equal(resident, streamed)
+
+
+@pytest.mark.parametrize("nodes,resident", [(19_717, True),
+                                            (232_965, False)])
+def test_sparse_grid_launch_follows_the_dense_slab(nodes, resident):
+    """pubmed's f32 dense slab fits the VMEM budget and runs resident;
+    reddit's 119 MB slab does not and streams its tiles."""
+    from repro.kernels import flexvector_spmm as fv
+
+    k = -(-nodes // 128) * 128
+    need = fv.resident_vmem_bytes(k, 6, block_rows=128, block_k=128,
+                                  block_f=128, dtype=jnp.float32,
+                                  out_dtype=jnp.float32)
+    assert (need <= fv.RESIDENT_VMEM_BUDGET) == resident
+    if resident:   # the module docstring's figures: 10.2 MB + 1.1 MB
+        assert need == 10_158_080 + 1_179_648

@@ -6,7 +6,9 @@ SMEM, a kernel past VMEM.  These tests compile each kernel for a
 described (not attached) v5e chip, at pubmed's Table III size cut into
 the serving config's tiles: 25,984 vertex-cut rows of ``tau=6``, 19,717
 dense rows padded to 19,840, 500 input features, 128-wide feature
-tiles, and the 14,809-step block-skipping pair list pubmed's ELL gets.
+tiles, and the 14,809-step block-skipping pair list pubmed's ELL gets
+(the streamed sparse grid; at pubmed size the sparse grid runs its
+VMEM-resident launch, which needs no pair list).
 
 The topology is described inside a fixture, never at import, so only
 the test worker that runs this file loads the TPU compiler; the
@@ -94,11 +96,15 @@ def test_dense_grid_compiles(shape, precision):
 
 
 @pytest.mark.parametrize("precision", PRECISIONS)
-def test_sparse_grid_compiles(shape, precision):
+def test_sparse_grid_compiles(shape, precision, monkeypatch):
+    """The streamed launch (the path for dense slabs past the resident
+    VMEM budget, reached here by a zero budget): one grid step per pair,
+    the pair list in SMEM."""
     import jax.numpy as jnp
 
     from repro.kernels import flexvector_spmm as fv
 
+    monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", 0)
     vdt, adt = _dtypes(precision)
     steps = shape((PAIRS,), jnp.int32)
     text = _compile(
@@ -108,7 +114,49 @@ def test_sparse_grid_compiles(shape, precision):
         shape((ROWS, TAU), jnp.int32), shape((ROWS, TAU), vdt),
         shape((K, F), adt), steps, steps, steps,
         _scales(shape, precision))
-    assert "tpu_custom_call" in text
+    assert "%flexvector_sparse_grid" in text
+    assert "%flexvector_sparse_grid_resident" not in text
+
+
+def _resident(shape, precision, k=K, rows=ROWS):
+    import jax.numpy as jnp
+
+    from repro.kernels import flexvector_spmm as fv
+
+    vdt, adt = _dtypes(precision)
+    steps = shape((PAIRS,), jnp.int32)
+    return _compile(
+        lambda c, v, d, rb, kb, fs, *s: fv.spmm_ell_sparse_grid(
+            c, v, d, rb, kb, fs, interpret=False,
+            scales=s[0] if s else None),
+        shape((rows, TAU), jnp.int32), shape((rows, TAU), vdt),
+        shape((k, F), adt), steps, steps, steps,
+        _scales(shape, precision, rows))
+
+
+@pytest.mark.parametrize("precision", PRECISIONS)
+def test_sparse_grid_resident_compiles(shape, precision):
+    """At pubmed size the sparse grid runs the resident launch: the
+    dense slab single-buffered in VMEM, one grid step per row block."""
+    assert "%flexvector_sparse_grid_resident" in _resident(shape, precision)
+
+
+def test_largest_resident_slab_compiles(shape):
+    """``RESIDENT_VMEM_BUDGET`` is sound at its edge: the largest dense
+    operand it admits compiles with the resident launch."""
+    import jax.numpy as jnp
+
+    from repro.kernels import flexvector_spmm as fv
+
+    k = K
+    while fv.resident_vmem_bytes(
+            k + BLOCK, TAU, block_rows=BLOCK, block_k=BLOCK, block_f=F,
+            dtype=jnp.float32, out_dtype=jnp.float32) \
+            <= fv.RESIDENT_VMEM_BUDGET:
+        k += BLOCK
+    assert k > 4 * K
+    text = _resident(shape, "f32", k=k, rows=8 * BLOCK)
+    assert "%flexvector_sparse_grid_resident" in text
 
 
 def _fused(shape, kind, precision, rows, f_in=F_IN):
@@ -197,6 +245,7 @@ def test_steps_name_their_kernels_and_scopes(shape, fused):
     assert f"%{prefix}sparse_grid" in text
     assert "gcn_full_step/" in text
     if not fused:
+        assert "%flexvector_sparse_grid_resident" in text
         assert "gcn_full_step/combine/" in text
     assert "/aggregate/" in text and "/fold/" in text
 
@@ -208,3 +257,38 @@ def test_steps_name_their_kernels_and_scopes(shape, fused):
     assert f"%{prefix}dense_grid" in text
     assert "gcn_bucket_step/" in text
     assert "/aggregate/" in text and "/fold/" in text
+
+
+def test_sharded_full_step_compiles(topo):
+    """The full-graph step sharded over a described 2x2 v5e (a 4-wide
+    data mesh): each shard's SpMM runs the resident launch under
+    ``shard_map``."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.exec import plan_for_config
+    from repro.graphs.datasets import (
+        DatasetSpec,
+        gcn_normalize,
+        synthesize_adjacency,
+    )
+    from repro.models.gcn import GCNConfig, GCNGraph, gcn_forward, \
+        init_params
+
+    spec = DatasetSpec("toy", nodes=1_000, edges=4_000, feature_dim=32,
+                       classes=5)
+    adj = gcn_normalize(synthesize_adjacency(spec, seed=7))
+    cfg = GCNConfig(in_dim=32, hidden_dim=16, out_dim=5,
+                    spmm_impl="pallas_sparse")
+    graph = GCNGraph.build(adj, cfg)
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    plan = plan_for_config(cfg, mesh=mesh, interpret=False)
+    on = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        a.shape, a.dtype, sharding=NamedSharding(mesh, PartitionSpec()))
+    params = jax.tree.map(on, jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    feats = on(jax.ShapeDtypeStruct((spec.nodes, 32), np.float32))
+    text = _compile(
+        lambda p, x: gcn_forward(p, graph, x, cfg, plan=plan), params, feats)
+    assert "%flexvector_sparse_grid_resident" in text
