@@ -37,7 +37,7 @@ def main() -> None:
                      edge_cut="rcm", pad_rows_to=128)
     print(f"preprocess: {time.perf_counter() - t0:.2f}s -> "
           f"{pre.ell.padded_rows} sub-rows, tau={pre.ell.tau}, "
-          f"{len(pre.tiles)} tiles")
+          f"{-(-ds.spec.nodes // pre.tile_rows)} tiles")
 
     # 2. aggregation SpMM: A_hat @ X
     x = jnp.asarray(ds.features[pre.perm])

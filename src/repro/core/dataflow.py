@@ -19,8 +19,7 @@ is outermost, and hot k-tiles lead so they stay VMEM-resident (DESIGN.md §2).
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Tuple
-
+import jax
 import numpy as np
 
 from repro.core.sparse_formats import TiledELL, _ceil_div
@@ -71,21 +70,54 @@ def plan_buffer(
 class KernelGrid:
     """Grid schedule for the Pallas kernel.
 
-    ``pairs`` enumerates the non-empty (row_block, k_tile) cells in
-    output-stationary order (all k-tiles of a row block consecutively,
-    hot k-tiles first); ``first_k`` flags the first visit of each row block
-    so the kernel zero-initializes its accumulator there.
+    The non-empty (row_block, k_tile) cells in output-stationary order:
+    row block ``rb`` visits the k-tiles ``kb_ids[starts[rb]:starts[rb +
+    1]]`` (hot k-tiles first with ``hot_k_first``), and every row block is
+    visited at least once so the kernel zero-initializes its output there.
+    A pytree over ``starts`` and ``kb_ids``, so a jitted step can take a
+    planned grid's arrays as an argument.
     """
 
     block_rows: int
     block_k: int
     block_f: int
-    pairs: np.ndarray     # (n_steps, 2) int32 [row_block, k_tile]
-    first_k: np.ndarray   # (n_steps,) bool
+    starts: np.ndarray    # (n_row_blocks + 1,) int32 run offsets
+    kb_ids: np.ndarray    # (n_steps,) int32 k-tile of each visit
     n_row_blocks: int
     n_k_tiles: int
     n_f_tiles: int
     density: float        # visited fraction of the dense grid
+    hot_k_first: bool = True
+
+    def fits(self, plan) -> bool:
+        """Was this grid planned for ``plan``'s blocks and k-order?"""
+        return (self.block_rows, self.block_k, self.hot_k_first) == (
+            plan.block_rows, plan.block_k, plan.hot_k_first)
+
+    @property
+    def pairs(self) -> np.ndarray:
+        """``(n_steps, 2)`` int32 ``[row_block, k_tile]`` per visit."""
+        rb = np.repeat(np.arange(self.n_row_blocks, dtype=np.int32),
+                       np.diff(self.starts))
+        return np.stack([rb, self.kb_ids], axis=1)
+
+
+jax.tree_util.register_dataclass(
+    KernelGrid, data_fields=["starts", "kb_ids"],
+    meta_fields=["block_rows", "block_k", "block_f", "n_row_blocks",
+                 "n_k_tiles", "n_f_tiles", "density", "hot_k_first"])
+
+
+def _k_order(ell: TiledELL, n_kb: int, block_k: int,
+             hot_k_first: bool) -> np.ndarray:
+    """k-tiles densest (hottest) first, ties in index order, so the
+    leading tiles are shared across row blocks; else index order."""
+    if not hot_k_first:
+        return np.arange(n_kb)
+    valid = ell.cols != -1
+    counts = np.bincount((ell.cols[valid] // block_k).ravel(),
+                         minlength=n_kb)
+    return np.argsort(-counts, kind="stable")
 
 
 def plan_kernel_grid(
@@ -102,39 +134,27 @@ def plan_kernel_grid(
     n_rb, n_kb = occ.shape
     if not skip_empty:
         occ = np.ones_like(occ)
-    # Order k-tiles within each row block: densest (hottest) first so the
-    # leading tiles are shared across row blocks and stay VMEM-resident.
-    if hot_k_first:
-        valid = ell.cols != -1
-        kb_of = np.where(valid, ell.cols // block_k, 0)
-        counts = np.bincount(kb_of[valid].ravel(), minlength=n_kb)
-        k_order = np.argsort(-counts, kind="stable")
-    else:
-        k_order = np.arange(n_kb)
-
-    pairs: List[Tuple[int, int]] = []
-    first: List[bool] = []
-    for rb in range(n_rb):
-        started = False
-        for kb in k_order:
-            if occ[rb, kb]:
-                pairs.append((rb, int(kb)))
-                first.append(not started)
-                started = True
-        if not started:  # keep every row block visited once to zero its out
-            pairs.append((rb, int(k_order[0]) if n_kb else 0))
-            first.append(True)
-    pairs_arr = np.asarray(pairs, dtype=np.int32).reshape(-1, 2)
+    k_order = _k_order(ell, n_kb, block_k, hot_k_first)
+    # A row block with no occupied k-tile still gets one visit (to the
+    # first tile of the order) to zero its output.
+    occ = occ[:, k_order]
+    if n_kb:
+        occ[~occ.any(axis=1), 0] = True
+    rb, pos = np.nonzero(occ)                 # row-major: rb, then order
+    counts = np.bincount(rb, minlength=n_rb)
+    starts = np.zeros(n_rb + 1, dtype=np.int32)
+    np.cumsum(counts, out=starts[1:])
     return KernelGrid(
         block_rows=block_rows,
         block_k=block_k,
         block_f=block_f,
-        pairs=pairs_arr,
-        first_k=np.asarray(first, dtype=bool),
+        starts=starts,
+        kb_ids=k_order[pos].astype(np.int32),
         n_row_blocks=n_rb,
         n_k_tiles=n_kb,
         n_f_tiles=_ceil_div(feature_dim, block_f),
-        density=float(len(pairs)) / float(max(n_rb * n_kb, 1)),
+        density=float(starts[-1]) / float(max(n_rb * n_kb, 1)),
+        hot_k_first=hot_k_first,
     )
 
 
@@ -156,14 +176,7 @@ def plan_fused_k_schedule(
     accumulate every output element through bitwise-identical partials.
     """
     occ_any = ell.block_occupancy(block_rows, block_k).any(axis=0)
-    n_kb = occ_any.shape[0]
-    if hot_k_first:
-        valid = ell.cols != -1
-        kb_of = np.where(valid, ell.cols // block_k, 0)
-        counts = np.bincount(kb_of[valid].ravel(), minlength=n_kb)
-        k_order = np.argsort(-counts, kind="stable")
-    else:
-        k_order = np.arange(n_kb)
+    k_order = _k_order(ell, occ_any.shape[0], block_k, hot_k_first)
     kbs = [int(kb) for kb in k_order if occ_any[kb]]
     if not kbs:  # fully-empty matrix: one step keeps the init path alive
         kbs = [0]

@@ -26,9 +26,9 @@ import scipy.sparse as sp
 from scipy.sparse.csgraph import reverse_cuthill_mckee
 
 from repro.core.sparse_formats import (
+    PAD_COL,
     CSRMatrix,
     TiledELL,
-    csr_rows_to_ell,
     _ceil_div,
 )
 
@@ -228,9 +228,97 @@ class PreprocessResult:
 
     ell: TiledELL                  # bounded-row sparse operand (global cols)
     perm: np.ndarray               # node permutation applied (edge-cut)
-    tiles: List[VertexCutTile]     # per-tile views (simulator input)
     tau: int
     tile_rows: int
+
+
+def _row_rank(flags: np.ndarray, indptr: np.ndarray,
+              row: np.ndarray) -> np.ndarray:
+    """For each entry, how many flagged entries precede it in its row."""
+    c = np.concatenate([[0], np.cumsum(flags)])
+    return c[:-1] - c[indptr[:-1]][row]
+
+
+def vertex_cut_rows(adj: CSRMatrix, tau: int, tile_rows: int):
+    """Algorithm 1 over every tile of ``adj`` at once.
+
+    Gives the sub-row of each nonzero as ``(row, sub)``, with ``sub`` the
+    split's index within its row, and the order in which the nonzeros
+    fill their sub-rows: the sub-rows of :func:`vertex_cut_tile` on each
+    tile of :func:`partition_into_tiles`, in the same order, holding the
+    same nonzeros in the same order (``tests/test_preprocessing.py``
+    checks it against the per-tile loop).
+    """
+    if tau < 1:
+        raise ValueError("tau must be >= 1")
+    n, nnz = adj.rows, adj.nnz
+    indptr = adj.indptr
+    rnz = np.diff(indptr)
+    row = np.repeat(np.arange(n, dtype=np.int64), rnz)
+    # Hot columns: each tile's tau columns of most nonzeros, ties to the
+    # lower column (``_hot_columns`` over the tile's sorted column ids).
+    key = (row // tile_rows) * max(adj.cols, 1) + adj.indices
+    uniq, inv, cnz = np.unique(key, return_inverse=True, return_counts=True)
+    tile_u = uniq // max(adj.cols, 1)
+    order = np.lexsort((uniq, -cnz, tile_u))
+    rank = np.arange(uniq.size) - np.searchsorted(tile_u[order],
+                                                  tile_u[order])
+    hot_u = np.empty(uniq.size, dtype=bool)
+    hot_u[order] = rank < tau
+    # Rows past tau split into K = ceil(rnz / tau) sub-rows: sub-row j
+    # takes misses [j*n_miss, (j+1)*n_miss) and hits [j*n_hit, ...), and
+    # the hits left over fill further sub-rows of tau each.
+    long_e = (rnz > tau)[row]
+    hit = hot_u[inv.reshape(-1)] & long_e
+    miss = ~hot_u[inv.reshape(-1)] & long_e
+    k = -(-rnz // tau)
+    n_miss = np.bincount(row[miss], minlength=n)
+    per_miss = -(-n_miss // np.maximum(k, 1))
+    per_hit = tau - per_miss
+    sub = np.zeros(nnz, dtype=np.int64)
+    m_rank = _row_rank(miss, indptr, row)
+    sub[miss] = m_rank[miss] // per_miss[row[miss]]
+    h_rank = _row_rank(hit, indptr, row)[hit]
+    h_row = row[hit]
+    taken = k[h_row] * per_hit[h_row]
+    sub[hit] = np.where(
+        h_rank < taken, h_rank // np.maximum(per_hit[h_row], 1),
+        k[h_row] + (h_rank - taken) // tau)
+    # Within a sub-row: its misses, then its hits, each in column order.
+    span = 2 * (int(sub.max(initial=0)) + 1)
+    fill = np.argsort(row * span + 2 * sub + hit, kind="stable")
+    return row, sub, fill
+
+
+def ell_from_vertex_cut(adj: CSRMatrix, row, sub, fill, tau: int,
+                        pad_rows_to: int = 1, dtype=np.float32) -> TiledELL:
+    """Lay the sub-rows of :func:`vertex_cut_rows` out as an ELL table, in
+    row order, each row's sub-rows in the order of their ``sub`` (an
+    empty row keeps one empty sub-row)."""
+    n = adj.rows
+    row_s, sub_s = row[fill], sub[fill]
+    new = np.ones(row_s.size, dtype=bool)
+    new[1:] = (row_s[1:] != row_s[:-1]) | (sub_s[1:] != sub_s[:-1])
+    group = np.cumsum(new) - 1                  # sub-row of each entry
+    g_start = np.flatnonzero(new)
+    g_row = row_s[g_start]
+    n_sub = np.maximum(np.bincount(g_row, minlength=n), 1)
+    row_off = np.concatenate([[0], np.cumsum(n_sub)])
+    g_first = np.concatenate([[0], np.cumsum(np.bincount(g_row,
+                                                         minlength=n))])
+    g_index = row_off[g_row] + np.arange(g_row.size) - g_first[g_row]
+    n_rows = int(row_off[-1])
+    padded = _ceil_div(max(n_rows, 1), pad_rows_to) * pad_rows_to
+    cols = np.full((padded, tau), PAD_COL, dtype=np.int32)
+    vals = np.zeros((padded, tau), dtype=dtype)
+    rmap = np.full((padded,), -1, dtype=np.int32)
+    rmap[:n_rows] = np.repeat(np.arange(n, dtype=np.int32), n_sub)
+    at = g_index[group]
+    slot = np.arange(row_s.size) - g_start[group]
+    cols[at, slot] = adj.indices[fill]
+    vals[at, slot] = adj.data[fill]
+    return TiledELL(cols=cols, vals=vals, row_map=rmap,
+                    n_dense_rows=adj.cols, n_orig_rows=n)
 
 
 def preprocess(
@@ -244,36 +332,22 @@ def preprocess(
     """Full hybrid pipeline: edge-cut -> tiles -> vertex-cut -> ELL.
 
     The returned ELL carries *global* column indices (into the permuted dense
-    operand) so a single kernel launch covers the whole matrix; per-tile
-    views are kept for the instruction-driven simulator.
+    operand) so a single kernel launch covers the whole matrix; the
+    simulator's per-tile views come from :func:`partition_into_tiles` and
+    :func:`vertex_cut_tile`, which cut the same sub-rows tile by tile.
     """
-    perm = edge_cut_permutation(adj, edge_cut)
-    padj = apply_symmetric_permutation(adj, perm) if edge_cut != "none" else adj
-    tiles = partition_into_tiles(padj, tile_rows)
-    vc_tiles = [vertex_cut_tile(t, tau) for t in tiles]
+    from repro.obs.trace import span  # deferred: core imports no layer
 
-    row_cols: List[np.ndarray] = []
-    row_vals: List[np.ndarray] = []
-    row_map: List[int] = []
-    for vt in vc_tiles:
-        col_ids = vt.tile.col_ids
-        for c, v, m in zip(vt.sub_rows_cols, vt.sub_rows_vals, vt.sub_row_map):
-            row_cols.append(col_ids[c].astype(np.int32))
-            row_vals.append(v)
-            row_map.append(int(m))
-    ell = csr_rows_to_ell(
-        row_cols,
-        row_vals,
-        row_map,
-        tau=tau,
-        n_dense_rows=padj.cols,
-        n_orig_rows=padj.rows,
-        pad_rows_to=pad_rows_to,
-        dtype=dtype,
-    )
-    return PreprocessResult(
-        ell=ell, perm=perm, tiles=vc_tiles, tau=tau, tile_rows=tile_rows
-    )
+    with span("preprocess.edge_cut"):
+        perm = edge_cut_permutation(adj, edge_cut)
+        padj = (apply_symmetric_permutation(adj, perm)
+                if edge_cut != "none" else adj)
+    with span("preprocess.vertex_cut"):
+        row, sub, fill = vertex_cut_rows(padj, tau, tile_rows)
+    with span("preprocess.ell"):
+        ell = ell_from_vertex_cut(padj, row, sub, fill, tau,
+                                  pad_rows_to=pad_rows_to, dtype=dtype)
+    return PreprocessResult(ell=ell, perm=perm, tau=tau, tile_rows=tile_rows)
 
 
 def hot_column_permutation(ell: TiledELL, n_hot: int) -> np.ndarray:

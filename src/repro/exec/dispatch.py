@@ -20,7 +20,8 @@ import jax.numpy as jnp
 from repro.core.sparse_formats import PAD_COL, TiledELL
 from repro.core.spmm import segment_accumulate
 from repro.exec import quant
-from repro.exec.operands import SpmmOperands
+from repro.core.dataflow import KernelGrid
+from repro.exec.operands import SpmmOperands, planned_grid
 from repro.exec.plan import SpmmPlan
 
 
@@ -31,12 +32,14 @@ def sub_row_products(
     dense: jax.Array,     # (K, F)
     ell: Optional[TiledELL] = None,
     scales: Optional[jax.Array] = None,  # (ceil(R/block_rows),) f32 (int8)
+    grid: Optional[KernelGrid] = None,
 ) -> jax.Array:
     """Per-sub-row products ``(R, F)`` with the plan's effective impl.
 
     The row-wise product core of the paper: each bounded (sub-)row times
-    the dense operand, *before* the CMP partial-sum fold.  ``ell`` is the
-    host container for ``pallas_sparse`` grid compaction; the plan must
+    the dense operand, *before* the CMP partial-sum fold.  ``pallas_sparse``
+    runs the planned ``grid`` when it fits the plan's blocks, else plans
+    one from ``ell``, the host container; the plan must
     already be resolved so the impl choice is pinned.  ``scales`` carries
     the per-row-block dequantization scales when ``vals`` is int8 — the
     kernels dequantize on load and still accumulate in f32.
@@ -53,31 +56,20 @@ def sub_row_products(
         return _sub_row_products_ref(cols, vals, dense)
 
     from repro.kernels import flexvector_spmm as fv  # deferred: keeps exec
-    from repro.core.dataflow import plan_kernel_grid  # importable w/o pallas
 
     r, f = cols.shape[0], dense.shape[1]
     cols_p, vals_p, dense_p, _ = fv.pad_operands(
         cols, vals, dense, plan.block_rows, plan.block_k, plan.block_f
     )
     if impl == "pallas_sparse":
-        import numpy as np
-
-        grid = plan_kernel_grid(
-            ell,
-            f,
-            block_rows=plan.block_rows,
-            block_k=plan.block_k,
-            block_f=plan.block_f,
-            skip_empty=True,
-            hot_k_first=plan.hot_k_first,
-        )
+        if grid is None or not grid.fits(plan):
+            grid = planned_grid(ell, plan)
         sub = fv.spmm_ell_sparse_grid(
             cols_p,
             vals_p,
             dense_p,
-            grid.pairs[:, 0],   # host arrays: the resident launch's
-            grid.pairs[:, 1],   # run offsets become a constant
-            grid.first_k.astype(np.int32),
+            grid.starts,
+            grid.kb_ids,
             block_rows=plan.block_rows,
             block_k=plan.block_k,
             block_f=plan.block_f,
@@ -290,7 +282,8 @@ def execute(plan: SpmmPlan, operands: SpmmOperands, dense: jax.Array) -> jax.Arr
             return _ref_spmm(cols, vals, row_map, dense, operands.n_out_rows)
     with jax.named_scope("aggregate"):
         sub = sub_row_products(
-            plan, cols, vals, dense, ell=operands.ell, scales=scales
+            plan, cols, vals, dense, ell=operands.ell, scales=scales,
+            grid=operands.grid,
         )
     with jax.named_scope("fold"):
         return segment_accumulate(sub, row_map, operands.n_out_rows)

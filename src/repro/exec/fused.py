@@ -327,9 +327,7 @@ def _execute_fused_sharded(
     row_sharded_dense = plan.dense_layout == "row_sharded"
 
     sh = shard_operands(
-        operands, n_shards, plan.block_rows, reserve_empty_block=False,
-        split=plan.shard_split,
-    )
+        operands, n_shards, plan.block_rows, split=plan.shard_split)
     cols = jnp.asarray(sh.cols)
     scales = None
     if plan.precision == "int8":

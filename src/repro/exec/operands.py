@@ -26,8 +26,19 @@ from typing import Optional, Tuple
 import jax
 import numpy as np
 
+from repro.core.dataflow import KernelGrid, plan_kernel_grid
 from repro.core.sparse_formats import PAD_COL, TiledELL
 from repro.plan import cost
+
+
+def planned_grid(ell: Optional[TiledELL], plan) -> KernelGrid:
+    """The ``pallas_sparse`` schedule of ``ell`` for ``plan``'s blocks."""
+    if ell is None:
+        raise ValueError("no planned grid fits the plan's blocks, and no "
+                         "host TiledELL to plan one from")
+    return plan_kernel_grid(
+        ell, plan.block_f, block_rows=plan.block_rows, block_k=plan.block_k,
+        block_f=plan.block_f, hot_k_first=plan.hot_k_first)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,6 +48,10 @@ class SpmmOperands:
     ``ell`` keeps the host container when the caller had one — it is the
     scheduling handle for ``pallas_sparse`` grid compaction and the
     source of ``n_dense_rows`` for per-shard occupancy planning.
+
+    ``grid`` is an already planned ``pallas_sparse`` schedule; with it
+    the operands are schedulable without the host container, so a jitted
+    step can take every graph operand as an argument.
 
     ``precision`` describes how ``vals`` is *stored* (``exec.quant``
     semantics): f32 vals may still be executed under a quantized plan
@@ -53,11 +68,13 @@ class SpmmOperands:
     scales: Optional[jax.typing.ArrayLike] = None  # (ceil(R/sbr),) f32
     scale_block_rows: Optional[int] = None
     precision: str = "f32"
+    grid: Optional[KernelGrid] = None
 
     @property
     def schedulable(self) -> bool:
-        """Host-side grid planning possible (TiledELL available)?"""
-        return self.ell is not None
+        """A ``pallas_sparse`` schedule planned, or plannable on the host
+        (TiledELL available)?"""
+        return self.ell is not None or self.grid is not None
 
     @property
     def concrete(self) -> bool:
@@ -106,7 +123,6 @@ def shard_operands(
     operands: SpmmOperands,
     n_shards: int,
     block_rows: int,
-    reserve_empty_block: bool = False,
     split: str = "nnz",
 ) -> ShardedOperands:
     """Split the sub-row axis into ``n_shards`` contiguous slices.
@@ -118,11 +134,7 @@ def shard_operands(
     fallback when no nonzero counts exist).  Either way every slice is
     padded to the same block-aligned ``rows_per_shard`` (PAD_COL cols,
     zero vals, -1 row_map) so the shards run one identical program on
-    different data.  ``reserve_empty_block`` appends one
-    guaranteed-all-padding row block per shard: the sharded
-    ``pallas_sparse`` schedule pads shorter shard pair-lists with no-op
-    visits to that block (adds exact zeros), equalizing scalar-prefetch
-    lengths across shards.
+    different data.
     """
     if not operands.concrete:
         raise TypeError(
@@ -142,8 +154,6 @@ def shard_operands(
         bounds = cost.balanced_split_points(np.zeros(r), n_shards)
     seg_len = int(np.diff(bounds).max()) if n_shards else 0
     per = _round_up(max(seg_len, 1), block_rows)
-    if reserve_empty_block:
-        per += block_rows
     out_cols = np.full((n_shards * per, tau), PAD_COL, dtype=np.int32)
     out_vals = np.zeros((n_shards * per, tau), dtype=vals.dtype)
     out_rmap = np.full((n_shards * per,), -1, dtype=np.int32)

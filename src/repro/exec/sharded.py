@@ -35,10 +35,9 @@ the historical equal-row-count split), so a hub-heavy shard does not
 serialize the cross-shard reduction behind its extra nonzeros.
 
 ``pallas_sparse`` keeps its block-skipping schedule per shard: each
-shard's (row-block, k-tile) pair list is planned host-side from its own
-occupancy, then padded to a common length with no-op visits to a reserved
-all-padding row block (they accumulate exact zeros), so every shard runs
-one identical scalar-prefetched program.
+shard's run offsets and visit list are planned host-side from its own
+occupancy, and the lists padded at their end to a common length, so
+every shard runs one identical program.
 
 Every dispatch records its epilogue's per-device collective bytes and the
 activation DRAM writeback into ``dist.collectives.LEDGER`` — recording is
@@ -61,7 +60,7 @@ from repro.dist.collectives import (
     segment_reduce_scatter,
 )
 from repro.exec import quant
-from repro.exec.operands import SpmmOperands, shard_operands
+from repro.exec.operands import SpmmOperands, planned_grid, shard_operands
 from repro.exec.plan import SpmmPlan
 
 
@@ -144,7 +143,6 @@ def execute_sharded(
             operands,
             n_shards,
             plan.block_rows,
-            reserve_empty_block=(impl == "pallas_sparse"),
             split=plan.shard_split,
         )
         cols_h, vals_h, rmap_h = sh.cols, sh.vals, sh.row_map
@@ -266,24 +264,12 @@ def execute_sharded(
 
     # pallas_sparse: per-shard block-skipping schedules, padded to one length.
     if n_shards > 1:
-        rb, kb, first = _padded_shard_schedules(plan, sh, f_local)
+        starts, kb = _shard_schedules(plan, sh)
     else:
-        from repro.core.dataflow import plan_kernel_grid
+        grid = planned_grid(operands.ell, plan)
+        starts, kb = grid.starts, grid.kb_ids
 
-        grid = plan_kernel_grid(
-            operands.ell,
-            f_local,
-            block_rows=plan.block_rows,
-            block_k=plan.block_k,
-            block_f=plan.block_f,
-            skip_empty=True,
-            hot_k_first=plan.hot_k_first,
-        )
-        rb = grid.pairs[:, 0].astype(np.int32)
-        kb = grid.pairs[:, 1].astype(np.int32)
-        first = grid.first_k.astype(np.int32)
-
-    def body(rb_s, kb_s, first_s, c, v, *rest):
+    def body(starts_s, kb_s, c, v, *rest):
         *sc, m, d = rest
         r_loc = c.shape[0]
         c, v, d, _ = fv.pad_operands(
@@ -293,9 +279,8 @@ def execute_sharded(
             c,
             v,
             d,
-            rb_s,
+            starts_s,
             kb_s,
-            first_s,
             block_rows=plan.block_rows,
             block_k=plan.block_k,
             block_f=plan.block_f,
@@ -308,54 +293,25 @@ def execute_sharded(
     fn = jax.shard_map(
         body,
         mesh=mesh,
-        in_specs=(P(row_spec), P(row_spec), P(row_spec), P(row_spec),
-                  P(row_spec)) + sc_specs + (P(row_spec), dense_spec),
+        in_specs=(P(row_spec), P(row_spec), P(row_spec), P(row_spec))
+        + sc_specs + (P(row_spec), dense_spec),
         out_specs=out_spec,
         check_vma=False,
     )
     return fn(
-        jnp.asarray(rb), jnp.asarray(kb), jnp.asarray(first), cols, vals,
-        *sc_args, rmap, dense,
+        jnp.asarray(starts), jnp.asarray(kb), cols, vals, *sc_args, rmap,
+        dense,
     )[:, :f]
 
 
-def _padded_shard_schedules(plan, sh, feature_dim):
-    """Plan each shard's compacted (row-block, k-tile) pair list and pad all
-    lists to the longest one with no-op visits.
-
-    The no-op targets the reserved trailing all-padding row block of each
-    shard (``reserve_empty_block``): its expansion is all zeros, and the
-    real schedule already zero-initialized it (``plan_kernel_grid`` visits
-    every row block at least once with ``first=1``), so padded steps
-    accumulate nothing.
-    """
-    from repro.core.dataflow import plan_kernel_grid
-
-    grids = [
-        plan_kernel_grid(
-            ell,
-            feature_dim,
-            block_rows=plan.block_rows,
-            block_k=plan.block_k,
-            block_f=plan.block_f,
-            skip_empty=True,
-            hot_k_first=plan.hot_k_first,
-        )
-        for ell in sh.shard_ells
-    ]
-    n_steps = max(len(g.pairs) for g in grids)
-    empty_rb = sh.rows_per_shard // plan.block_rows - 1
-    rb_all, kb_all, first_all = [], [], []
-    for g in grids:
-        pad = n_steps - len(g.pairs)
-        rb_all.append(np.concatenate(
-            [g.pairs[:, 0], np.full(pad, empty_rb, np.int32)]))
-        kb_all.append(np.concatenate(
-            [g.pairs[:, 1], np.zeros(pad, np.int32)]))
-        first_all.append(np.concatenate(
-            [g.first_k.astype(np.int32), np.zeros(pad, np.int32)]))
+def _shard_schedules(plan, sh):
+    """Each shard's planned run offsets and visit list, the lists padded
+    at their end to the longest (the offsets never reach the padding), so
+    every shard runs one program on its own slices."""
+    grids = [planned_grid(ell, plan) for ell in sh.shard_ells]
+    n_visits = max(len(g.kb_ids) for g in grids)
     return (
-        np.concatenate(rb_all).astype(np.int32),
-        np.concatenate(kb_all).astype(np.int32),
-        np.concatenate(first_all).astype(np.int32),
+        np.concatenate([g.starts for g in grids]).astype(np.int32),
+        np.concatenate([np.pad(g.kb_ids, (0, n_visits - len(g.kb_ids)))
+                        for g in grids]).astype(np.int32),
     )

@@ -17,36 +17,31 @@ Two launch schedules:
   (Section V-B); Pallas' pipelined DMA double-buffers the streamed dense
   k-tiles exactly like the double-VRF MV_Dyn/CMP overlap (Fig 7c).
 
-* ``spmm_ell_sparse_grid`` — block-skipping schedule: a scalar-prefetched
-  (row_block, k_tile) pair list visits only non-empty cells, the grid-level
-  analogue of never issuing MV_Dyn for absent rows.  Hot k-tiles are
-  ordered first within each row block (``hot_k_first``).  It runs one of
-  two launches, chosen from the operands' size:
-
-  - ``flexvector_sparse_grid_resident`` (``sparse_grid_resident``) while
-    the dense (K, BF) column slab fits ``RESIDENT_VMEM_BUDGET``: the slab
-    is one single-buffered block, DMA'd once per f-tile and kept in VMEM —
-    the flexible VRF's fixed region, at slab granularity.  One grid step
-    per row block builds the row block's expansion tables once (the
-    broadcasts of its ELL slabs, which do not depend on the k-tile) and
-    loops over its visits, slicing each k-tile from the slab.
-  - ``flexvector_sparse_grid`` (the streamed schedule) past it, as for
-    reddit's 119 MB f32 slab: one grid step per pair, each DMA-ing its
-    own (BK, BF) dense tile, so high-reuse hot tiles stay VMEM-resident —
-    the VRF fixed region, at tile granularity.
-
-  Both visit the same pairs in the same order through the same expansion
-  and dot, so their outputs are bitwise equal.
+* ``spmm_ell_sparse_grid`` (``flexvector_sparse_grid_rows``) —
+  block-skipping schedule over the non-empty (row_block, k_tile) cells, the
+  grid-level analogue of never issuing MV_Dyn for absent rows.  One grid
+  step per row block builds the row block's expansion tables once (the
+  broadcasts of its ELL slabs, which do not depend on the k-tile) and
+  loops over its visits, hot k-tiles first (``hot_k_first``).  Only the
+  per-row-block run offsets ride in SMEM; the visit list stays in HBM and
+  each row block's window of it is copied into SMEM a step ahead, so any
+  list fits.  The dense operand's residency follows from its size: while
+  the (K, BF) column slab fits ``RESIDENT_VMEM_BUDGET`` it is one
+  single-buffered VMEM block, DMA'd once per f-tile — the flexible VRF's
+  fixed region, at slab granularity; past it (reddit's 119 MB f32 slab)
+  each visit's (BK, BF) tile is copied in from HBM, double-buffered.  Both
+  residencies add the same products in the same order, so their outputs
+  are bitwise equal.
 
 VMEM budget per grid step (dtype bytes b): BR*128*(4+b) sparse table
 (the tau lanes pad to 128) + BK*BF*b dense tile + BR*BF*4 accumulator +
 BR*BK*4 scratch.  The defaults (BR=BK=BF=128, f32) total about 0.5 MiB
 with double-buffered inputs, well inside the 16 MiB of scoped VMEM a v5e
-kernel gets by default.  The resident launch holds the K*BF*b slab, the
-double-buffered ELL slabs and out block, and two (tau, BR, BK) expansion
-tables (``resident_vmem_bytes``): at pubmed f32 that is 10.2 MB of slab
-and 1.1 MB besides.  The fused kernels hold a whole (R, BF) slab instead;
-``plan.cost.fused_vmem_bytes`` counts it.
+kernel gets by default.  The sparse grid holds the K*BF*b slab (or eight
+streamed tiles), the double-buffered ELL slabs and out block, and two
+(tau, BR, BK) expansion tables (``resident_vmem_bytes``): at pubmed f32
+that is 10.2 MB of slab and 1.1 MB besides.  The fused kernels hold a
+whole (R, BF) slab instead; ``plan.cost.fused_vmem_bytes`` counts it.
 """
 
 from __future__ import annotations
@@ -205,37 +200,158 @@ def spmm_ell_dense_grid(
     )(*args)
 
 
-def _sparse_grid_kernel(rb_ids_ref, kb_ids_ref, first_ref, *refs, block_k,
-                        scaled):
-    scales_ref, (cols_ref, vals_ref, dense_ref, out_ref) = _split_scales(
-        refs, scaled)
-    s = pl.program_id(1)
+# A v5e TensorCore has 128 MiB of VMEM and a kernel gets a 16 MiB scope of
+# it unless it asks for more.  The sparse grid asks for its footprint plus
+# that scope, and keeps the dense slab resident while the resident
+# footprint stays within half the VMEM (``tests/test_tpu_compile.py``
+# compiles one at this edge): pubmed's f32 dense slab (10.2 MB) fits,
+# reddit's (119 MB) does not.
+RESIDENT_VMEM_BUDGET = 64 * 2**20
+_DEFAULT_SCOPED_VMEM = 16 * 2**20
+# Visits the sparse grid's loop expands and multiplies before it adds
+# their products, in order, into the out block: the MXU work of one
+# overlaps the next one's expansion (and, streamed, the next tiles' DMA).
+_VISITS_PER_ITER = 4
+# The tiling of a 1-D int32 array, to which a DMA'd slice must align.
+_KB_ALIGN = 1024
 
-    @pl.when(first_ref[s] == 1)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
 
+def _round_up(x: int, q: int) -> int:
+    return -(-x // q) * q
+
+
+def resident_vmem_bytes(k, tau, *, block_rows, block_k, block_f, dtype,
+                        out_dtype) -> int:
+    """VMEM of :func:`spmm_ell_sparse_grid` with a resident ``(k, ·)``
+    dense slab of ``dtype`` and ``tau`` ELL slots: the single-buffered
+    ``(k, block_f)`` slab, the double-buffered ELL slabs (the tau lanes pad
+    to 128) and out block, and the two ``(tau, block_rows, block_k)``
+    expansion tables."""
+    slab = k * block_f * jnp.dtype(dtype).itemsize
+    return slab + _grid_vmem_bytes(tau, block_rows=block_rows,
+                                   block_k=block_k, block_f=block_f,
+                                   out_dtype=out_dtype)
+
+
+def _grid_vmem_bytes(tau, *, block_rows, block_k, block_f, out_dtype) -> int:
+    """The sparse grid's VMEM besides its dense operand."""
+    ell = 2 * 2 * block_rows * _round_up(tau, 128) * 4
+    out = 2 * block_rows * block_f * jnp.dtype(out_dtype).itemsize
+    return ell + out + 2 * tau * block_rows * block_k * 4
+
+
+def _sparse_grid_kernel(starts_ref, *refs, block_k, block_f, scaled,
+                        resident, per_iter):
+    """One row block: build its expansion tables once, then run its
+    visits ``kb_ids[starts[rb]:starts[rb + 1]]`` in the list's order,
+    ``per_iter`` at a time and the remainder one by one.
+
+    The row block's k-tile ids come from the list in HBM: a window of it
+    is copied into SMEM one grid step ahead.  A resident dense slab is
+    sliced in VMEM; otherwise each visit's ``(block_k, block_f)`` tile is
+    copied in from HBM, the next ``per_iter`` tiles while these multiply.
+    """
+    scales_ref, (cols_ref, vals_ref, dense_ref, kb_hbm, out_ref, kb_win,
+                 kb_sem, offs_ref, vtab_ref, *stream) = _split_scales(
+                     refs, scaled)
+    fi, rb = pl.program_id(0), pl.program_id(1)
+    n_rb = pl.num_programs(1)
+    step, n_steps = fi * n_rb + rb, pl.num_programs(0) * n_rb
+    n_list = kb_hbm.shape[0]
+    width = kb_win.shape[0] // 2
+
+    def window(r, slot):
+        base = jnp.minimum(starts_ref[r] // _KB_ALIGN * _KB_ALIGN,
+                           n_list - width)
+        return base, pltpu.make_async_copy(
+            kb_hbm.at[pl.ds(base, width)],
+            kb_win.at[pl.ds(slot * width, width)], kb_sem.at[slot])
+
+    slot = jax.lax.rem(step, 2)
+
+    @pl.when(step == 0)
+    def _first_window():
+        window(rb, slot)[1].start()
+
+    @pl.when(step + 1 < n_steps)
+    def _next_window():
+        window(jax.lax.rem(rb + 1, n_rb), 1 - slot)[1].start()
+
+    tau = cols_ref.shape[1]
     acc = _acc_dtype(out_ref.dtype)
-    a_blk = _expand_block(
-        cols_ref[...], vals_ref[...], kb_ids_ref[s] * block_k, block_k, acc
-    )
-    if scales_ref is not None:
-        a_blk = a_blk * scales_ref[rb_ids_ref[s]].astype(acc)
-    out_ref[...] += jax.lax.dot_general(
-        a_blk,
-        dense_ref[...].astype(acc),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=out_ref.dtype,
-    )
+    scale = None if scales_ref is None else scales_ref[rb].astype(acc)
+    offs, vals = _expansion_tables(cols_ref[...], vals_ref[...], block_k, acc)
+    for t in range(tau):
+        offs_ref[t], vtab_ref[t] = offs[t], vals[t]
+    out_ref[...] = jnp.zeros_like(out_ref)
+    base, copy = window(rb, slot)
+    copy.wait()
+    start, stop = starts_ref[rb], starts_ref[rb + 1]
+
+    def kb_of(s):
+        return kb_win[slot * width + s - base]
+
+    def rows_of(s):
+        return pl.ds(pl.multiple_of(kb_of(s) * block_k, block_k), block_k)
+
+    if not resident:
+        tiles, tile_sem = stream
+        n_buf = 2 * per_iter
+
+        def tile_copy(s):
+            buf = jax.lax.rem(s - start, n_buf)
+            return buf, pltpu.make_async_copy(
+                dense_ref.at[rows_of(s),
+                             pl.ds(pl.multiple_of(fi * block_f, block_f),
+                                   block_f)],
+                tiles.at[buf], tile_sem.at[buf])
+
+        def fetch(first):
+            for j in range(per_iter):
+                @pl.when(first + j < stop)
+                def _start():
+                    tile_copy(first + j)[1].start()
+
+        fetch(start)
+
+    def product(s):
+        if resident:
+            tile = dense_ref[rows_of(s), :]
+        else:
+            buf, copy = tile_copy(s)
+            copy.wait()
+            tile = tiles[buf]
+        a_blk = _expand_tile(offs_ref, vtab_ref, kb_of(s) * block_k, tau)
+        if scale is not None:
+            a_blk = a_blk * scale
+        return jax.lax.dot_general(
+            a_blk, tile.astype(acc), (((1,), (0,)), ((), ())),
+            preferred_element_type=out_ref.dtype,
+        )
+
+    def visits(i, carry):
+        s = start + i * per_iter
+        if not resident:
+            fetch(s + per_iter)
+        for p in [product(s + j) for j in range(per_iter)]:
+            out_ref[...] += p
+        return carry
+
+    def visit(s, carry):
+        out_ref[...] += product(s)
+        return carry
+
+    n_iter = (stop - start) // per_iter
+    jax.lax.fori_loop(0, n_iter, visits, 0)
+    jax.lax.fori_loop(start + n_iter * per_iter, stop, visit, 0)
 
 
 def spmm_ell_sparse_grid(
     cols: jax.Array,
     vals: jax.Array,
     dense: jax.Array,
-    rb_ids: jax.Array,   # (n_steps,) int32 row-block per grid step
-    kb_ids: jax.Array,   # (n_steps,) int32 k-tile per grid step
-    first: jax.Array,    # (n_steps,) int32 1 on the first visit of rb
+    starts: jax.Array,   # (r // block_rows + 1,) int32 run offsets
+    kb_ids: jax.Array,   # (n_visits,) int32 k-tile of each visit
     *,
     block_rows: int = 128,
     block_k: int = 128,
@@ -244,179 +360,82 @@ def spmm_ell_sparse_grid(
     interpret: Optional[bool] = None,
     scales: Optional[jax.Array] = None,  # (r // block_rows,) f32 dequant
 ) -> jax.Array:
-    """Block-skipping schedule driven by a scalar-prefetched pair list.
+    """Block-skipping schedule: grid (f-tile, row block), each step
+    running its row block's visits ``kb_ids[starts[rb]:starts[rb + 1]]``
+    (``plan_kernel_grid``: every row block at least once, hot k-tiles
+    first) into its output block.
 
-    The (rb, kb) pair list must keep all visits of one row block
-    consecutive (``plan_kernel_grid`` guarantees it) so the output block is
-    revisited contiguously while it stays resident in VMEM.  ``scales``
-    enables int8 dequantize-on-load, as in :func:`spmm_ell_dense_grid`.
-    The three prefetched lists live in SMEM, which bounds ``n_steps``
-    (about 40,000 steps fit a v5e core's 1 MiB SMEM; 400,000 do not).
-
+    Only the run offsets ride in SMEM (scalar prefetch); the visit list
+    stays in HBM and each row block's window of it is copied into SMEM.
     While :func:`resident_vmem_bytes` fits ``RESIDENT_VMEM_BUDGET`` the
-    launch is :func:`sparse_grid_resident`: the same visits in the same
-    order, through the same expansion and dot, so the outputs are bitwise
-    equal.  ``first`` is then not read.
+    whole ``(K, block_f)`` dense slab is one single-buffered VMEM block,
+    DMA'd once per f-tile; past it each visit's dense tile is copied in
+    from HBM, double-buffered.  Either way every visit goes through the
+    same expansion and dot, added in list order, so the two residencies
+    give bitwise-equal sums.  ``scales`` enables int8 dequantize-on-load,
+    as in :func:`spmm_ell_dense_grid`.
     """
     r, tau = cols.shape
     k, f = dense.shape
     if r % block_rows or k % block_k or f % block_f:
         raise ValueError("operands must be padded to block multiples")
+    if starts.shape != (r // block_rows + 1,):
+        raise ValueError(f"starts must hold {r // block_rows + 1} run "
+                         f"offsets, not {starts.shape}")
     out_dtype = out_dtype or _acc_dtype(dense.dtype)
-    if resident_vmem_bytes(
-            k, tau, block_rows=block_rows, block_k=block_k, block_f=block_f,
-            dtype=dense.dtype, out_dtype=out_dtype) <= RESIDENT_VMEM_BUDGET:
-        return sparse_grid_resident(
-            cols, vals, dense, rb_ids, kb_ids, block_rows=block_rows,
-            block_k=block_k, block_f=block_f, out_dtype=out_dtype,
-            interpret=interpret, scales=scales)
-    n_steps = int(rb_ids.shape[0])
-    ell_spec = pl.BlockSpec(
-        (block_rows, tau), lambda fi, s, rb, kb, fs: (rb[s], 0)
-    )
-    dense_spec = pl.BlockSpec(
-        (block_k, block_f), lambda fi, s, rb, kb, fs: (kb[s], fi)
-    )
-    in_specs, args = _with_scales(
-        [ell_spec, ell_spec, dense_spec], (cols, vals, dense), scales, r,
-        block_rows,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(f // block_f, n_steps),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (block_rows, block_f), lambda fi, s, rb, kb, fs: (rb[s], fi)
-        ),
-    )
-    return pl.pallas_call(
-        functools.partial(
-            _sparse_grid_kernel, block_k=block_k, scaled=scales is not None
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r, f), out_dtype),
-        interpret=_default_interpret(interpret),
-        name="flexvector_sparse_grid",
-    )(rb_ids, kb_ids, first, *args)
-
-
-# A v5e TensorCore has 128 MiB of VMEM and a kernel gets a 16 MiB scope of
-# it unless it asks for more.  The resident launch asks for its footprint
-# plus that scope, and is taken while the footprint stays within half the
-# VMEM (``tests/test_tpu_compile.py`` compiles one at this edge): pubmed's
-# f32 dense slab (10.2 MB) fits, reddit's (119 MB) does not.
-RESIDENT_VMEM_BUDGET = 64 * 2**20
-_DEFAULT_SCOPED_VMEM = 16 * 2**20
-# Visits the resident loop expands and multiplies before it adds their
-# products, in order, into the out block: the MXU work of one overlaps
-# the next one's expansion.
-_VISITS_PER_ITER = 4
-
-
-def resident_vmem_bytes(k, tau, *, block_rows, block_k, block_f, dtype,
-                        out_dtype) -> int:
-    """VMEM of :func:`sparse_grid_resident` for a ``(k, ·)`` dense operand
-    of ``dtype`` and ``tau`` ELL slots: the single-buffered ``(k,
-    block_f)`` slab, the double-buffered ELL slabs (the tau lanes pad to
-    128) and out block, and the two ``(tau, block_rows, block_k)``
-    expansion tables."""
-    lanes = -(-tau // 128) * 128
-    slab = k * block_f * jnp.dtype(dtype).itemsize
-    ell = 2 * 2 * block_rows * lanes * 4
-    out = 2 * block_rows * block_f * jnp.dtype(out_dtype).itemsize
-    return slab + ell + out + 2 * tau * block_rows * block_k * 4
-
-
-def _resident_kernel(kb_ids_ref, starts_ref, *refs, block_k, scaled):
-    """One row block: build its expansion tables once, then run its
-    visits ``kb_ids[starts[rb]:starts[rb + 1]]`` in the list's order,
-    ``_VISITS_PER_ITER`` at a time and the remainder one by one."""
-    scales_ref, (cols_ref, vals_ref, dense_ref, out_ref, offs_ref,
-                 vtab_ref) = _split_scales(refs, scaled)
-    rb = pl.program_id(1)
-    tau = cols_ref.shape[1]
-    acc = _acc_dtype(out_ref.dtype)
-    scale = None if scales_ref is None else scales_ref[rb].astype(acc)
-    offs, vals = _expansion_tables(cols_ref[...], vals_ref[...], block_k, acc)
-    for t in range(tau):
-        offs_ref[t], vtab_ref[t] = offs[t], vals[t]
-    out_ref[...] = jnp.zeros_like(out_ref)
-
-    def product(s):
-        kb = kb_ids_ref[s]
-        a_blk = _expand_tile(offs_ref, vtab_ref, kb * block_k, tau)
-        if scale is not None:
-            a_blk = a_blk * scale
-        tile = dense_ref[pl.ds(pl.multiple_of(kb * block_k, block_k),
-                               block_k), :]
-        return jax.lax.dot_general(
-            a_blk, tile.astype(acc), (((1,), (0,)), ((), ())),
-            preferred_element_type=out_ref.dtype,
-        )
-
-    def visits(i, carry):
-        s = start + i * _VISITS_PER_ITER
-        for p in [product(s + j) for j in range(_VISITS_PER_ITER)]:
-            out_ref[...] += p
-        return carry
-
-    def visit(s, carry):
-        out_ref[...] += product(s)
-        return carry
-
-    start, stop = starts_ref[rb], starts_ref[rb + 1]
-    n_iter = (stop - start) // _VISITS_PER_ITER
-    jax.lax.fori_loop(0, n_iter, visits, 0)
-    jax.lax.fori_loop(start + n_iter * _VISITS_PER_ITER, stop, visit, 0)
-
-
-def sparse_grid_resident(cols, vals, dense, rb_ids, kb_ids, *, block_rows,
-                         block_k, block_f, out_dtype, interpret,
-                         scales) -> jax.Array:
-    """Resident launch: grid (f-tile, row block).  The whole ``(K,
-    block_f)`` dense slab is a single-buffered block, DMA'd once per
-    f-tile and kept in VMEM; each step runs its row block's visits of the
-    pair list in order, slicing each k-tile from the slab.  The run
-    offsets ``starts`` come from ``rb_ids`` on the host when it is
-    concrete (inside ``shard_map`` it is not, and they are searched on
-    the device)."""
-    r, tau = cols.shape
-    k, f = dense.shape
-    with jax.ensure_compile_time_eval():
-        starts = jnp.searchsorted(
-            rb_ids, jnp.arange(r // block_rows + 1)).astype(jnp.int32)
-    ell_spec = pl.BlockSpec((block_rows, tau), lambda fi, rb, kb, st: (rb, 0))
-    dense_spec = pl.BlockSpec((k, block_f), lambda fi, rb, kb, st: (0, fi),
-                              pipeline_mode=pl.Buffered(1))
-    in_specs, args = _with_scales(
-        [ell_spec, ell_spec, dense_spec], (cols, vals, dense), scales, r,
-        block_rows,
-    )
     acc = _acc_dtype(out_dtype)
+    blocks = dict(block_rows=block_rows, block_k=block_k, block_f=block_f,
+                  out_dtype=out_dtype)
+    resident = resident_vmem_bytes(
+        k, tau, dtype=dense.dtype, **blocks) <= RESIDENT_VMEM_BUDGET
+    per_iter = _VISITS_PER_ITER
+    vmem = _grid_vmem_bytes(tau, **blocks) + jnp.dtype(dense.dtype).itemsize \
+        * (k if resident else 2 * per_iter * block_k) * block_f
+    # A window of the visit list is aligned to its (1024) tiling and
+    # covers any row block's run of at most K / block_k visits.
+    width = _round_up(k // block_k + _KB_ALIGN - 1, _KB_ALIGN)
+    n_list = max(_round_up(int(kb_ids.shape[0]), _KB_ALIGN), width)
+    if n_list != kb_ids.shape[0]:
+        kb_ids = jnp.pad(kb_ids, (0, n_list - kb_ids.shape[0]))
+    ell_spec = pl.BlockSpec((block_rows, tau), lambda fi, rb, st: (rb, 0))
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    scratch = [pltpu.SMEM((2 * width,), jnp.int32),
+               pltpu.SemaphoreType.DMA((2,)),
+               pltpu.VMEM((tau, block_rows, block_k), jnp.int32),
+               pltpu.VMEM((tau, block_rows, block_k), acc)]
+    if resident:
+        dense_spec = pl.BlockSpec((k, block_f), lambda fi, rb, st: (0, fi),
+                                  pipeline_mode=pl.Buffered(1))
+    else:
+        dense_spec = any_spec
+        scratch += [pltpu.VMEM((2 * per_iter, block_k, block_f), dense.dtype),
+                    pltpu.SemaphoreType.DMA((2 * per_iter,))]
+    in_specs, args = _with_scales(
+        [ell_spec, ell_spec, dense_spec, any_spec],
+        (cols, vals, dense, kb_ids), scales, r, block_rows,
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(f // block_f, r // block_rows),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (block_rows, block_f), lambda fi, rb, kb, st: (rb, fi)
+            (block_rows, block_f), lambda fi, rb, st: (rb, fi)
         ),
-        scratch_shapes=[pltpu.VMEM((tau, block_rows, block_k), jnp.int32),
-                        pltpu.VMEM((tau, block_rows, block_k), acc)],
+        scratch_shapes=scratch,
     )
-    vmem = resident_vmem_bytes(
-        k, tau, block_rows=block_rows, block_k=block_k, block_f=block_f,
-        dtype=dense.dtype, out_dtype=out_dtype)
     return pl.pallas_call(
         functools.partial(
-            _resident_kernel, block_k=block_k, scaled=scales is not None
+            _sparse_grid_kernel, block_k=block_k, block_f=block_f,
+            scaled=scales is not None, resident=resident, per_iter=per_iter,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, f), out_dtype),
         interpret=_default_interpret(interpret),
         compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem + _DEFAULT_SCOPED_VMEM),
-        name="flexvector_sparse_grid_resident",
-    )(kb_ids, starts, *args)
+        name="flexvector_sparse_grid_rows",
+    )(starts, *args)
 
 
 def _combine_tile(x_ref, w_ref, b_ref, kb, block_k, k_real, cast_xw):

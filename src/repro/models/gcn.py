@@ -15,13 +15,14 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Dict, List, Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import PreprocessResult, preprocess
+from repro.core.dataflow import KernelGrid, plan_kernel_grid
 from repro.core.sparse_formats import CSRMatrix
 
 
@@ -42,11 +43,15 @@ class GCNConfig:
 
 @dataclasses.dataclass
 class GCNGraph:
-    """Preprocessed graph operand shared by all layers."""
+    """Preprocessed graph operand shared by all layers, with the
+    ``pallas_sparse`` schedules planned for it so far (by block_rows,
+    block_k, hot_k_first)."""
 
     pre: PreprocessResult
     n_nodes: int
     inv: Optional[np.ndarray] = None  # inverse edge-cut permutation
+    grids: Dict[Tuple[int, int, bool], KernelGrid] = dataclasses.field(
+        default_factory=dict)
 
     def __post_init__(self):
         # Precomputed once: the inverse permutation sits on the per-request
@@ -67,6 +72,53 @@ class GCNGraph:
             pad_rows_to=cfg.block_rows,
         )
         return GCNGraph(pre=pre, n_nodes=adj_norm.rows)
+
+    def kernel_grid(self, block_rows: int, block_k: int,
+                    hot_k_first: bool = True) -> KernelGrid:
+        """The sparse-grid schedule for these blocks, planned once."""
+        key = (block_rows, block_k, hot_k_first)
+        if key not in self.grids:
+            self.grids[key] = plan_kernel_grid(
+                self.pre.ell, block_k, block_rows=block_rows,
+                block_k=block_k, block_f=block_k, hot_k_first=hot_k_first)
+        return self.grids[key]
+
+    def arrays(self, plan) -> "GraphArrays":
+        """The operands a forward under ``plan`` reads, as host arrays."""
+        ell = self.pre.ell
+        return GraphArrays(
+            cols=ell.cols, vals=ell.vals, row_map=ell.row_map,
+            perm=np.asarray(self.pre.perm, np.int32),
+            inv=np.asarray(self.inv, np.int32),
+            grid=self.kernel_grid(plan.block_rows, plan.block_k,
+                                  plan.hot_k_first),
+            n_out_rows=ell.n_orig_rows)
+
+
+@dataclasses.dataclass(frozen=True)
+class GraphArrays:
+    """A preprocessed graph's operands as arrays (``GCNGraph.arrays``).
+
+    A pytree: a jitted forward takes it as an argument, so its operands
+    live on the device once per graph and none of them becomes a constant
+    of the compiled program.  It serves the static single-device plans;
+    the pipeline planner, fused launches and the sharded split plan on
+    the host ``GCNGraph``.
+    """
+
+    cols: jax.typing.ArrayLike     # (R, tau) int32
+    vals: jax.typing.ArrayLike     # (R, tau)
+    row_map: jax.typing.ArrayLike  # (R,) int32
+    perm: jax.typing.ArrayLike     # (N,) int32 edge-cut permutation
+    inv: jax.typing.ArrayLike      # (N,) int32 its inverse
+    grid: KernelGrid
+    n_out_rows: int
+
+
+jax.tree_util.register_dataclass(
+    GraphArrays,
+    data_fields=["cols", "vals", "row_map", "perm", "inv", "grid"],
+    meta_fields=["n_out_rows"])
 
 
 def init_params(cfg: GCNConfig, key: jax.Array) -> Dict[str, Dict[str, jax.Array]]:
@@ -96,7 +148,8 @@ def gcn_forward(
 
     ``features`` are in original node order; the edge-cut permutation is
     applied on entry and inverted on exit, so callers never see permuted
-    node ids.
+    node ids.  ``graph`` is the host :class:`GCNGraph`, or its
+    :class:`GraphArrays` for a static single-device plan.
 
     ``plan`` (an :class:`~repro.exec.SpmmPlan`) or ``mesh`` place the
     aggregation step: a mesh whose ``data`` axis is wider than one device
@@ -155,8 +208,14 @@ def gcn_forward(
     from repro.exec.dispatch import execute_layer
     from repro.exec.operands import SpmmOperands
 
-    operands = SpmmOperands.from_ell(graph.pre.ell)
-    perm = jnp.asarray(graph.pre.perm)
+    if isinstance(graph, GraphArrays):
+        operands = SpmmOperands(
+            cols=graph.cols, vals=graph.vals, row_map=graph.row_map,
+            n_out_rows=graph.n_out_rows, grid=graph.grid)
+        perm, inv = graph.perm, graph.inv
+    else:
+        operands = SpmmOperands.from_ell(graph.pre.ell)
+        perm, inv = jnp.asarray(graph.pre.perm), jnp.asarray(graph.inv)
     x = features[perm]
     n_layers = len(params)
     for i in range(n_layers):
@@ -172,7 +231,7 @@ def gcn_forward(
             x = jax.nn.relu(x)
     if shard_out:
         return x          # permuted order, padded height, row-sharded
-    return x[jnp.asarray(graph.inv)]
+    return x[inv]
 
 
 def gcn_loss(

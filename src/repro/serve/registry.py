@@ -13,24 +13,50 @@ per graph, not once per request:
   ``.cache`` pickle machinery (`repro.serve.cache`, the same path
   `benchmarks/common.py` uses) so they survive process restarts.
 
+A persisted full graph carries the ``pallas_sparse`` schedule planned
+for the config's blocks, and its key hashes the source of the modules
+that write it, so a pickle left by other code is never read.
+
 Jitted full-graph forward steps are cached per key in memory only
-(executables are not picklable).
+(executables are not picklable).  A static single-device step takes the
+graph's operands as an argument (``GraphArrays``, copied to the device
+once per graph), so none of them is a constant of its program.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
+import importlib.util
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import numpy as np
 
 from repro.core.sparse_formats import CSRMatrix
-from repro.models.gcn import GCNConfig, GCNGraph, gcn_forward
+from repro.models.gcn import GCNConfig, GCNGraph, GraphArrays, gcn_forward
+from repro.obs.trace import span
 from repro.serve import cache as disk_cache
 
-_KEY_VERSION = "v1"
+# The modules whose code decides what a persisted artifact holds.
+_ARTIFACT_MODULES = (
+    "repro.core.preprocessing",
+    "repro.core.sparse_formats",
+    "repro.core.dataflow",
+    "repro.models.gcn",
+    "repro.exec.quant",
+)
+
+
+@functools.lru_cache(maxsize=None)
+def source_digest(modules: Tuple[str, ...] = _ARTIFACT_MODULES) -> str:
+    """SHA-256 over the source files of ``modules``."""
+    h = hashlib.sha256()
+    for name in modules:
+        with open(importlib.util.find_spec(name).origin, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
 
 
 @dataclasses.dataclass
@@ -43,14 +69,16 @@ class RegistryStats:
 
 
 def graph_key(adj: CSRMatrix, cfg: GCNConfig) -> str:
-    """Content hash over the adjacency and the preprocessing-relevant
-    config fields (dims/impl don't change the preprocessed operand)."""
+    """Content hash over the adjacency, the preprocessing-relevant config
+    fields (dims/impl don't change the preprocessed operand) and the
+    source of the code that preprocesses it."""
     h = hashlib.sha256()
-    h.update(_KEY_VERSION.encode())
+    h.update(source_digest().encode())
     h.update(np.ascontiguousarray(adj.indptr).tobytes())
     h.update(np.ascontiguousarray(adj.indices).tobytes())
     h.update(np.ascontiguousarray(adj.data).tobytes())
-    meta = (adj.shape, cfg.tau, cfg.tile_rows, cfg.edge_cut, cfg.block_rows)
+    meta = (adj.shape, cfg.tau, cfg.tile_rows, cfg.edge_cut, cfg.block_rows,
+            cfg.block_k)
     h.update(repr(meta).encode())
     return f"gcngraph_{h.hexdigest()[:24]}"
 
@@ -62,12 +90,14 @@ class ArtifactRegistry:
         self.cache_dir = cache_dir or disk_cache.default_cache_dir()
         self.mem_capacity = mem_capacity
         self.stats = RegistryStats()
-        # A jitted step closes over its operand; when the LRU drops the
-        # graph, keeping the step would pin the memory the eviction was
-        # supposed to release, so eviction cascades into _forwards.
+        # A jitted step holds its operand; when the LRU drops the graph,
+        # keeping the step or the operand's device copy would pin the
+        # memory the eviction was supposed to release, so eviction
+        # cascades into _forwards and _uploads.
         self._graphs = disk_cache.LruDict(
             mem_capacity, on_evict=self._drop_forwards)
         self._forwards: Dict[Tuple[str, GCNConfig], Callable] = {}
+        self._uploads: Dict[Tuple[str, int, int, bool], GraphArrays] = {}
 
     def get_or_build(
         self,
@@ -77,9 +107,10 @@ class ArtifactRegistry:
         key: Optional[str] = None,
     ) -> GCNGraph:
         """Return the preprocessed graph for ``(adj, cfg)``, building it at
-        most once per content key (``persist`` keeps full graphs on disk;
-        sampled subgraphs stay memory-only).  ``key`` lets callers that
-        already hashed the adjacency skip a second content pass."""
+        most once per content key (``persist`` keeps full graphs on disk,
+        with their sparse-grid schedule for the config's blocks; sampled
+        subgraphs stay memory-only).  ``key`` lets callers that already
+        hashed the adjacency skip a second content pass."""
         if key is None:
             key = graph_key(adj, cfg)
         graph = self._graphs.get(key)
@@ -92,9 +123,12 @@ class ArtifactRegistry:
                 self.stats.disk_hits += 1
                 self._remember(key, graph)
                 return graph
-        graph = GCNGraph.build(adj, cfg)
+        with span("registry.preprocess"):
+            graph = GCNGraph.build(adj, cfg)
         self.stats.builds += 1
         if persist:
+            with span("registry.plan_grid"):
+                graph.kernel_grid(cfg.block_rows, cfg.block_k)
             disk_cache.store_pickle(key, graph, self.cache_dir)
         self._remember(key, graph)
         return graph
@@ -102,7 +136,7 @@ class ArtifactRegistry:
     def forward_step(
         self, adj: CSRMatrix, cfg: GCNConfig, persist: bool = True,
         plan=None, precision: str = "f32", interpret: Optional[bool] = None,
-    ) -> Callable:
+    ) -> "FullStep":
         """Jitted full-graph forward ``step(params, features) -> logits``
         bound to the registered preprocessed operand.
 
@@ -115,6 +149,8 @@ class ArtifactRegistry:
         per-layer plans); a plan object keys the cache by identity.
         ``interpret`` pins Pallas interpret mode for an ``"auto"`` plan
         (``None``: from the backend); a plan object carries its own.
+        A static single-device plan's step takes the graph's device
+        arrays as an argument; the others plan on the host graph.
         """
         gkey = graph_key(adj, cfg)
         key = (gkey, cfg, precision, interpret,
@@ -132,14 +168,33 @@ class ArtifactRegistry:
             step_plan = plan_pipeline(cfg, graph.pre.ell,
                                       precision=precision,
                                       interpret=interpret)
-        def gcn_full_step(params, feats):
-            with jax.named_scope("gcn_full_step"):
-                return gcn_forward(params, graph, feats, cfg,
-                                   plan=step_plan, precision=precision)
+        arrays = None
+        if _takes_arrays(step_plan):
+            from repro.exec import plan_for_config
 
-        fwd = jax.jit(gcn_full_step)
+            arrays = self._upload(gkey, graph,
+                                  step_plan or plan_for_config(cfg))
+
+        def gcn_full_step(params, feats, arrays):
+            with jax.named_scope("gcn_full_step"):
+                return gcn_forward(params, graph if arrays is None else arrays,
+                                   feats, cfg, plan=step_plan,
+                                   precision=precision)
+
+        fwd = FullStep(jax.jit(gcn_full_step), arrays)
         self._forwards[key] = fwd
         return fwd
+
+    def _upload(self, gkey: str, graph: GCNGraph, plan) -> GraphArrays:
+        """The graph's operands for ``plan`` on the device, copied once."""
+        ukey = (gkey, plan.block_rows, plan.block_k, plan.hot_k_first)
+        arrays = self._uploads.get(ukey)
+        if arrays is None:
+            with span("registry.upload"):
+                arrays = jax.block_until_ready(
+                    jax.device_put(graph.arrays(plan)))
+            self._uploads[ukey] = arrays
+        return arrays
 
     def quantized_ell(
         self, adj: CSRMatrix, cfg: GCNConfig, precision: str,
@@ -182,3 +237,40 @@ class ArtifactRegistry:
     def _drop_forwards(self, key: str, _graph: GCNGraph) -> None:
         for fkey in [k for k in self._forwards if k[0] == key]:
             del self._forwards[fkey]
+        for ukey in [k for k in self._uploads if k[0] == key]:
+            del self._uploads[ukey]
+
+
+@dataclasses.dataclass(frozen=True)
+class FullStep:
+    """``step(params, features) -> logits``: a jitted full-graph forward
+    ``jitted(params, features, arrays)`` with the graph's device arrays
+    bound (``None`` where the step plans on the host graph)."""
+
+    jitted: Callable
+    arrays: Optional[GraphArrays]
+
+    def __call__(self, params, features):
+        return self.jitted(params, features, self.arrays)
+
+    def lower(self, params, features):
+        """Lower for (abstract) ``params`` and ``features``; the graph's
+        arrays are lowered as arguments of their shapes, placed as
+        ``features`` is."""
+        place = getattr(features, "sharding", None)
+        arrays = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=place),
+            self.arrays)
+        return self.jitted.lower(params, features, arrays)
+
+
+def _takes_arrays(plan) -> bool:
+    """Does a step under ``plan`` read the graph only as arrays?  The
+    pipeline planner, fused launches and sharded splits plan on the host
+    graph."""
+    from repro.exec import SpmmPlan
+
+    if plan is None:
+        return True
+    return (isinstance(plan, SpmmPlan) and not plan.fused
+            and not plan.sharded and not plan.feature_sharded)

@@ -1,6 +1,7 @@
 """Property tests for the hybrid preprocessing (Algorithm 1 + edge-cut)."""
 
 import numpy as np
+import pytest
 try:
     from hypothesis import given, settings, strategies as st
 except ImportError:  # seeded-sweep fallback, tests/_propcheck.py
@@ -89,3 +90,44 @@ def test_preprocess_spmm_correct_after_permutation():
     out_perm = np.asarray(spmm_ell(res.ell, x[res.perm]))
     expected = (adj.to_scipy() @ x)[res.perm]
     np.testing.assert_allclose(out_perm, expected, rtol=1e-4, atol=1e-5)
+
+
+def _ell_tile_by_tile(adj, tau, tile_rows, pad_rows_to):
+    """The ELL of the per-tile Algorithm 1 loop (partition_into_tiles,
+    vertex_cut_tile), assembled row by row."""
+    from repro.core.sparse_formats import csr_rows_to_ell
+
+    cols, vals, rmap = [], [], []
+    for t in partition_into_tiles(adj, tile_rows):
+        vt = vertex_cut_tile(t, tau)
+        for c, v, m in zip(vt.sub_rows_cols, vt.sub_rows_vals,
+                           vt.sub_row_map):
+            cols.append(t.col_ids[c].astype(np.int32))
+            vals.append(v)
+            rmap.append(int(m))
+    return csr_rows_to_ell(cols, vals, rmap, tau=tau, n_dense_rows=adj.cols,
+                           n_orig_rows=adj.rows, pad_rows_to=pad_rows_to)
+
+
+@pytest.mark.parametrize("graph,tau,tile_rows", [
+    ("pubmed", 6, 16), ("power_law", 2, 16), ("power_law", 3, 5),
+    ("power_law", 6, 16)])
+def test_vectorised_vertex_cut_equals_the_tile_loop(graph, tau, tile_rows):
+    """``preprocess`` cuts every tile at once; its ELL equals, array for
+    array, the one the per-tile loop gives on the same permuted graph."""
+    from repro.core.preprocessing import apply_symmetric_permutation
+
+    if graph == "pubmed":
+        adj = load_dataset("pubmed", with_features=False).adj_norm
+    else:
+        adj = random_power_law_csr(300, 300, 5000, seed=tau)
+    res = preprocess(adj, tau=tau, tile_rows=tile_rows, pad_rows_to=128)
+    want = _ell_tile_by_tile(apply_symmetric_permutation(adj, res.perm),
+                             tau, tile_rows, 128)
+    assert (res.ell.cols != -1).sum(axis=1).max() <= tau
+    for name in ("cols", "vals", "row_map"):
+        got, ref = getattr(res.ell, name), getattr(want, name)
+        assert got.dtype == ref.dtype
+        np.testing.assert_array_equal(got, ref)
+    assert (res.ell.n_dense_rows, res.ell.n_orig_rows) == (
+        want.n_dense_rows, want.n_orig_rows)

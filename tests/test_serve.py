@@ -441,3 +441,105 @@ def test_compile_cache_lands_in_env_dir_or_checkout(env_set, tmp_path):
     assert reported == configured == want
     assert any(name.endswith("-cache") for name in os.listdir(want))
     assert not os.path.exists(tmp_path / "artifacts" / "jax")
+
+
+# ---------------------------------------------------------------------------
+# graph operands as arguments of the full-graph step; artifact keys
+# ---------------------------------------------------------------------------
+
+
+def test_a_changed_source_misses_the_persisted_artifact(toy_graph, tmp_path,
+                                                        monkeypatch):
+    """Persisted graphs are keyed by the source of the code that wrote
+    them: a changed module gives another digest, and under another
+    digest the pickle on disk is not read."""
+    from repro.serve import registry as reg_mod
+
+    mod = tmp_path / "artifact_writer.py"
+    mod.write_text("SPLIT = 1\n")
+    monkeypatch.syspath_prepend(str(tmp_path))
+    before = reg_mod.source_digest(("artifact_writer",))
+    mod.write_text("SPLIT = 2\n")
+    reg_mod.source_digest.cache_clear()
+    assert reg_mod.source_digest(("artifact_writer",)) != before
+
+    adj_norm, _ = toy_graph
+    cache = str(tmp_path / "artifacts")
+    ArtifactRegistry(cache_dir=cache).get_or_build(adj_norm, _cfg())
+    warm = ArtifactRegistry(cache_dir=cache)
+    warm.get_or_build(adj_norm, _cfg())
+    assert (warm.stats.disk_hits, warm.stats.builds) == (1, 0)
+    monkeypatch.setattr(reg_mod, "source_digest", lambda: before)
+    changed = ArtifactRegistry(cache_dir=cache)
+    changed.get_or_build(adj_norm, _cfg())
+    assert (changed.stats.disk_hits, changed.stats.builds) == (0, 1)
+
+
+def _constant_bytes(hlo_text):
+    """Bytes of each constant in a lowered module's text."""
+    import re
+
+    width = {"i1": 1, "i8": 1, "i16": 2, "bf16": 2, "f16": 2, "i32": 4,
+             "f32": 4, "i64": 8, "f64": 8, "ui32": 4, "ui8": 1}
+    out = []
+    for dims in re.findall(r"stablehlo\.constant [^\n]*: tensor<([^>]*)>",
+                           hlo_text):
+        *shape, dtype = dims.split("x")
+        out.append(int(np.prod([int(d) for d in shape])) * width[dtype])
+    return out
+
+
+def test_full_step_carries_no_graph_constant(tmp_path):
+    """At pubmed the full-graph step takes the ELL, row map, permutations
+    and visit list as arguments: its program's constants come to less
+    than 1 MB together, where those operands as constants are 3 MB."""
+    from repro.exec import plan_for_config
+    from repro.graphs.datasets import load_dataset
+
+    ds = load_dataset("pubmed", with_features=False)
+    cfg = GCNConfig(in_dim=500, hidden_dim=16, out_dim=3,
+                    spmm_impl="pallas_sparse")
+    plan = plan_for_config(cfg, interpret=True).resolve(schedulable=True)
+    step = ArtifactRegistry(cache_dir=str(tmp_path)).forward_step(
+        ds.adj_norm, cfg, plan=plan)
+    feats = jax.ShapeDtypeStruct((ds.spec.nodes, 500), np.float32)
+    text = step.lower(init_params(cfg, jax.random.PRNGKey(0)),
+                      feats).as_text()
+    assert sum(_constant_bytes(text)) <= 2**20
+    closed = jax.jit(lambda p, x: gcn_forward(
+        p, step.arrays, x, cfg, plan=plan)).lower(
+            init_params(cfg, jax.random.PRNGKey(0)), feats).as_text()
+    assert sum(_constant_bytes(closed)) > 2**20   # the check sees them
+
+
+@pytest.mark.parametrize("resident", [True, False])
+def test_full_step_matches_a_plain_gcn(toy_graph, monkeypatch, resident):
+    """The full-graph step through the sparse grid, its slab resident or
+    streamed, gives a plain f32 jax.numpy GCN's logits on seeded
+    weights and biases."""
+    import jax.numpy as jnp
+
+    from repro.exec import plan_for_config
+    from repro.kernels import flexvector_spmm as fv
+
+    adj_norm, feats = toy_graph
+    cfg = _cfg(spmm_impl="pallas_sparse")
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    rng = np.random.default_rng(3)
+    for layer in params.values():
+        layer["b"] = jnp.asarray(rng.normal(0, 0.1, layer["b"].shape),
+                                 jnp.float32)
+    if not resident:
+        monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", 0)
+    plan = plan_for_config(cfg, interpret=True).resolve(schedulable=True)
+    got = np.asarray(ArtifactRegistry().forward_step(
+        adj_norm, cfg, plan=plan)(params, feats))
+    a = jnp.asarray(adj_norm.to_scipy().toarray())
+    with jax.default_matmul_precision("highest"):
+        h = jnp.asarray(feats)
+        for i in range(cfg.n_layers):
+            p = params[f"layer_{i}"]
+            h = a @ (h @ p["w"] + p["b"])
+            if i < cfg.n_layers - 1:
+                h = jax.nn.relu(h)
+    np.testing.assert_allclose(got, np.asarray(h), rtol=1e-4, atol=1e-4)

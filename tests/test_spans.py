@@ -270,3 +270,34 @@ def test_profiler_trace_holds_full_forward_with_dispatch_and_fetch(
     [(d0, d1)] = spans["repro.engine.dispatch"]
     [(g0, g1)] = spans["repro.engine.fetch"]
     assert f0 <= d0 <= d1 <= g0 <= g1 <= f1
+
+
+def test_profiler_trace_holds_the_graph_set_up_stages(toy_parts, tmp_path):
+    """Building an engine's full graph writes ``registry.preprocess`` over
+    its three stages, then ``registry.plan_grid`` and ``registry.upload``
+    for the step's device operands."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _engine(toy_parts, spmm_impl="pallas_sparse", interpret=True)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(("repro.registry.",
+                                       "repro.preprocess.")):
+                    spans.setdefault(ev.name[len("repro."):], []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    [(p0, p1)] = spans["registry.preprocess"]
+    for stage in ("edge_cut", "vertex_cut", "ell"):
+        [(s0, s1)] = spans[f"preprocess.{stage}"]
+        assert p0 <= s0 <= s1 <= p1
+    [(g0, g1)] = spans["registry.plan_grid"]
+    [(u0, u1)] = spans["registry.upload"]
+    assert p1 <= g0 <= g1 <= u0 <= u1

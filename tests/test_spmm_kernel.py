@@ -128,16 +128,16 @@ def test_pad_operands_alignment():
 
 
 def _parity_operands(case, br, bk, f):
-    """``[(cols, vals, rb_ids, kb_ids, first, k)]`` for the launch-parity
-    test: an ELL with all-empty row blocks, or each shard of a two-way
-    split whose shorter pair list is padded with no-op visits to the
-    trailing empty row block, as the sharded path pads it."""
+    """``[(cols, vals, starts, kb_ids, k)]`` for the launch-parity test:
+    an ELL with all-empty row blocks, or each shard of a two-way split
+    whose shorter visit list is padded at its end, as the sharded path
+    pads it."""
     import dataclasses
 
     from repro.core.sparse_formats import PAD_COL
     from repro.exec import SpmmPlan
     from repro.exec.operands import SpmmOperands, shard_operands
-    from repro.exec.sharded import _padded_shard_schedules
+    from repro.exec.sharded import _shard_schedules
 
     res, _ = _problem(96, 800, 5, f, seed=11)
     ell = res.ell
@@ -147,35 +147,63 @@ def _parity_operands(case, br, bk, f):
         ell = dataclasses.replace(ell, cols=cols, vals=vals)
         g = plan_kernel_grid(ell, f, block_rows=br, block_k=bk, block_f=bk)
         assert (ell.block_occupancy(br, bk).sum(axis=1) == 0).sum() >= 2
-        return [(ell.cols, ell.vals, g.pairs[:, 0], g.pairs[:, 1],
-                 g.first_k.astype(np.int32), ell.n_dense_rows)]
-    sh = shard_operands(SpmmOperands.from_ell(ell), 2, block_rows=br,
-                        reserve_empty_block=True)
+        return [(ell.cols, ell.vals, g.starts, g.kb_ids, ell.n_dense_rows)]
+    sh = shard_operands(SpmmOperands.from_ell(ell), 2, block_rows=br)
     plan = SpmmPlan(block_rows=br, block_k=bk, block_f=bk)
-    rb, kb, first = _padded_shard_schedules(plan, sh, f)
-    n, per = len(rb) // 2, sh.rows_per_shard
+    starts, kb = _shard_schedules(plan, sh)
+    n, per = len(kb) // 2, sh.rows_per_shard
+    m = per // br + 1
     shards = [(sh.cols[s * per:(s + 1) * per], sh.vals[s * per:(s + 1) * per],
-               rb[s * n:(s + 1) * n], kb[s * n:(s + 1) * n],
-               first[s * n:(s + 1) * n], ell.n_dense_rows) for s in range(2)]
-    empty_rb = per // br - 1
-    assert any((s[2][-2:] == empty_rb).all() and s[4][-1] == 0
-               for s in shards), "no shard got no-op visits"
+               starts[s * m:(s + 1) * m], kb[s * n:(s + 1) * n],
+               ell.n_dense_rows) for s in range(2)]
+    assert any(sd[2][-1] < n for sd in shards), "no shard list was padded"
     return shards
+
+
+def _schedule_oracle(cols, vals, dense, starts, kb_ids, br, bk, bf,
+                     scales=None):
+    """The sparse grid's sums, one row block at a time in plain jnp:
+    each visit's block expanded as the kernels expand it and multiplied
+    by its dense tile, the products added in the list's order."""
+    from repro.kernels.flexvector_spmm import _acc_dtype, _expand_block
+
+    r, f = cols.shape[0], dense.shape[1]
+    out_dtype = _acc_dtype(dense.dtype)
+    blocks = []
+    for rb in range(r // br):
+        rows = slice(rb * br, (rb + 1) * br)
+        tiles = []
+        for fi in range(f // bf):
+            acc = jnp.zeros((br, bf), out_dtype)
+            for kb in kb_ids[starts[rb]:starts[rb + 1]]:
+                a = _expand_block(cols[rows], vals[rows], int(kb) * bk, bk,
+                                  out_dtype)
+                if scales is not None:
+                    a = a * scales[rb].astype(out_dtype)
+                tile = dense[int(kb) * bk:(int(kb) + 1) * bk,
+                             fi * bf:(fi + 1) * bf]
+                acc = acc + jax.lax.dot_general(
+                    a, tile.astype(out_dtype), (((1,), (0,)), ((), ())),
+                    preferred_element_type=out_dtype)
+            tiles.append(acc)
+        blocks.append(jnp.concatenate(tiles, axis=1))
+    return np.asarray(jnp.concatenate(blocks, axis=0))
 
 
 @pytest.mark.parametrize("case", ["empty_row_blocks", "shard_padding"])
 @pytest.mark.parametrize("precision", ["f32", "bf16", "int8"])
 def test_resident_and_streamed_launches_bitwise_equal(precision, case,
                                                       monkeypatch):
-    """The resident launch visits the streamed launch's pairs in the same
-    order through the same expansion and dot, so its sub-row products are
-    bitwise equal."""
+    """Whether the dense slab is resident in VMEM or each visit's tile is
+    copied in, the sparse grid visits the same tiles in the same order
+    through the same expansion and dot: its sub-row products are bitwise
+    equal, to each other and to the schedule done one visit at a time."""
     from repro.exec import quant
     from repro.kernels import flexvector_spmm as fv
 
     br = bk = 16
-    f = 24
-    for cols, vals, rb, kb, first, k in _parity_operands(case, br, bk, f):
+    f = 32
+    for cols, vals, starts, kb, k in _parity_operands(case, br, bk, f):
         dense = np.random.default_rng(k).standard_normal((k, f))
         scales = None
         if precision == "int8":
@@ -186,37 +214,68 @@ def test_resident_and_streamed_launches_bitwise_equal(precision, case,
         dense = quant.cast_dense(jnp.asarray(dense, jnp.float32), precision)
         c, v, d, _ = pad_operands(cols, vals, dense, br, bk, bk)
         run = lambda: np.asarray(fv.spmm_ell_sparse_grid(  # noqa: E731
-            c, v, d, rb, kb, first, block_rows=br, block_k=bk, block_f=bk,
-            interpret=True, scales=scales))
+            c, v, d, jnp.asarray(starts), jnp.asarray(kb), block_rows=br,
+            block_k=bk, block_f=bk, interpret=True, scales=scales))
         resident = run()
         monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", 0)
         streamed = run()
         monkeypatch.undo()
         assert np.abs(streamed).max() > 0
         np.testing.assert_array_equal(resident, streamed)
+        np.testing.assert_array_equal(resident, _schedule_oracle(
+            c, v, d, starts, kb, br, bk, bk, scales))
 
 
 @pytest.mark.parametrize("visits_per_iter", [1, 2, 3])
 def test_resident_launch_keeps_the_visit_order(visits_per_iter,
                                                monkeypatch):
-    """However many visits one loop iteration runs, their products are
-    added in the pair list's order: the result is bitwise equal to the
-    streamed launch on a row block with an odd number of visits."""
+    """However many visits one loop iteration runs, resident or streamed,
+    their products are added in the visit list's order: the result is
+    bitwise equal to the schedule done one visit at a time, on row blocks
+    with odd numbers of visits and on lists longer than one window."""
     from repro.kernels import flexvector_spmm as fv
 
     res, dense = _problem(96, 900, 6, 16, seed=4)
     g = plan_kernel_grid(res.ell, 16, block_rows=16, block_k=16, block_f=16)
-    assert (np.bincount(g.pairs[:, 0]) % 2 == 1).any()
+    assert (np.diff(g.starts) % 2 == 1).any()
     c, v, d, _ = pad_operands(res.ell.cols, res.ell.vals,
                               jnp.asarray(dense), 16, 16, 16)
-    args = (c, v, d, g.pairs[:, 0], g.pairs[:, 1],
-            g.first_k.astype(np.int32))
-    kw = dict(block_rows=16, block_k=16, block_f=16, interpret=True)
+    want = _schedule_oracle(c, v, d, g.starts, g.kb_ids, 16, 16, 16)
     monkeypatch.setattr(fv, "_VISITS_PER_ITER", visits_per_iter)
-    resident = np.asarray(fv.spmm_ell_sparse_grid(*args, **kw))
-    monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", 0)
-    streamed = np.asarray(fv.spmm_ell_sparse_grid(*args, **kw))
-    np.testing.assert_array_equal(resident, streamed)
+    monkeypatch.setattr(fv, "_KB_ALIGN", 8)
+    for budget in (fv.RESIDENT_VMEM_BUDGET, 0):
+        monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", budget)
+        got = np.asarray(fv.spmm_ell_sparse_grid(
+            c, v, d, jnp.asarray(g.starts), jnp.asarray(g.kb_ids),
+            block_rows=16, block_k=16, block_f=16, interpret=True))
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("resident", [True, False])
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+def test_sparse_grid_matches_the_reference_impl(precision, resident,
+                                                monkeypatch):
+    """On a power-law graph, both residencies of the sparse grid give the
+    ``reference`` impl's sums within the precision's tolerance."""
+    from repro.exec import SpmmPlan, sub_row_products
+    from repro.kernels import flexvector_spmm as fv
+
+    res, dense = _problem(300, 4000, 6, 40, seed=9)
+    if not resident:
+        monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", 0)
+    dt = jnp.float32 if precision == "f32" else jnp.bfloat16
+    vals, x = jnp.asarray(res.ell.vals, dt), jnp.asarray(dense, dt)
+    outs = {}
+    for impl in ("reference", "pallas_sparse"):
+        plan = SpmmPlan(impl=impl, block_rows=32, block_k=32, block_f=16,
+                        interpret=True, precision=precision
+                        ).resolve(schedulable=True)
+        outs[impl] = np.asarray(sub_row_products(
+            plan, jnp.asarray(res.ell.cols), vals, x, ell=res.ell),
+            np.float32)
+    tol = 1e-5 if precision == "f32" else 2e-2
+    np.testing.assert_allclose(outs["pallas_sparse"], outs["reference"],
+                               rtol=tol, atol=tol)
 
 
 @pytest.mark.parametrize("nodes,resident", [(19_717, True),

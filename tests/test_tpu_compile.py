@@ -1,14 +1,14 @@
-"""The main path's Pallas kernels compile for a TPU v5e at pubmed widths.
+"""The main path's Pallas kernels compile for a TPU v5e at real widths.
 
 Interpret mode on the CPU cannot show what the TPU compiler refuses: a
-block that breaks the (8, 128) tiling rule, a scalar-prefetch list past
-SMEM, a kernel past VMEM.  These tests compile each kernel for a
-described (not attached) v5e chip, at pubmed's Table III size cut into
-the serving config's tiles: 25,984 vertex-cut rows of ``tau=6``, 19,717
-dense rows padded to 19,840, 500 input features, 128-wide feature
-tiles, and the 14,809-step block-skipping pair list pubmed's ELL gets
-(the streamed sparse grid; at pubmed size the sparse grid runs its
-VMEM-resident launch, which needs no pair list).
+block that breaks the (8, 128) tiling rule, a list past SMEM, a kernel
+past VMEM.  These tests compile each kernel for a described (not
+attached) v5e chip, at pubmed's Table III size cut into the serving
+config's tiles: 25,856 vertex-cut rows of ``tau=6``, 19,717 dense rows
+padded to 19,840, 500 input features, 128-wide feature tiles, and the
+14,281-visit block-skipping list pubmed's ELL gets; the sparse grid also
+at reddit's: 4,205,568 rows, 233,088 dense rows, 17,685,868 visits over
+32,856 row blocks.
 
 The topology is described inside a fixture, never at import, so only
 the test worker that runs this file loads the TPU compiler; the
@@ -20,8 +20,10 @@ import os
 
 import pytest
 
-ROWS, TAU, K, K_REAL, F, F_IN = 25_984, 6, 19_840, 19_717, 128, 500
-PAIRS, FUSED_STEPS, BLOCK = 14_809, 155, 128
+ROWS, TAU, K, K_REAL, F, F_IN = 25_856, 6, 19_840, 19_717, 128, 500
+VISITS, FUSED_STEPS, BLOCK = 14_281, 155, 128
+# reddit at Table III size (graph seed 0, tau 6, 128-row blocks)
+REDDIT_ROWS, REDDIT_K, REDDIT_VISITS = 4_205_568, 233_088, 17_685_868
 PRECISIONS = ("f32", "bf16", "int8")
 
 
@@ -95,50 +97,54 @@ def test_dense_grid_compiles(shape, precision):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("precision", PRECISIONS)
-def test_sparse_grid_compiles(shape, precision, monkeypatch):
-    """The streamed launch (the path for dense slabs past the resident
-    VMEM budget, reached here by a zero budget): one grid step per pair,
-    the pair list in SMEM."""
+def _sparse_grid(shape, precision, k=K, rows=ROWS, visits=VISITS):
     import jax.numpy as jnp
 
+    from repro.kernels import flexvector_spmm as fv
+
+    vdt, adt = _dtypes(precision)
+    return _compile(
+        lambda c, v, d, st, kb, *s: fv.spmm_ell_sparse_grid(
+            c, v, d, st, kb, interpret=False, scales=s[0] if s else None),
+        shape((rows, TAU), jnp.int32), shape((rows, TAU), vdt),
+        shape((k, F), adt), shape((rows // BLOCK + 1,), jnp.int32),
+        shape((visits,), jnp.int32), _scales(shape, precision, rows))
+
+
+@pytest.mark.parametrize("precision", PRECISIONS)
+def test_sparse_grid_compiles(shape, precision, monkeypatch):
+    """The streamed residency (dense slabs past the resident VMEM budget,
+    reached here by a zero budget): each visit's tile copied in."""
     from repro.kernels import flexvector_spmm as fv
 
     monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", 0)
-    vdt, adt = _dtypes(precision)
-    steps = shape((PAIRS,), jnp.int32)
-    text = _compile(
-        lambda c, v, d, rb, kb, fs, *s: fv.spmm_ell_sparse_grid(
-            c, v, d, rb, kb, fs, interpret=False,
-            scales=s[0] if s else None),
-        shape((ROWS, TAU), jnp.int32), shape((ROWS, TAU), vdt),
-        shape((K, F), adt), steps, steps, steps,
-        _scales(shape, precision))
-    assert "%flexvector_sparse_grid" in text
-    assert "%flexvector_sparse_grid_resident" not in text
-
-
-def _resident(shape, precision, k=K, rows=ROWS):
-    import jax.numpy as jnp
-
-    from repro.kernels import flexvector_spmm as fv
-
-    vdt, adt = _dtypes(precision)
-    steps = shape((PAIRS,), jnp.int32)
-    return _compile(
-        lambda c, v, d, rb, kb, fs, *s: fv.spmm_ell_sparse_grid(
-            c, v, d, rb, kb, fs, interpret=False,
-            scales=s[0] if s else None),
-        shape((rows, TAU), jnp.int32), shape((rows, TAU), vdt),
-        shape((k, F), adt), steps, steps, steps,
-        _scales(shape, precision, rows))
+    assert "%flexvector_sparse_grid_rows" in _sparse_grid(shape, precision)
 
 
 @pytest.mark.parametrize("precision", PRECISIONS)
 def test_sparse_grid_resident_compiles(shape, precision):
-    """At pubmed size the sparse grid runs the resident launch: the
-    dense slab single-buffered in VMEM, one grid step per row block."""
-    assert "%flexvector_sparse_grid_resident" in _resident(shape, precision)
+    """At pubmed size the sparse grid keeps the dense slab single-buffered
+    in VMEM, one grid step per row block."""
+    assert "%flexvector_sparse_grid_rows" in _sparse_grid(shape, precision)
+
+
+@pytest.mark.parametrize("precision,resident", [("f32", False),
+                                                ("bf16", True)])
+def test_sparse_grid_compiles_at_reddit_size(shape, precision, resident):
+    """At reddit's size the run offsets fit SMEM and the visit list stays
+    in HBM; the f32 slab (119 MB) streams its tiles, the bf16 one (60 MB)
+    stays resident, and either fits VMEM."""
+    import jax.numpy as jnp
+
+    from repro.kernels import flexvector_spmm as fv
+
+    need = fv.resident_vmem_bytes(
+        REDDIT_K, TAU, block_rows=BLOCK, block_k=BLOCK, block_f=F,
+        dtype=_dtypes(precision)[1], out_dtype=jnp.float32)
+    assert (need <= fv.RESIDENT_VMEM_BUDGET) == resident
+    assert "%flexvector_sparse_grid_rows" in _sparse_grid(
+        shape, precision, k=REDDIT_K, rows=REDDIT_ROWS,
+        visits=REDDIT_VISITS)
 
 
 def test_largest_resident_slab_compiles(shape):
@@ -155,8 +161,8 @@ def test_largest_resident_slab_compiles(shape):
             <= fv.RESIDENT_VMEM_BUDGET:
         k += BLOCK
     assert k > 4 * K
-    text = _resident(shape, "f32", k=k, rows=8 * BLOCK)
-    assert "%flexvector_sparse_grid_resident" in text
+    text = _sparse_grid(shape, "f32", k=k, rows=8 * BLOCK)
+    assert "%flexvector_sparse_grid_rows" in text
 
 
 def _fused(shape, kind, precision, rows, f_in=F_IN):
@@ -241,11 +247,12 @@ def test_steps_name_their_kernels_and_scopes(shape, fused):
 
     full = engine.registry.forward_step(
         adj, cfg, plan=dataclasses.replace(engine.full_plan, fused=fused))
-    text = _compile(full, on(engine.params), shape(feats.shape, feats.dtype))
+    text = full.lower(on(engine.params), shape(feats.shape, feats.dtype)
+                      ).compile().as_text()
     assert f"%{prefix}sparse_grid" in text
     assert "gcn_full_step/" in text
     if not fused:
-        assert "%flexvector_sparse_grid_resident" in text
+        assert "%flexvector_sparse_grid_rows" in text
         assert "gcn_full_step/combine/" in text
     assert "/aggregate/" in text and "/fold/" in text
 
@@ -261,8 +268,8 @@ def test_steps_name_their_kernels_and_scopes(shape, fused):
 
 def test_sharded_full_step_compiles(topo):
     """The full-graph step sharded over a described 2x2 v5e (a 4-wide
-    data mesh): each shard's SpMM runs the resident launch under
-    ``shard_map``."""
+    data mesh): each shard's SpMM runs the sparse grid under
+    ``shard_map``, on its own run offsets and visit list."""
     import jax
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
@@ -291,4 +298,4 @@ def test_sharded_full_step_compiles(topo):
     feats = on(jax.ShapeDtypeStruct((spec.nodes, 32), np.float32))
     text = _compile(
         lambda p, x: gcn_forward(p, graph, x, cfg, plan=plan), params, feats)
-    assert "%flexvector_sparse_grid_resident" in text
+    assert "%flexvector_sparse_grid_rows" in text
