@@ -25,23 +25,36 @@ Two launch schedules:
   loops over its visits, hot k-tiles first (``hot_k_first``).  Only the
   per-row-block run offsets ride in SMEM; the visit list stays in HBM and
   each row block's window of it is copied into SMEM a step ahead, so any
-  list fits.  The dense operand's residency follows from its size: while
-  the (K, BF) column slab fits ``RESIDENT_VMEM_BUDGET`` it is one
-  single-buffered VMEM block, DMA'd once per f-tile — the flexible VRF's
-  fixed region, at slab granularity; past it (reddit's 119 MB f32 slab)
-  each visit's (BK, BF) tile is copied in from HBM, double-buffered.  Both
-  residencies add the same products in the same order, so their outputs
-  are bitwise equal.
+  list fits.  Where the dense operand lives follows from its size and
+  dtype (``sparse_grid_residency``), the flexible VRF deciding what the
+  register file holds:
+
+  - ``resident``: the (K, BF) column slab fits ``RESIDENT_VMEM_BUDGET``
+    and is one single-buffered VMEM block, DMA'd once per f-tile (pubmed);
+  - ``resident_bf16``: an f32 slab that fits only in the form the MXU
+    consumes (reddit's 119 MB).  At the first row block of each f-tile the
+    kernel copies the f32 slab in from HBM a chunk at a time, two chunks
+    in flight, and rounds it to bf16 (round to nearest even) into a VMEM
+    scratch.  The MXU rounds f32 operands to bf16 the same way at the
+    default precision, so the products do not change;
+  - ``streamed``: past both, each visit's (BK, BF) tile is copied in from
+    HBM, double-buffered.
+
+  All three add the same products in the same order, so on the chip their
+  outputs are bitwise equal (``resident_bf16`` equals the others run on
+  the bf16-rounded operand wherever products are exact f32, as on the CPU).
 
 VMEM budget per grid step (dtype bytes b): BR*128*(4+b) sparse table
 (the tau lanes pad to 128) + BK*BF*b dense tile + BR*BF*4 accumulator +
 BR*BK*4 scratch.  The defaults (BR=BK=BF=128, f32) total about 0.5 MiB
 with double-buffered inputs, well inside the 16 MiB of scoped VMEM a v5e
-kernel gets by default.  The sparse grid holds the K*BF*b slab (or eight
-streamed tiles), the double-buffered ELL slabs and out block, and two
-(tau, BR, BK) expansion tables (``resident_vmem_bytes``): at pubmed f32
-that is 10.2 MB of slab and 1.1 MB besides.  The fused kernels hold a
-whole (R, BF) slab instead; ``plan.cost.fused_vmem_bytes`` counts it.
+kernel gets by default.  The sparse grid holds, besides 1.1 MB of
+double-buffered ELL slabs and out block and two (tau, BR, BK) expansion
+tables, its dense operand (``sparse_grid_vmem_bytes``): the K*BF*b slab
+resident (10.2 MB at pubmed f32); the K*BF*2 bf16 slab and two
+(8*BK, BF) f32 staging chunks for ``resident_bf16`` (59.7 MB and 1 MiB
+at reddit); or eight streamed tiles.  The fused kernels hold a whole
+(R, BF) slab instead; ``plan.cost.fused_vmem_bytes`` counts it.
 """
 
 from __future__ import annotations
@@ -202,10 +215,10 @@ def spmm_ell_dense_grid(
 
 # A v5e TensorCore has 128 MiB of VMEM and a kernel gets a 16 MiB scope of
 # it unless it asks for more.  The sparse grid asks for its footprint plus
-# that scope, and keeps the dense slab resident while the resident
-# footprint stays within half the VMEM (``tests/test_tpu_compile.py``
-# compiles one at this edge): pubmed's f32 dense slab (10.2 MB) fits,
-# reddit's (119 MB) does not.
+# that scope, and keeps the dense slab resident while its footprint stays
+# within half the VMEM (``tests/test_tpu_compile.py`` compiles one at each
+# residency's edge): pubmed's f32 dense slab (10.2 MB) fits, reddit's
+# (119 MB) fits only rounded to bf16 (59.7 MB).
 RESIDENT_VMEM_BUDGET = 64 * 2**20
 _DEFAULT_SCOPED_VMEM = 16 * 2**20
 # Visits the sparse grid's loop expands and multiplies before it adds
@@ -214,45 +227,107 @@ _DEFAULT_SCOPED_VMEM = 16 * 2**20
 _VISITS_PER_ITER = 4
 # The tiling of a 1-D int32 array, to which a DMA'd slice must align.
 _KB_ALIGN = 1024
+# k-tiles of the f32 slab a ``resident_bf16`` launch copies in at a time
+# to round into its bf16 slab (512 KiB at 128 x 128), two in flight.
+_FILL_TILES = 8
 
 
 def _round_up(x: int, q: int) -> int:
     return -(-x // q) * q
 
 
-def resident_vmem_bytes(k, tau, *, block_rows, block_k, block_f, dtype,
-                        out_dtype) -> int:
-    """VMEM of :func:`spmm_ell_sparse_grid` with a resident ``(k, ·)``
-    dense slab of ``dtype`` and ``tau`` ELL slots: the single-buffered
-    ``(k, block_f)`` slab, the double-buffered ELL slabs (the tau lanes pad
-    to 128) and out block, and the two ``(tau, block_rows, block_k)``
-    expansion tables."""
-    slab = k * block_f * jnp.dtype(dtype).itemsize
-    return slab + _grid_vmem_bytes(tau, block_rows=block_rows,
-                                   block_k=block_k, block_f=block_f,
-                                   out_dtype=out_dtype)
+def _fill_rows(k, block_k) -> int:
+    return min(_FILL_TILES * block_k, k)
 
 
-def _grid_vmem_bytes(tau, *, block_rows, block_k, block_f, out_dtype) -> int:
-    """The sparse grid's VMEM besides its dense operand."""
+def sparse_grid_vmem_bytes(residency, k, tau, *, dtype, out_dtype,
+                           block_rows, block_k, block_f) -> int:
+    """VMEM of :func:`spmm_ell_sparse_grid` in ``residency`` with a
+    ``(k, ·)`` dense operand of ``dtype`` and ``tau`` ELL slots: the dense
+    operand's buffers (the single-buffered ``(k, block_f)`` slab; the bf16
+    slab and two f32 staging chunks; or ``2 * _VISITS_PER_ITER`` streamed
+    tiles), the double-buffered ELL slabs (the tau lanes pad to 128) and
+    out block, and the two ``(tau, block_rows, block_k)`` expansion
+    tables."""
+    b = jnp.dtype(dtype).itemsize
+    if residency == "resident":
+        dense = k * block_f * b
+    elif residency == "resident_bf16":
+        dense = k * block_f * 2 + 2 * _fill_rows(k, block_k) * block_f * b
+    else:
+        dense = 2 * _VISITS_PER_ITER * block_k * block_f * b
     ell = 2 * 2 * block_rows * _round_up(tau, 128) * 4
     out = 2 * block_rows * block_f * jnp.dtype(out_dtype).itemsize
-    return ell + out + 2 * tau * block_rows * block_k * 4
+    return dense + ell + out + 2 * tau * block_rows * block_k * 4
+
+
+def sparse_grid_residency(k, tau, *, dtype, out_dtype, block_rows, block_k,
+                          block_f) -> str:
+    """Where :func:`spmm_ell_sparse_grid` keeps a ``(k, ·)`` dense operand
+    of ``dtype``: ``"resident"`` while its slab fits
+    ``RESIDENT_VMEM_BUDGET``; ``"resident_bf16"`` for an f32 slab under a
+    float accumulator that fits only rounded to bf16 (the MXU's operand
+    form at the default precision); else ``"streamed"``."""
+    def fits(residency):
+        return sparse_grid_vmem_bytes(
+            residency, k, tau, dtype=dtype, out_dtype=out_dtype,
+            block_rows=block_rows, block_k=block_k,
+            block_f=block_f) <= RESIDENT_VMEM_BUDGET
+
+    if fits("resident"):
+        return "resident"
+    if (jnp.dtype(dtype) == jnp.float32
+            and _acc_dtype(out_dtype) == jnp.float32
+            and fits("resident_bf16")):
+        return "resident_bf16"
+    return "streamed"
+
+
+def _fill_slab(dense_hbm, slab, stage, sems, col0, block_k):
+    """Copy the f32 column slab ``dense_hbm[:, col0:col0 + BF]`` into the
+    bf16 VMEM ``slab`` a chunk at a time, the next chunk in flight while
+    this one is rounded (to nearest even) and stored.  The last chunk ends
+    at the slab's end, so it may store some rows a second time."""
+    k, chunk, bf = slab.shape[0], stage.shape[1], slab.shape[1]
+    n = -(-k // chunk)
+
+    def copy(i):
+        row0 = pl.multiple_of(jnp.minimum(i * chunk, k - chunk), block_k)
+        slot = jax.lax.rem(i, 2)
+        return row0, slot, pltpu.make_async_copy(
+            dense_hbm.at[pl.ds(row0, chunk), pl.ds(col0, bf)],
+            stage.at[slot], sems.at[slot])
+
+    copy(0)[2].start()
+
+    def body(i, carry):
+        @pl.when(i + 1 < n)
+        def _next():
+            copy(i + 1)[2].start()
+
+        row0, slot, chunk_copy = copy(i)
+        chunk_copy.wait()
+        slab[pl.ds(row0, chunk), :] = stage[slot].astype(slab.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, n, body, 0)
 
 
 def _sparse_grid_kernel(starts_ref, *refs, block_k, block_f, scaled,
-                        resident, per_iter):
+                        residency, per_iter):
     """One row block: build its expansion tables once, then run its
     visits ``kb_ids[starts[rb]:starts[rb + 1]]`` in the list's order,
     ``per_iter`` at a time and the remainder one by one.
 
     The row block's k-tile ids come from the list in HBM: a window of it
     is copied into SMEM one grid step ahead.  A resident dense slab is
-    sliced in VMEM; otherwise each visit's ``(block_k, block_f)`` tile is
-    copied in from HBM, the next ``per_iter`` tiles while these multiply.
+    sliced in VMEM (a ``resident_bf16`` one is filled at the f-tile's
+    first row block, and each tile widened back to f32, exactly);
+    otherwise each visit's ``(block_k, block_f)`` tile is copied in from
+    HBM, the next ``per_iter`` tiles while these multiply.
     """
     scales_ref, (cols_ref, vals_ref, dense_ref, kb_hbm, out_ref, kb_win,
-                 kb_sem, offs_ref, vtab_ref, *stream) = _split_scales(
+                 kb_sem, offs_ref, vtab_ref, *extra) = _split_scales(
                      refs, scaled)
     fi, rb = pl.program_id(0), pl.program_id(1)
     n_rb = pl.num_programs(1)
@@ -277,6 +352,14 @@ def _sparse_grid_kernel(starts_ref, *refs, block_k, block_f, scaled,
     def _next_window():
         window(jax.lax.rem(rb + 1, n_rb), 1 - slot)[1].start()
 
+    if residency == "resident_bf16":
+        slab = extra[0]
+
+        @pl.when(rb == 0)
+        def _fill():
+            _fill_slab(dense_ref, *extra,
+                       pl.multiple_of(fi * block_f, block_f), block_k)
+
     tau = cols_ref.shape[1]
     acc = _acc_dtype(out_ref.dtype)
     scale = None if scales_ref is None else scales_ref[rb].astype(acc)
@@ -294,8 +377,8 @@ def _sparse_grid_kernel(starts_ref, *refs, block_k, block_f, scaled,
     def rows_of(s):
         return pl.ds(pl.multiple_of(kb_of(s) * block_k, block_k), block_k)
 
-    if not resident:
-        tiles, tile_sem = stream
+    if residency == "streamed":
+        tiles, tile_sem = extra
         n_buf = 2 * per_iter
 
         def tile_copy(s):
@@ -315,8 +398,10 @@ def _sparse_grid_kernel(starts_ref, *refs, block_k, block_f, scaled,
         fetch(start)
 
     def product(s):
-        if resident:
+        if residency == "resident":
             tile = dense_ref[rows_of(s), :]
+        elif residency == "resident_bf16":
+            tile = slab[rows_of(s), :]
         else:
             buf, copy = tile_copy(s)
             copy.wait()
@@ -331,7 +416,7 @@ def _sparse_grid_kernel(starts_ref, *refs, block_k, block_f, scaled,
 
     def visits(i, carry):
         s = start + i * per_iter
-        if not resident:
+        if residency == "streamed":
             fetch(s + per_iter)
         for p in [product(s + j) for j in range(per_iter)]:
             out_ref[...] += p
@@ -367,13 +452,15 @@ def spmm_ell_sparse_grid(
 
     Only the run offsets ride in SMEM (scalar prefetch); the visit list
     stays in HBM and each row block's window of it is copied into SMEM.
-    While :func:`resident_vmem_bytes` fits ``RESIDENT_VMEM_BUDGET`` the
-    whole ``(K, block_f)`` dense slab is one single-buffered VMEM block,
-    DMA'd once per f-tile; past it each visit's dense tile is copied in
-    from HBM, double-buffered.  Either way every visit goes through the
-    same expansion and dot, added in list order, so the two residencies
-    give bitwise-equal sums.  ``scales`` enables int8 dequantize-on-load,
-    as in :func:`spmm_ell_dense_grid`.
+    The dense operand's residency is :func:`sparse_grid_residency`'s: the
+    whole ``(K, block_f)`` slab as one single-buffered VMEM block, DMA'd
+    once per f-tile; an f32 slab rounded to bf16 into VMEM scratch once
+    per f-tile; or each visit's tile copied in from HBM, double-buffered.
+    Every visit goes through the same expansion and dot, added in list
+    order, so the residencies give bitwise-equal sums wherever the MXU
+    rounds f32 operands to bf16, as a TPU does at the default precision.
+    ``scales`` enables int8 dequantize-on-load, as in
+    :func:`spmm_ell_dense_grid`.
     """
     r, tau = cols.shape
     k, f = dense.shape
@@ -384,13 +471,11 @@ def spmm_ell_sparse_grid(
                          f"offsets, not {starts.shape}")
     out_dtype = out_dtype or _acc_dtype(dense.dtype)
     acc = _acc_dtype(out_dtype)
-    blocks = dict(block_rows=block_rows, block_k=block_k, block_f=block_f,
-                  out_dtype=out_dtype)
-    resident = resident_vmem_bytes(
-        k, tau, dtype=dense.dtype, **blocks) <= RESIDENT_VMEM_BUDGET
+    blocks = dict(dtype=dense.dtype, out_dtype=out_dtype,
+                  block_rows=block_rows, block_k=block_k, block_f=block_f)
+    residency = sparse_grid_residency(k, tau, **blocks)
+    vmem = sparse_grid_vmem_bytes(residency, k, tau, **blocks)
     per_iter = _VISITS_PER_ITER
-    vmem = _grid_vmem_bytes(tau, **blocks) + jnp.dtype(dense.dtype).itemsize \
-        * (k if resident else 2 * per_iter * block_k) * block_f
     # A window of the visit list is aligned to its (1024) tiling and
     # covers any row block's run of at most K / block_k visits.
     width = _round_up(k // block_k + _KB_ALIGN - 1, _KB_ALIGN)
@@ -403,11 +488,16 @@ def spmm_ell_sparse_grid(
                pltpu.SemaphoreType.DMA((2,)),
                pltpu.VMEM((tau, block_rows, block_k), jnp.int32),
                pltpu.VMEM((tau, block_rows, block_k), acc)]
-    if resident:
+    dense_spec = any_spec
+    if residency == "resident":
         dense_spec = pl.BlockSpec((k, block_f), lambda fi, rb, st: (0, fi),
                                   pipeline_mode=pl.Buffered(1))
+    elif residency == "resident_bf16":
+        scratch += [pltpu.VMEM((k, block_f), jnp.bfloat16),
+                    pltpu.VMEM((2, _fill_rows(k, block_k), block_f),
+                               dense.dtype),
+                    pltpu.SemaphoreType.DMA((2,))]
     else:
-        dense_spec = any_spec
         scratch += [pltpu.VMEM((2 * per_iter, block_k, block_f), dense.dtype),
                     pltpu.SemaphoreType.DMA((2 * per_iter,))]
     in_specs, args = _with_scales(
@@ -426,7 +516,8 @@ def spmm_ell_sparse_grid(
     return pl.pallas_call(
         functools.partial(
             _sparse_grid_kernel, block_k=block_k, block_f=block_f,
-            scaled=scales is not None, resident=resident, per_iter=per_iter,
+            scaled=scales is not None, residency=residency,
+            per_iter=per_iter,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, f), out_dtype),

@@ -321,7 +321,8 @@ class ServeEngine:
 
     def full_forward(self) -> np.ndarray:
         """Full-graph logits for every node (original node order)."""
-        with span("engine.full_forward"):
+        with span("engine.full_forward") as s:
+            s.set(residency=self._full_step.residency)
             t0 = time.perf_counter()
             with span("engine.dispatch"):
                 # The call into the jitted step; the numpy features are

@@ -66,6 +66,9 @@ class RegistryStats:
     mem_hits: int = 0
     disk_hits: int = 0
     builds: int = 0          # preprocessing actually ran
+    # The built full steps' sparse-grid launches, by where each keeps its
+    # dense operand (``FullStep.residency``).
+    residency: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 def graph_key(adj: CSRMatrix, cfg: GCNConfig) -> str:
@@ -168,12 +171,16 @@ class ArtifactRegistry:
             step_plan = plan_pipeline(cfg, graph.pre.ell,
                                       precision=precision,
                                       interpret=interpret)
-        arrays = None
+        arrays, residency = None, ()
         if _takes_arrays(step_plan):
             from repro.exec import plan_for_config
 
-            arrays = self._upload(gkey, graph,
-                                  step_plan or plan_for_config(cfg))
+            static = step_plan or plan_for_config(cfg)
+            arrays = self._upload(gkey, graph, static)
+            residency = _launch_residency(static, arrays, cfg.n_layers,
+                                          precision)
+            for r in residency:
+                self.stats.residency[r] = self.stats.residency.get(r, 0) + 1
 
         def gcn_full_step(params, feats, arrays):
             with jax.named_scope("gcn_full_step"):
@@ -181,7 +188,7 @@ class ArtifactRegistry:
                                    feats, cfg, plan=step_plan,
                                    precision=precision)
 
-        fwd = FullStep(jax.jit(gcn_full_step), arrays)
+        fwd = FullStep(jax.jit(gcn_full_step), arrays, residency)
         self._forwards[key] = fwd
         return fwd
 
@@ -245,10 +252,13 @@ class ArtifactRegistry:
 class FullStep:
     """``step(params, features) -> logits``: a jitted full-graph forward
     ``jitted(params, features, arrays)`` with the graph's device arrays
-    bound (``None`` where the step plans on the host graph)."""
+    bound (``None`` where the step plans on the host graph).
+    ``residency`` holds each layer's sparse-grid launch's
+    ``sparse_grid_residency`` (empty where the step runs none)."""
 
     jitted: Callable
     arrays: Optional[GraphArrays]
+    residency: Tuple[str, ...] = ()
 
     def __call__(self, params, features):
         return self.jitted(params, features, self.arrays)
@@ -262,6 +272,27 @@ class FullStep:
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=place),
             self.arrays)
         return self.jitted.lower(params, features, arrays)
+
+
+def _launch_residency(plan, arrays: GraphArrays, n_layers: int,
+                      precision: str) -> Tuple[str, ...]:
+    """Where each layer's sparse-grid launch keeps its dense operand, from
+    the uploaded operands' shapes: every layer's operand has the graph's
+    node rows, in f32 or, under bf16/int8, bf16 (``quant.cast_dense``)."""
+    if plan.resolve(schedulable=True).effective_impl != "pallas_sparse":
+        return ()
+    import jax.numpy as jnp
+
+    from repro.kernels.flexvector_spmm import sparse_grid_residency
+
+    prec = precision if precision != "f32" else plan.precision
+    k = -(-len(arrays.perm) // plan.block_k) * plan.block_k
+    residency = sparse_grid_residency(
+        k, arrays.cols.shape[1],
+        dtype=jnp.float32 if prec == "f32" else jnp.bfloat16,
+        out_dtype=plan.out_dtype or jnp.float32, block_rows=plan.block_rows,
+        block_k=plan.block_k, block_f=plan.block_f)
+    return (residency,) * n_layers
 
 
 def _takes_arrays(plan) -> bool:

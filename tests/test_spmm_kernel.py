@@ -278,17 +278,72 @@ def test_sparse_grid_matches_the_reference_impl(precision, resident,
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("nodes,resident", [(19_717, True),
-                                            (232_965, False)])
-def test_sparse_grid_launch_follows_the_dense_slab(nodes, resident):
+@pytest.mark.parametrize("visits_per_iter", [1, 2, 3])
+def test_bf16_slab_launch_is_the_launch_on_the_rounded_operand(
+        visits_per_iter, monkeypatch):
+    """An f32 slab that fits the budget only rounded to bf16 is filled
+    into VMEM in chunks (the last one clamped to the slab's end) and
+    rounded on the way: the launch is bitwise the ``resident`` and
+    ``streamed`` launches, and the one-visit-at-a-time schedule, run on
+    the bf16-rounded operand, with every product exact as on the CPU.
+    Row blocks with odd numbers of visits, two f-tiles, lists longer than
+    one window."""
+    from repro.kernels import flexvector_spmm as fv
+
+    res, dense = _problem(300, 3000, 6, 32, seed=4)
+    g = plan_kernel_grid(res.ell, 16, block_rows=16, block_k=16, block_f=16)
+    assert (np.diff(g.starts) % 2 == 1).any()
+    c, v, d, _ = pad_operands(res.ell.cols, res.ell.vals,
+                              jnp.asarray(dense), 16, 16, 16)
+    rounded = d.astype(jnp.bfloat16).astype(jnp.float32)
+    monkeypatch.setattr(fv, "_VISITS_PER_ITER", visits_per_iter)
+    monkeypatch.setattr(fv, "_KB_ALIGN", 8)
+    monkeypatch.setattr(fv, "_FILL_TILES", 3)
+    k = d.shape[0]
+    assert len(g.kb_ids) > fv._round_up(k // 16 + 8 - 1, 8)   # one window
+    assert k % (3 * 16)                # the last chunk is clamped
+    blocks = dict(dtype=jnp.float32, out_dtype=jnp.float32, block_rows=16,
+                  block_k=16, block_f=16)
+
+    def run(dense, budget):
+        monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", budget)
+        return np.asarray(fv.spmm_ell_sparse_grid(
+            c, v, dense, jnp.asarray(g.starts), jnp.asarray(g.kb_ids),
+            block_rows=16, block_k=16, block_f=16, interpret=True))
+
+    bf16_need = fv.sparse_grid_vmem_bytes("resident_bf16", k, 6, **blocks)
+    assert bf16_need < fv.sparse_grid_vmem_bytes("resident", k, 6, **blocks)
+    monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", bf16_need)
+    assert fv.sparse_grid_residency(k, 6, **blocks) == "resident_bf16"
+    got = run(d, bf16_need)
+    assert np.abs(got).max() > 0
+    np.testing.assert_array_equal(got, run(rounded, 2**30))
+    np.testing.assert_array_equal(got, run(rounded, 0))
+    np.testing.assert_array_equal(got, _schedule_oracle(
+        c, v, rounded, g.starts, g.kb_ids, 16, 16, 16))
+
+
+@pytest.mark.parametrize("nodes,residency,need", [
+    (19_717, "resident", 10_158_080 + 1_179_648),           # pubmed
+    (232_965, "resident_bf16", 59_670_528 + 1_048_576 + 1_179_648),  # reddit
+    (2_449_029, "streamed", 524_288 + 1_179_648),       # ogbn-products
+])
+def test_sparse_grid_launch_follows_the_dense_slab(nodes, residency, need):
     """pubmed's f32 dense slab fits the VMEM budget and runs resident;
-    reddit's 119 MB slab does not and streams its tiles."""
+    reddit's 119 MB slab fits only rounded to bf16 (60 MB, with two 512
+    KiB staging chunks); a slab past both streams its tiles.  The bytes
+    are the module docstring's figures."""
     from repro.kernels import flexvector_spmm as fv
 
     k = -(-nodes // 128) * 128
-    need = fv.resident_vmem_bytes(k, 6, block_rows=128, block_k=128,
-                                  block_f=128, dtype=jnp.float32,
-                                  out_dtype=jnp.float32)
-    assert (need <= fv.RESIDENT_VMEM_BUDGET) == resident
-    if resident:   # the module docstring's figures: 10.2 MB + 1.1 MB
-        assert need == 10_158_080 + 1_179_648
+    blocks = dict(dtype=jnp.float32, out_dtype=jnp.float32, block_rows=128,
+                  block_k=128, block_f=128)
+    assert fv.sparse_grid_residency(k, 6, **blocks) == residency
+    assert fv.sparse_grid_vmem_bytes(residency, k, 6, **blocks) == need
+    assert need <= fv.RESIDENT_VMEM_BUDGET
+    # an integer accumulator, or a slab already bf16, never takes the
+    # bf16 residency
+    assert fv.sparse_grid_residency(
+        k, 6, **dict(blocks, out_dtype=jnp.int32)) != "resident_bf16"
+    assert fv.sparse_grid_residency(
+        k, 6, **dict(blocks, dtype=jnp.bfloat16)) != "resident_bf16"

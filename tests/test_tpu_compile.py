@@ -128,39 +128,60 @@ def test_sparse_grid_resident_compiles(shape, precision):
     assert "%flexvector_sparse_grid_rows" in _sparse_grid(shape, precision)
 
 
-@pytest.mark.parametrize("precision,resident", [("f32", False),
-                                                ("bf16", True)])
-def test_sparse_grid_compiles_at_reddit_size(shape, precision, resident):
+@pytest.mark.parametrize("precision,residency,budget", [
+    ("f32", "resident_bf16", None), ("bf16", "resident", None),
+    ("f32", "streamed", 0)])
+def test_sparse_grid_compiles_at_reddit_size(shape, precision, residency,
+                                             budget, monkeypatch):
     """At reddit's size the run offsets fit SMEM and the visit list stays
-    in HBM; the f32 slab (119 MB) streams its tiles, the bf16 one (60 MB)
-    stays resident, and either fits VMEM."""
+    in HBM; the f32 slab (119 MB) fits VMEM only rounded to bf16 (60 MB),
+    as the bf16 one does; with no budget the f32 slab streams its tiles.
+    Each fits VMEM."""
     import jax.numpy as jnp
 
     from repro.kernels import flexvector_spmm as fv
 
-    need = fv.resident_vmem_bytes(
+    if budget is not None:
+        monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", budget)
+    assert fv.sparse_grid_residency(
         REDDIT_K, TAU, block_rows=BLOCK, block_k=BLOCK, block_f=F,
-        dtype=_dtypes(precision)[1], out_dtype=jnp.float32)
-    assert (need <= fv.RESIDENT_VMEM_BUDGET) == resident
+        dtype=_dtypes(precision)[1], out_dtype=jnp.float32) == residency
     assert "%flexvector_sparse_grid_rows" in _sparse_grid(
         shape, precision, k=REDDIT_K, rows=REDDIT_ROWS,
         visits=REDDIT_VISITS)
 
 
-def test_largest_resident_slab_compiles(shape):
-    """``RESIDENT_VMEM_BUDGET`` is sound at its edge: the largest dense
-    operand it admits compiles with the resident launch."""
+def _largest_k(residency, k):
+    """The largest dense operand (in 128-row steps from ``k``) that takes
+    ``residency`` at f32."""
     import jax.numpy as jnp
 
     from repro.kernels import flexvector_spmm as fv
 
-    k = K
-    while fv.resident_vmem_bytes(
-            k + BLOCK, TAU, block_rows=BLOCK, block_k=BLOCK, block_f=F,
-            dtype=jnp.float32, out_dtype=jnp.float32) \
-            <= fv.RESIDENT_VMEM_BUDGET:
+    blocks = dict(block_rows=BLOCK, block_k=BLOCK, block_f=F,
+                  dtype=jnp.float32, out_dtype=jnp.float32)
+    assert fv.sparse_grid_residency(k, TAU, **blocks) == residency
+    while fv.sparse_grid_residency(k + BLOCK, TAU, **blocks) == residency:
         k += BLOCK
+    return k
+
+
+def test_largest_resident_slab_compiles(shape):
+    """``RESIDENT_VMEM_BUDGET`` is sound at its edge: the largest dense
+    operand it admits compiles with the resident launch."""
+    k = _largest_k("resident", K)
     assert k > 4 * K
+    text = _sparse_grid(shape, "f32", k=k, rows=8 * BLOCK)
+    assert "%flexvector_sparse_grid_rows" in text
+
+
+def test_largest_bf16_slab_compiles(shape):
+    """The budget is sound at the bf16 residency's edge too: the largest
+    f32 operand that fits only rounded to bf16 (253,440 rows, the slab
+    and its staging chunks within 64 MiB) compiles, and one k-tile more
+    streams."""
+    k = _largest_k("resident_bf16", REDDIT_K)
+    assert k == 253_440
     text = _sparse_grid(shape, "f32", k=k, rows=8 * BLOCK)
     assert "%flexvector_sparse_grid_rows" in text
 
