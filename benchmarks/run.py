@@ -50,7 +50,6 @@ IN_PROCESS_BENCHES = [
     ("Fig 12 (buffer sizes)", "bench_buffer_sizes"),
     ("Fig 13 (VLEN/depth)", "bench_vlen_depth"),
     ("SpMM kernel", "bench_spmm_kernel"),
-    ("Fused combination+aggregation layers", "bench_fused"),
     ("Quantized serving (f32/bf16/int8)", "bench_quant"),
     ("Serving engine", "bench_serve"),
     ("Async queue (open-loop Poisson)", "bench_queue"),

@@ -157,27 +157,3 @@ def plan_kernel_grid(
         hot_k_first=hot_k_first,
     )
 
-
-def plan_fused_k_schedule(
-    ell: TiledELL,
-    block_rows: int = 128,
-    block_k: int = 128,
-    hot_k_first: bool = True,
-) -> np.ndarray:
-    """k-tile visit order for the fused (whole-row-space) launch schedule.
-
-    The fused kernel keeps the *entire* output column slab VMEM-resident,
-    so its grid has no row-block axis — one step per k-tile occupied by
-    any row.  The tiles are emitted in the same global ``k_order`` that
-    :func:`plan_kernel_grid` applies within each row block (hot tiles
-    first), which makes each row block's accumulation sequence here an
-    exact supersequence of its unfused sparse-grid sequence: the extra
-    tiles contribute all-zero expanded blocks, so fused and unfused
-    accumulate every output element through bitwise-identical partials.
-    """
-    occ_any = ell.block_occupancy(block_rows, block_k).any(axis=0)
-    k_order = _k_order(ell, occ_any.shape[0], block_k, hot_k_first)
-    kbs = [int(kb) for kb in k_order if occ_any[kb]]
-    if not kbs:  # fully-empty matrix: one step keeps the init path alive
-        kbs = [0]
-    return np.asarray(kbs, dtype=np.int32)

@@ -135,22 +135,6 @@ class CollectiveLedger:
         for listener in self.listeners:
             listener(kind, float(nbytes), n)
 
-    def record_fused_writeback(self, saved_bytes: float) -> None:
-        """Ledger a fused layer's activation writeback: zero bytes, recorded.
-
-        A fused combination+aggregation launch never materializes the
-        intermediate activation in DRAM.  Recording an explicit 0-byte
-        ``activation_dram`` entry (instead of silently skipping the
-        record) keeps the entry *count* comparable between fused and
-        unfused runs of the same stack — ``bench_pipeline``-style
-        comparisons can assert both sides dispatched the same number of
-        layers while the byte totals diverge.  The eliminated bytes are
-        tallied separately under ``fused_writeback_saved`` so the saving
-        itself is machine-readable.
-        """
-        self.record("activation_dram", 0.0)
-        self.record("fused_writeback_saved", float(saved_bytes))
-
     def reset(self) -> None:
         self.counts.clear()
         self.bytes.clear()

@@ -16,14 +16,8 @@ over the ``data`` mesh axis from the same code:
                  scheduling) and the per-shard sub-row splitter;
 * ``dispatch`` — :func:`execute`, the one pad/dispatch/segment-accumulate
                  implementation shared by all entry points, and
-                 :func:`execute_layer`, the layer-level entry that routes
-                 a ``fused=True`` plan to the fused kernel and otherwise
-                 runs combination + aggregation as two launches;
-* ``fused``    — :func:`execute_fused`: combination ``x @ w + b`` and
-                 ELL aggregation in *one* Pallas launch per layer (the
-                 paper's §2 two-stage SpMM with the intermediate
-                 activation never leaving VMEM), bitwise-identical to
-                 the two-launch path for every impl and precision;
+                 :func:`execute_layer`, the layer-level entry that runs
+                 combination + aggregation as two launches;
 * ``sharded``  — :func:`execute_sharded`, ``shard_map`` over the ``data``
                  axis with a pluggable epilogue: ``segment_psum``
                  (replicated output) or ``segment_reduce_scatter``
@@ -59,7 +53,6 @@ from repro.exec.dispatch import (
     prepare_precision,
     sub_row_products,
 )
-from repro.exec.fused import execute_fused
 from repro.exec.sharded import execute_sharded
 from repro.exec.pipeline import (
     GcnPipelinePlan,
@@ -80,7 +73,6 @@ __all__ = [
     "SpmmOperands",
     "SpmmPlan",
     "execute",
-    "execute_fused",
     "execute_layer",
     "execute_sharded",
     "pipeline_forward",

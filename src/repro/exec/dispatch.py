@@ -180,6 +180,22 @@ def record_spmm_dram(
     )
 
 
+def record_combination_dram(
+    plan: SpmmPlan, k: int, f_in: int, f_out: int
+) -> None:
+    """Ledger the combination launch: ``X`` read, ``W`` read, and
+    the intermediate ``XW`` activation written back to DRAM (its read-back
+    is part of the aggregation launch's ``spmm_dram`` record)."""
+    from repro.dist.collectives import LEDGER  # deferred: no cycle
+
+    vb = quant.bytes_per_value(plan.precision)
+    ab = quant.activation_bytes(plan.precision)
+    LEDGER.record(
+        "combination_dram",
+        float(k * f_in * ab + f_in * f_out * vb + k * f_out * ab),
+    )
+
+
 def execute_layer(
     plan: SpmmPlan,
     operands: SpmmOperands,
@@ -188,17 +204,11 @@ def execute_layer(
     *,
     w_block_rows: int = quant.QUANT_BLOCK_ROWS,
 ) -> jax.Array:
-    """One full GCN layer — combination ``x @ w + b`` then aggregation —
-    under the plan's fusion decision.
+    """One full GCN layer — combination ``x @ w + b`` then aggregation.
 
     This is the layer-level entry every forward path (``models.gcn``,
-    ``exec.pipeline``, the serving batcher) routes through.  A plan with
-    ``fused=True`` and a pallas impl runs the single-launch fused kernel
-    (``exec.fused``); otherwise the two launches run separately, exactly
-    as before, with the combination's DRAM traffic ledgered so fused vs
-    unfused byte totals compare honestly.  The reference impl always runs
-    unfused (a gather oracle has no launch to fuse), as do feature-sharded
-    plans (the fused launch keeps the full feature slab VMEM-resident).
+    ``exec.pipeline``, the serving batcher) routes through: the
+    combination in XLA, its DRAM traffic ledgered, then :func:`execute`.
     ``layer`` holds ``"w"``/``"b"`` and optionally ``"w_scale"`` with
     ``w_block_rows`` granularity (see ``quant.quantize_params``).
 
@@ -229,21 +239,9 @@ def _execute_layer_inner(
     *,
     w_block_rows: int,
 ) -> jax.Array:
-    if (
-        plan.fused
-        and plan.effective_impl != "reference"
-        and not plan.feature_sharded
-    ):
-        from repro.exec.fused import execute_fused  # deferred: no cycle
-
-        return execute_fused(
-            plan, operands, x, layer, w_block_rows=w_block_rows
-        )
     with jax.named_scope("combine"):
         xw = quant.affine(x, layer, plan.precision, w_block_rows)
     if operands.concrete and not isinstance(x, jax.core.Tracer):
-        from repro.exec.fused import record_combination_dram
-
         record_combination_dram(
             plan, x.shape[0], x.shape[1], int(xw.shape[1])
         )

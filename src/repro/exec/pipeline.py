@@ -29,17 +29,11 @@ Planning is a tiny exact DP: the state at each layer boundary is the
 activation layout (``replicated`` | ``row_sharded``), edges are costed by
 ``plan.cost.spmm_cost`` under the edge's (dense_layout, out_layout) pair
 plus the combination-matmul roofline and the layout's activation
-writeback.  Each edge additionally offers a *fused* variant — the whole
-layer as one kernel launch, priced by ``plan.cost.fused_layer_cost`` with
-the intermediate ``xw`` round trip gone — whenever the fused launch's
-resident footprint fits VMEM (``plan.cost.fused_viable``), so the DP
-weighs fuse-vs-reshard per layer: a fused edge saves the writeback a
-replicated boundary would pay, which shifts where resharding is worth
-it.  The input features and the final output are pinned replicated, so a
-plan is a shortest path through a 2-wide lattice.  The static per-layer
-default (the config's impl/blocks, replicated everywhere, unfused, at
-the given mesh width) is always costed as the baseline and the chosen
-pipeline is never costed worse than it.
+writeback.  The input features and the final output are pinned
+replicated, so a plan is a shortest path through a 2-wide lattice.  The
+static per-layer default (the config's impl/blocks, replicated
+everywhere, at the given mesh width) is always costed as the baseline
+and the chosen pipeline is never costed worse than it.
 """
 
 from __future__ import annotations
@@ -213,47 +207,23 @@ def plan_pipeline(
         return cost_mod.split_imbalance(stats.row_nnz, bounds)
 
     def edge_seconds(base_plan, f_in, f_out, width, in_layout, out_layout,
-                     imb, fused: bool = False) -> float:
-        if fused:
-            core = cost_mod.fused_layer_cost(
-                stats, f_in, f_out, impl=base_plan.impl,
-                block_rows=base_plan.block_rows, block_k=base_plan.block_k,
-                block_f=base_plan.block_f, n_shards=width,
-                out_layout=out_layout, dense_layout=in_layout,
-                shard_imbalance=imb, dtype_bytes=dtype_bytes,
-                precision=precision, device=device,
-            ).seconds
-        else:
-            spmm = cost_mod.spmm_cost(
-                stats, f_out, impl=base_plan.impl,
-                block_rows=base_plan.block_rows, block_k=base_plan.block_k,
-                block_f=base_plan.block_f, n_shards=width,
-                out_layout=out_layout, dense_layout=in_layout,
-                shard_imbalance=imb, dtype_bytes=dtype_bytes,
-                precision=precision, device=device,
-            ).seconds
-            comb = _combination_seconds(n_out, f_in, f_out, width, in_layout,
-                                        device, act_bytes, w_bytes)
-            core = spmm + comb
+                     imb) -> float:
+        spmm = cost_mod.spmm_cost(
+            stats, f_out, impl=base_plan.impl,
+            block_rows=base_plan.block_rows, block_k=base_plan.block_k,
+            block_f=base_plan.block_f, n_shards=width,
+            out_layout=out_layout, dense_layout=in_layout,
+            shard_imbalance=imb, dtype_bytes=dtype_bytes,
+            precision=precision, device=device,
+        ).seconds
+        comb = _combination_seconds(n_out, f_in, f_out, width, in_layout,
+                                    device, act_bytes, w_bytes)
         # Per-device share of the layout's activation writeback; the
         # replication factor is what distinguishes the layouts here.
         wb = cost_mod.activation_writeback_bytes(
             n_out, f_out, width, out_layout, act_bytes
         ) / max(width, 1) / device.hbm_bw
-        return core + wb
-
-    def fuse_options(base_plan, f_in, width) -> Tuple[bool, ...]:
-        """Edge variants the DP may take: always unfused; fused too when
-        the impl has a launch to fuse and the resident slab fits VMEM."""
-        if base_plan.impl == "reference":
-            return (False,)
-        if not cost_mod.fused_viable(
-            stats, f_in, block_rows=base_plan.block_rows,
-            block_k=base_plan.block_k, block_f=base_plan.block_f,
-            precision=precision, n_shards=width, device=device,
-        ):
-            return (False,)
-        return (False, True)
+        return spmm + comb + wb
 
     def mesh_for(width: int):
         if width <= 1:
@@ -307,11 +277,10 @@ def plan_pipeline(
             nxt: dict = {}
             for in_l, (acc, path) in dist.items():
                 for out_l in outs:
-                    for fu in fuse_options(bases[i], f_in, w):
-                        s = acc + edge_seconds(
-                            bases[i], f_in, f_out, w, in_l, out_l, imb, fu)
-                        if out_l not in nxt or s < nxt[out_l][0]:
-                            nxt[out_l] = (s, path + [(in_l, out_l, fu)])
+                    s = acc + edge_seconds(
+                        bases[i], f_in, f_out, w, in_l, out_l, imb)
+                    if out_l not in nxt or s < nxt[out_l][0]:
+                        nxt[out_l] = (s, path + [(in_l, out_l)])
             dist = nxt
         total, path = dist[final]
         layers = tuple(
@@ -319,15 +288,14 @@ def plan_pipeline(
                 spmm=dataclasses.replace(
                     bases[i], mesh=w_mesh, dense_layout=in_l,
                     out_layout=out_l, interpret=interpret,
-                    precision=precision, fused=fu,
+                    precision=precision,
                 ),
                 f_in=dims[i][0], f_out=dims[i][1],
                 in_layout=in_l, out_layout=out_l,
                 seconds=edge_seconds(
-                    bases[i], dims[i][0], dims[i][1], w, in_l, out_l, imb,
-                    fu),
+                    bases[i], dims[i][0], dims[i][1], w, in_l, out_l, imb),
             )
-            for i, (in_l, out_l, fu) in enumerate(path)
+            for i, (in_l, out_l) in enumerate(path)
         )
         cand = GcnPipelinePlan(
             layers=layers, n_shards=w, cost_seconds=total,
@@ -360,7 +328,6 @@ def static_pipeline(
     n_layers: Optional[int] = None,
     impl: Optional[str] = None,
     precision: str = "f32",
-    fused: bool = False,
 ) -> GcnPipelinePlan:
     """A :class:`GcnPipelinePlan` from the config alone — no cost model.
 
@@ -370,9 +337,6 @@ def static_pipeline(
     per-layer-psum baseline.  The two differ *only* in layouts, which is
     what the parity tests and the pipeline benchmark need: an
     apples-to-apples traffic comparison at identical impl/blocks.
-    ``fused=True`` stamps every layer's plan fused — the single-launch
-    kernel per layer — again changing nothing else, so fused-vs-unfused
-    comparisons are equally apples-to-apples.
     """
     dims = _layer_dims(cfg, n_layers)
     width = (
@@ -387,7 +351,7 @@ def static_pipeline(
     base = SpmmPlan(
         impl=impl or cfg.spmm_impl, block_rows=cfg.block_rows,
         block_k=cfg.block_k, block_f=cfg.block_f, interpret=interpret,
-        mesh=mesh, precision=precision, fused=fused,
+        mesh=mesh, precision=precision,
     )
     layers = tuple(
         LayerPlan(
@@ -412,13 +376,11 @@ def pipeline_forward(
     layer dispatches through its own placed :class:`SpmmPlan` via
     :func:`repro.exec.dispatch.execute_layer` — so a ``row_sharded``
     boundary hands the next layer a padded, row-sharded activation whose
-    combination matmul runs on local rows, a ``fused`` layer runs
-    combination + aggregation as one launch, and the only full all-reduce
+    combination matmul runs on local rows, and the only full all-reduce
     is the final replicated epilogue.  Bitwise-identical to the
-    replicated unfused path: the reduce-scatter epilogue performs the
-    same per-row reduction as the psum, the fused kernel computes the
-    same padded tiles in the same order, and the pad rows (all zeros,
-    past every real row) never feed a nonzero adjacency column.
+    replicated path: the reduce-scatter epilogue performs the same
+    per-row reduction as the psum, and the pad rows (all zeros, past
+    every real row) never feed a nonzero adjacency column.
     """
     assert len(pplan.layers) == len(params), (
         f"pipeline plan has {len(pplan.layers)} layers, params have "

@@ -84,7 +84,6 @@ class SpmmPlan:
     out_layout: str = "replicated"    # epilogue: psum | reduce-scatter
     feature_axis: Optional[str] = None  # mesh axis splitting the F dimension
     precision: str = "f32"            # storage precision: f32 | bf16 | int8
-    fused: bool = False               # fuse combination + aggregation per layer
     effective_impl: Optional[str] = None
     degraded_reason: Optional[str] = None
 

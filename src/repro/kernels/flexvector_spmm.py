@@ -53,8 +53,7 @@ double-buffered ELL slabs and out block and two (tau, BR, BK) expansion
 tables, its dense operand (``sparse_grid_vmem_bytes``): the K*BF*b slab
 resident (10.2 MB at pubmed f32); the K*BF*2 bf16 slab and two
 (8*BK, BF) f32 staging chunks for ``resident_bf16`` (59.7 MB and 1 MiB
-at reddit); or eight streamed tiles.  The fused kernels hold a whole
-(R, BF) slab instead; ``plan.cost.fused_vmem_bytes`` counts it.
+at reddit); or eight streamed tiles.
 """
 
 from __future__ import annotations
@@ -527,216 +526,6 @@ def spmm_ell_sparse_grid(
             vmem_limit_bytes=vmem + _DEFAULT_SCOPED_VMEM),
         name="flexvector_sparse_grid_rows",
     )(starts, *args)
-
-
-def _combine_tile(x_ref, w_ref, b_ref, kb, block_k, k_real, cast_xw):
-    """In-VMEM dense combination for one k-tile: ``x_tile @ w + b``.
-
-    Replicates ``exec.quant.affine`` per tile (bf16 inputs arrive
-    pre-cast, accumulation is f32, bias added in f32), then zeroes the
-    rows past ``k_real`` so the tile matches the padded activation the
-    unfused path would have read from HBM.  ``cast_xw`` rounds through
-    the storage dtype (bf16 under bf16/int8 plans) the way
-    ``quant.cast_dense`` does between the two unfused launches.
-    """
-    xw = jax.lax.dot_general(
-        x_ref[...],
-        w_ref[...],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    xw = xw + b_ref[...].astype(jnp.float32)
-    rows = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, xw.shape, 0)
-    xw = jnp.where(rows < k_real, xw, 0.0)
-    if cast_xw is not None:
-        xw = xw.astype(cast_xw)
-    return xw
-
-
-def _fused_accumulate(cols_ref, vals_ref, scales_ref, xw, out_ref, kb_base,
-                      *, block_rows, block_k):
-    """Aggregate one combined k-tile into the resident output slab.
-
-    Per row block the expansion + dot shapes are exactly those of the
-    unfused kernels — (BR, tau) -> (BR, BK) @ (BK, BF) — so each output
-    element accumulates through the same sequence of partial products.
-    The row blocks run in a loop that writes each product straight into
-    its slice of the slab: unrolled, the products of a large graph would
-    all be live at once and spill far past VMEM.
-    """
-    acc = _acc_dtype(out_ref.dtype)
-    xw = xw.astype(acc)
-
-    def body(rb, carry):
-        rows = pl.ds(pl.multiple_of(rb * block_rows, block_rows), block_rows)
-        a_blk = _expand_block(
-            cols_ref[rows, :], vals_ref[rows, :], kb_base, block_k, acc
-        )
-        if scales_ref is not None:
-            a_blk = a_blk * scales_ref[rb].astype(acc)
-        out_ref[rows, :] += jax.lax.dot_general(
-            a_blk, xw, (((1,), (0,)), ((), ())),
-            preferred_element_type=out_ref.dtype,
-        )
-        return carry
-
-    jax.lax.fori_loop(0, cols_ref.shape[0] // block_rows, body, 0)
-
-
-def _fused_dense_kernel(*refs, block_rows, block_k, k_real, cast_xw, scaled):
-    scales_ref, (cols_ref, vals_ref, x_ref, w_ref, b_ref, out_ref) = (
-        _split_scales(refs, scaled))
-    kb = pl.program_id(1)
-
-    @pl.when(kb == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    xw = _combine_tile(x_ref, w_ref, b_ref, kb, block_k, k_real, cast_xw)
-    _fused_accumulate(
-        cols_ref, vals_ref, scales_ref, xw, out_ref, kb * block_k,
-        block_rows=block_rows, block_k=block_k,
-    )
-
-
-def spmm_ell_fused_dense_grid(
-    cols: jax.Array,   # (R, tau) int32, PAD_COL = -1 padding
-    vals: jax.Array,   # (R, tau)
-    x: jax.Array,      # (K, F_in) layer input, padded to k % block_k == 0
-    w: jax.Array,      # (F_in, F_out) layer weight, F_out % block_f == 0
-    b: jax.Array,      # (1, F_out) layer bias
-    *,
-    block_rows: int = 128,
-    block_k: int = 128,
-    block_f: int = 128,
-    k_real: Optional[int] = None,   # rows of x that are real (rest padding)
-    out_dtype=None,
-    interpret: Optional[bool] = None,
-    scales: Optional[jax.Array] = None,  # (r // block_rows,) f32 dequant
-    cast_xw=None,                        # storage round-trip dtype (bf16)
-) -> jax.Array:
-    """One launch per layer: combination ``x @ w + b`` fused with the
-    masked full-grid aggregation schedule.
-
-    The grid is (f-tile, k-tile); the whole (R, block_f) output slab is
-    the out block for every step of one f-tile, so it stays VMEM-resident
-    across the k sweep and the intermediate activation never exists in
-    HBM.  Per k-tile the kernel computes the (block_k, block_f) slice of
-    ``x @ w + b`` in VMEM and immediately feeds it to the row-wise
-    product expansion — the paper's two-stage formulation in one pass.
-    """
-    r, tau = cols.shape
-    k, f_in = x.shape
-    f_out = w.shape[1]
-    if r % block_rows or k % block_k or f_out % block_f:
-        raise ValueError("operands must be padded to block multiples")
-    ell_spec = pl.BlockSpec((r, tau), lambda fi, kb: (0, 0))
-    in_specs, args = _with_scales(
-        [
-            ell_spec,
-            ell_spec,
-            pl.BlockSpec((block_k, f_in), lambda fi, kb: (kb, 0)),
-            pl.BlockSpec((f_in, block_f), lambda fi, kb: (0, fi)),
-            pl.BlockSpec((1, block_f), lambda fi, kb: (0, fi)),
-        ],
-        (cols, vals, x, w, b), scales, r, block_rows,
-    )
-    return pl.pallas_call(
-        functools.partial(
-            _fused_dense_kernel, block_rows=block_rows, block_k=block_k,
-            k_real=k if k_real is None else k_real, cast_xw=cast_xw,
-            scaled=scales is not None,
-        ),
-        grid=(f_out // block_f, k // block_k),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((r, block_f), lambda fi, kb: (0, fi)),
-        out_shape=jax.ShapeDtypeStruct((r, f_out), out_dtype or jnp.float32),
-        interpret=_default_interpret(interpret),
-        name="flexvector_fused_dense_grid",
-    )(*args)
-
-
-def _fused_sparse_kernel(kb_ids_ref, *refs, block_rows, block_k, k_real,
-                         cast_xw, scaled):
-    scales_ref, (cols_ref, vals_ref, x_ref, w_ref, b_ref, out_ref) = (
-        _split_scales(refs, scaled))
-    s = pl.program_id(1)
-
-    @pl.when(s == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(kb_ids_ref[s] >= 0)
-    def _step():
-        kb = kb_ids_ref[s]
-        xw = _combine_tile(x_ref, w_ref, b_ref, kb, block_k, k_real, cast_xw)
-        _fused_accumulate(
-            cols_ref, vals_ref, scales_ref, xw, out_ref, kb * block_k,
-            block_rows=block_rows, block_k=block_k,
-        )
-
-
-def spmm_ell_fused_sparse_grid(
-    cols: jax.Array,
-    vals: jax.Array,
-    x: jax.Array,
-    w: jax.Array,
-    b: jax.Array,
-    kb_ids: jax.Array,   # (n_steps,) int32 k-tile per grid step, -1 = no-op
-    *,
-    block_rows: int = 128,
-    block_k: int = 128,
-    block_f: int = 128,
-    k_real: Optional[int] = None,
-    out_dtype=None,
-    interpret: Optional[bool] = None,
-    scales: Optional[jax.Array] = None,
-    cast_xw=None,
-) -> jax.Array:
-    """Fused launch over a scalar-prefetched occupied-k-tile list.
-
-    ``kb_ids`` comes from :func:`repro.core.dataflow.plan_fused_k_schedule`
-    — every k-tile occupied anywhere, in the same global hot-first order
-    the unfused sparse grid applies per row block.  ``-1`` entries are
-    no-op steps (used to equalize per-shard schedule lengths under
-    ``shard_map``); their index maps clamp to tile 0 and the step body is
-    skipped entirely.
-    """
-    r, tau = cols.shape
-    k, f_in = x.shape
-    f_out = w.shape[1]
-    if r % block_rows or k % block_k or f_out % block_f:
-        raise ValueError("operands must be padded to block multiples")
-    ell_spec = pl.BlockSpec((r, tau), lambda fi, s, kb: (0, 0))
-    in_specs, args = _with_scales(
-        [
-            ell_spec,
-            ell_spec,
-            pl.BlockSpec(
-                (block_k, f_in), lambda fi, s, kb: (jnp.maximum(kb[s], 0), 0)
-            ),
-            pl.BlockSpec((f_in, block_f), lambda fi, s, kb: (0, fi)),
-            pl.BlockSpec((1, block_f), lambda fi, s, kb: (0, fi)),
-        ],
-        (cols, vals, x, w, b), scales, r, block_rows,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(f_out // block_f, int(kb_ids.shape[0])),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((r, block_f), lambda fi, s, kb: (0, fi)),
-    )
-    return pl.pallas_call(
-        functools.partial(
-            _fused_sparse_kernel, block_rows=block_rows, block_k=block_k,
-            k_real=k if k_real is None else k_real, cast_xw=cast_xw,
-            scaled=scales is not None,
-        ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r, f_out), out_dtype or jnp.float32),
-        interpret=_default_interpret(interpret),
-        name="flexvector_fused_sparse_grid",
-    )(kb_ids, *args)
 
 
 def _default_interpret(interpret: Optional[bool]) -> bool:

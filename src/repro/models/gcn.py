@@ -102,8 +102,8 @@ class GraphArrays:
     A pytree: a jitted forward takes it as an argument, so its operands
     live on the device once per graph and none of them becomes a constant
     of the compiled program.  It serves the static single-device plans;
-    the pipeline planner, fused launches and the sharded split plan on
-    the host ``GCNGraph``.
+    the pipeline planner and the sharded split plan on the host
+    ``GCNGraph``.
     """
 
     cols: jax.typing.ArrayLike     # (R, tau) int32
@@ -223,8 +223,6 @@ def gcn_forward(
         layer_plan = plan
         if shard_out and i == n_layers - 1:
             layer_plan = dataclasses.replace(plan, out_layout="row_sharded")
-        # combination + aggregation under the plan's fusion decision: one
-        # launch when the plan says fused, the classic two otherwise.
         x = execute_layer(
             layer_plan, operands, x, p, w_block_rows=plan.block_rows)
         if i < n_layers - 1:

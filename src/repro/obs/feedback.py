@@ -23,8 +23,8 @@ so "never worse than static" holds in measured terms.
 Keys are strings so the store survives JSON round-trips:
 
 * ``bucket_key(bucket, feature_dim)`` → ``"b{nodes}x{rows}/f{fdim}"``
-* ``plan_key(impl, br, bk, bf, width, precision, fused)`` →
-  ``"reference/r128.k128.f128/w1/f32/unfused"``
+* ``plan_key(impl, br, bk, bf, width, precision)`` →
+  ``"reference/r128.k128.f128/w1/f32"``
 """
 
 from __future__ import annotations
@@ -61,19 +61,17 @@ def bucket_key(bucket, feature_dim: int) -> str:
 
 
 def plan_key(impl: str, block_rows: int, block_k: int, block_f: int,
-             width: int = 1, precision: str = "f32",
-             fused: bool = False) -> str:
+             width: int = 1, precision: str = "f32") -> str:
     """Canonical identity of one plan candidate in the autoplan search."""
     return (f"{impl}/r{int(block_rows)}.k{int(block_k)}.f{int(block_f)}"
-            f"/w{int(width)}/{precision}/"
-            f"{'fused' if fused else 'unfused'}")
+            f"/w{int(width)}/{precision}")
 
 
 def plan_key_from_plan(plan) -> str:
     """`plan_key` of a concrete ``SpmmPlan`` (pre-resolve ``impl``)."""
     return plan_key(plan.impl, plan.block_rows, plan.block_k, plan.block_f,
                     int(getattr(plan, "n_shards", 1) or 1),
-                    plan.precision, bool(plan.fused))
+                    plan.precision)
 
 
 class PlanFeedback:

@@ -21,7 +21,7 @@ sampling, induction, vertex-cut and padding stages) on the submitting
 thread, admission in ``runtime.queue``, queue wait, batch close and
 execute (stack, dispatch, fetch) on the worker, with the execute span
 stamped with the :class:`~repro.exec.plan.SpmmPlan` attributes
-(impl, precision, fused, mesh width, block sizes) that served it.
+(impl, precision, mesh width, block sizes) that served it.
 Device time per layer comes from the profiler's trace, under the named
 scopes of the compiled steps, not from these spans.
 
@@ -394,7 +394,6 @@ def plan_attributes(plan, **extra: object) -> Dict[str, object]:
         "impl": getattr(plan, "effective_impl", None)
         or getattr(plan, "impl", "?"),
         "precision": getattr(plan, "precision", "f32"),
-        "fused": bool(getattr(plan, "fused", False)),
         "mesh_width": int(getattr(plan, "n_shards", 1) or 1),
         "block_rows": getattr(plan, "block_rows", None),
         "block_k": getattr(plan, "block_k", None),

@@ -108,16 +108,9 @@ def choose_plan(
     invariant.
 
     ``f_in`` (the layer's *input* feature width) switches the search to
-    whole-layer scoring and adds kernel fusion as a search dimension:
-    every candidate is priced as a full GCN layer — unfused as
+    whole-layer scoring: every candidate is priced as a full GCN layer,
     ``spmm_cost + combination_seconds`` (the intermediate activation
-    written and read back), fused as :func:`~repro.plan.cost.fused_layer_cost`
-    (no intermediate traffic, combination recomputed per f-tile) — and
-    fused candidates are admitted only when
-    :func:`~repro.plan.cost.fused_viable` says the resident output slab +
-    ELL table fit VMEM.  The static plan stays the first candidate and is
-    scored unfused, so a fused plan is chosen only when the model prices
-    the whole fused layer strictly below the whole static layer.
+    written and read back).
 
     ``feedback`` + ``feedback_key`` close ROADMAP item 5's loop: when a
     :class:`~repro.obs.feedback.PlanFeedback` store holds a measured
@@ -206,23 +199,14 @@ def choose_plan(
             shard_imbalance=width_imbalance(width), device=device,
         )
 
-    def layer_score(impl, br, bk, bf, width, precision, fuse):
+    def layer_score(impl, br, bk, bf, width, precision):
         """(comparison seconds, CostBreakdown receipt) for one candidate.
 
         Without ``f_in`` the comparison scalar is the SpMM bound alone
-        (historical behavior).  With ``f_in`` it is the whole layer:
-        unfused adds the standalone combination launch (which writes the
-        intermediate activation the SpMM then re-reads); fused is the
-        single-launch estimate with that round trip gone.
+        (historical behavior).  With ``f_in`` it is the whole layer: the
+        SpMM plus the standalone combination launch (which writes the
+        intermediate activation the SpMM then re-reads).
         """
-        if fuse:
-            c = cost_mod.fused_layer_cost(
-                stats, f_in, feature_dim, impl=impl, block_rows=br,
-                block_k=bk, block_f=bf, n_shards=width,
-                dtype_bytes=dtype_bytes, precision=precision,
-                shard_imbalance=width_imbalance(width), device=device,
-            )
-            return c.seconds, c
         c = score(impl, br, bk, bf, width, precision)
         if f_in is None:
             return c.seconds, c
@@ -232,19 +216,9 @@ def choose_plan(
         )
         return c.seconds + comb, c
 
-    def fuse_options(impl, br, bk, bf, width, precision):
-        if f_in is None or impl == "reference":
-            return (False,)
-        if not cost_mod.fused_viable(
-            stats, f_in, block_rows=br, block_k=bk, block_f=bf,
-            precision=precision, n_shards=width, device=device,
-        ):
-            return (False,)
-        return (False, True)
-
     measured_used = 0
 
-    def with_measured(modeled, impl, br, bk, bf, w, prec, fuse):
+    def with_measured(modeled, impl, br, bk, bf, w, prec):
         """A candidate's comparison scalar: measured EWMA if one exists,
         else the modeled seconds (cold-start fallback)."""
         nonlocal measured_used
@@ -253,7 +227,7 @@ def choose_plan(
         from repro.obs.feedback import plan_key  # deferred: no cycle
 
         m = feedback.measured(
-            feedback_key, plan_key(impl, br, bk, bf, w, prec, fuse))
+            feedback_key, plan_key(impl, br, bk, bf, w, prec))
         if m is None:
             return modeled
         measured_used += 1
@@ -263,10 +237,10 @@ def choose_plan(
     static_impl = base_impl if (
         schedulable or base_impl != "pallas_sparse") else "pallas"
     static_secs, static_cost = layer_score(
-        static_impl, *base_blocks, mesh_width, "f32", False)
+        static_impl, *base_blocks, mesh_width, "f32")
     static_secs = with_measured(
-        static_secs, static_impl, *base_blocks, mesh_width, "f32", False)
-    best = (static_impl, *base_blocks, mesh_width, "f32", False)
+        static_secs, static_impl, *base_blocks, mesh_width, "f32")
+    best = (static_impl, *base_blocks, mesh_width, "f32")
     best_secs, best_cost = static_secs, static_cost
 
     n_cand = 1
@@ -276,18 +250,14 @@ def choose_plan(
                 for bf in blocks_for(base_blocks[2]):
                     for w in widths:
                         for prec in precs:
-                            for fuse in fuse_options(
-                                    impl, br, bk, bf, w, prec):
-                                n_cand += 1
-                                s, c = layer_score(
-                                    impl, br, bk, bf, w, prec, fuse)
-                                s = with_measured(
-                                    s, impl, br, bk, bf, w, prec, fuse)
-                                if s < best_secs:
-                                    best = (impl, br, bk, bf, w, prec, fuse)
-                                    best_secs, best_cost = s, c
+                            n_cand += 1
+                            s, c = layer_score(impl, br, bk, bf, w, prec)
+                            s = with_measured(s, impl, br, bk, bf, w, prec)
+                            if s < best_secs:
+                                best = (impl, br, bk, bf, w, prec)
+                                best_secs, best_cost = s, c
 
-    impl, br, bk, bf, width, precision, fused = best
+    impl, br, bk, bf, width, precision = best
     hot_k_first = True
     if impl == "pallas_sparse" and stats.ell is not None:
         hot_k_first = choose_hot_k_first(
@@ -303,7 +273,7 @@ def choose_plan(
     plan = SpmmPlan(
         impl=impl, block_rows=br, block_k=bk, block_f=bf,
         interpret=interpret, mesh=chosen_mesh, hot_k_first=hot_k_first,
-        precision=precision, fused=fused,
+        precision=precision,
     )
     static_plan = SpmmPlan(
         impl=base_impl, block_rows=base_blocks[0], block_k=base_blocks[1],

@@ -71,7 +71,6 @@ class DeviceModel:
     hbm_bw: float = 819e9                # bytes/s per chip
     ici_bw: float = 50e9                 # bytes/s per link
     hbm_capacity_bytes: float = 16e9
-    vmem_bytes: float = 16e6             # scoped VMEM of one Pallas kernel
     dram_pj_per_byte: float = PJ_PER_BYTE_DRAM
     dense_buffer_bytes: int = 2048       # SRAM-energy anchor (HWConfig)
     sparse_buffer_bytes: int = 256
@@ -160,31 +159,6 @@ class GraphStats:
             pairs = int(min(n_rb * n_kb, max(self.nnz, n_rb)))
         self._occ_cache[key] = pairs
         return pairs
-
-    def occupied_k_tiles(self, block_k: int) -> int:
-        """k-tiles holding at least one nonzero *anywhere* in the matrix —
-        the number of steps the fused sparse-grid launch streams an
-        ``X`` tile for.
-
-        Exact via the host container when available; otherwise the
-        spread upper bound min(n_kb, nnz) (every nonzero in its own
-        tile).  On power-law graphs the exact count is far below the
-        bound: nonzeros concentrate in a few hot (supernode) tiles.
-        """
-        key = ("ktiles", block_k)
-        hit = self._occ_cache.get(key)
-        if hit is not None:
-            return hit
-        n_kb = _ceil_div(self.n_dense_rows, block_k)
-        if self.ell is not None:
-            tiles = int(
-                self.ell.block_occupancy(self.padded_rows, block_k)
-                .any(axis=0).sum()
-            )
-        else:
-            tiles = int(min(n_kb, max(self.nnz, 1)))
-        self._occ_cache[key] = max(tiles, 1)
-        return max(tiles, 1)
 
 
 def graph_stats_from_ell(ell: TiledELL) -> GraphStats:
@@ -462,188 +436,6 @@ def combination_seconds(
         flops / shards, dram / shards, 0.0, device
     )
     return max(compute, memory)
-
-
-def fused_vmem_bytes(
-    padded_rows: int,
-    tau: int,
-    f_in: int,
-    *,
-    block_rows: int = 128,
-    block_k: int = 128,
-    block_f: int = 128,
-    precision: str = "f32",
-    n_shards: int = 1,
-) -> float:
-    """VMEM footprint of one fused launch (per shard), as the TPU
-    compiler lays it out.
-
-    The fused kernel holds the *entire* per-shard output column slab
-    resident — ``(r_pad / n_shards, block_f)`` f32 — and the full ELL
-    table, whose ``(r, tau)`` planes are stored in (sublane, 128-lane)
-    tiles, so each costs ``r x 128`` elements whatever ``tau`` is.  The
-    streamed ``X`` tile and the weight/bias slabs are double-buffered;
-    the expansion, ``XW`` and product tiles are per-step scratch.  The
-    int8 scale vector lives in SMEM and costs no VMEM.  Compiles for a
-    described v5e (scoped limit raised, 120k rows) agree with this count
-    to within 1%: the slab and both lane-padded tables, each held once.
-    """
-    lanes = 128
-    act_b = _PRECISION_ACT_BYTES.get(precision, 4)
-    val_b = _PRECISION_BYTES.get(precision, 4)
-    w_b = 4 if precision == "f32" else 2
-    r_pad = _round_up(
-        _ceil_div(padded_rows, max(n_shards, 1)), block_rows
-    )
-    r_tiled = _round_up(r_pad, 32)   # int8 packs 32 rows per sublane tile
-    out_slab = float(r_pad) * block_f * 4
-    ell_table = float(r_tiled) * _round_up(tau, lanes) * (4 + val_b)
-    x_tile = 2.0 * block_k * _round_up(f_in, lanes) * act_b
-    w_slab = 2.0 * _round_up(f_in, 16) * block_f * w_b
-    bias = 2.0 * 8 * block_f * 4
-    scratch = float(block_k) * block_f * 4 + float(block_rows) * (
-        block_k + block_f) * 4
-    return out_slab + ell_table + x_tile + w_slab + bias + scratch
-
-
-def fused_layer_cost(
-    stats: GraphStats,
-    f_in: int,
-    f_out: int,
-    *,
-    impl: str = "pallas",
-    block_rows: int = 128,
-    block_k: int = 128,
-    block_f: int = 128,
-    n_shards: int = 1,
-    out_layout: str = "replicated",
-    dense_layout: str = "replicated",
-    shard_imbalance: float = 1.0,
-    dtype_bytes: int = 4,
-    idx_bytes: int = 4,
-    precision: str = "f32",
-    device: DeviceModel = TPU_V5E,
-) -> CostBreakdown:
-    """Traffic/energy/time estimate of one *fused* GCN layer:
-    ``A @ (X @ W + b)`` in a single launch.
-
-    Covers the whole layer, so compare against
-    ``spmm_cost(...).seconds + combination_seconds(...)`` plus the
-    intermediate writeback — not against ``spmm_cost`` alone.  The fused
-    traffic shape differs from the two-launch sum in three ways:
-
-    * the intermediate ``(K, F_out)`` activation is never written or
-      read back (``fused_writeback_saved`` in the ledger);
-    * the ELL table streams *once* (the constant-index BlockSpec keeps
-      it VMEM-resident for the whole grid) instead of once per visit;
-    * ``X`` streams once per f-tile over the *occupied* k-tiles
-      (``GraphStats.occupied_k_tiles``; all of them under the masked
-      ``pallas`` schedule), and the combination FLOPs are recomputed
-      per f-tile — the classic fusion recompute-vs-traffic trade.
-    """
-    f = max(f_out, 1)
-    r_pad = _round_up(stats.padded_rows, block_rows)
-    k_pad = _round_up(stats.n_dense_rows, block_k)
-    f_pad = _round_up(f, block_f)
-    n_rb = _ceil_div(r_pad, block_rows)
-    n_kb = _ceil_div(k_pad, block_k)
-    n_fb = _ceil_div(f_pad, block_f)
-    if precision == "f32":
-        val_bytes, act_bytes = dtype_bytes, dtype_bytes
-    else:
-        val_bytes = device.bytes_per_element(precision)
-        act_bytes = _PRECISION_ACT_BYTES[precision]
-    if impl == "pallas_sparse":
-        occ_kb = min(stats.occupied_k_tiles(block_k), n_kb)
-    else:
-        occ_kb = n_kb
-
-    sparse_bytes = float(r_pad) * stats.tau * (idx_bytes + val_bytes)
-    if precision == "int8":
-        sparse_bytes += n_rb * 4.0
-    x_bytes = float(n_fb) * occ_kb * block_k * f_in * act_bytes
-    w_bytes = float(f_in) * f_pad * val_bytes
-    out_bytes = float(r_pad + stats.n_out_rows) * f * act_bytes
-    dram_bytes = sparse_bytes + x_bytes + w_bytes + out_bytes
-
-    # Combination recompute (every occupied k-tile x full f_pad) plus the
-    # aggregation dots: the fused grid runs *every* row block at every
-    # visited step (empty blocks expand to zeros), unlike the unfused
-    # block-skipping grid.
-    flops = (
-        2.0 * occ_kb * block_k * f_in * f_pad
-        + 2.0 * n_rb * occ_kb * block_rows * stats.tau * f_pad
-    )
-    grid_steps = n_fb * occ_kb
-
-    if out_layout == "row_sharded":
-        coll_bytes = reduce_scatter_bytes(
-            stats.n_out_rows, f, n_shards, dtype_bytes)
-    else:
-        coll_bytes = psum_bytes(stats.n_out_rows, f, n_shards, dtype_bytes)
-    if dense_layout == "row_sharded":
-        # The fused prologue gathers the layer *input* at F_in width —
-        # narrower than the unfused path's F_out-wide activation gather
-        # whenever the stack widens.
-        coll_bytes += all_gather_bytes(
-            stats.n_dense_rows, f_in, n_shards, act_bytes)
-
-    shards = max(n_shards, 1)
-    imb = max(float(shard_imbalance), 1.0)
-    compute, memory, collective, dominant = roofline_seconds(
-        flops / shards * imb, dram_bytes / shards * imb, coll_bytes, device
-    )
-    compute += (grid_steps / shards) * imb * device.step_overhead_s
-    if compute > max(memory, collective):
-        dominant = "compute"
-    return CostBreakdown(
-        flops=flops,
-        dram_bytes=dram_bytes,
-        collective_bytes=coll_bytes,
-        sram_pj=(x_bytes + w_bytes + out_bytes)
-        * sram_pj_per_byte(device.dense_buffer_bytes)
-        + sparse_bytes * sram_pj_per_byte(device.sparse_buffer_bytes),
-        dram_pj=dram_bytes * device.dram_pj_per_byte,
-        compute_s=compute,
-        memory_s=memory,
-        collective_s=collective,
-        dominant=dominant,
-    )
-
-
-def fused_layer_seconds(
-    stats: GraphStats, f_in: int, f_out: int, **kw
-) -> float:
-    """Roofline seconds of one fused layer — argmin-ready scalar."""
-    return fused_layer_cost(stats, f_in, f_out, **kw).seconds
-
-
-def fused_viable(
-    stats: GraphStats,
-    f_in: int,
-    *,
-    block_rows: int = 128,
-    block_k: int = 128,
-    block_f: int = 128,
-    precision: str = "f32",
-    n_shards: int = 1,
-    device: DeviceModel = TPU_V5E,
-    headroom: float = 0.9,
-) -> bool:
-    """Does the fused launch's resident footprint fit in VMEM?
-
-    ``device.vmem_bytes`` is the scoped VMEM a Pallas kernel may use
-    (16 MiB by default on v5e); the whole footprint is held to it, which
-    is stricter than the compiler (it counts only the slab and scratch
-    against that limit, the tables against the 128 MiB of the core), so
-    an admitted launch always compiles.  ``headroom`` reserves a fraction
-    for compiler scratch the estimate cannot see.
-    """
-    return fused_vmem_bytes(
-        stats.padded_rows, stats.tau, f_in,
-        block_rows=block_rows, block_k=block_k, block_f=block_f,
-        precision=precision, n_shards=n_shards,
-    ) <= device.vmem_bytes * headroom
 
 
 def bucket_forward_seconds(

@@ -153,7 +153,6 @@ class MicroBatcher:
         mesh=None,
         autoplan: bool = False,
         precision: str = "f32",
-        fused: Optional[bool] = None,
         feedback=None,
     ):
         self.cfg = cfg
@@ -169,14 +168,6 @@ class MicroBatcher:
         # rung warmed never triggers a recompile — it informs the next
         # engine build instead.
         self.feedback = feedback
-        # Kernel fusion per layer: ``None`` leaves the decision to the
-        # planner (``autoplan=True`` lets the pipeline DP fuse layers it
-        # prices cheaper; otherwise plans run unfused as always), ``True``
-        # forces the single-launch fused kernel on every pallas layer,
-        # ``False`` forces two launches everywhere.  The flag is baked
-        # into each rung's trace at first sight, so it never triggers a
-        # post-warmup recompile.
-        self.fused = fused
         # Default storage precision for every rung; per-rung overrides
         # (the engine's accuracy-budgeted warmup choice) land in
         # _bucket_precisions via set_bucket_precision *before* warmup
@@ -271,19 +262,10 @@ class MicroBatcher:
         coalesced forward traces bare arrays with no host-side row split;
         bucket chunks shard at request granularity instead.  Cached per
         (bucket, feature_dim), so the choice is made once and the
-        zero-recompile-after-warmup invariant is untouched.  The pipeline
-        planner's DP now weighs a *fused* variant of every layer, so an
-        autoplanned rung may come back with fused per-layer plans; an
-        explicit ``MicroBatcher(fused=...)`` overrides the decision both
-        ways.
+        zero-recompile-after-warmup invariant is untouched.
         """
         if not self.autoplan:
-            plans = [self.plan] * self.cfg.n_layers
-            if self.fused is not None:
-                plans = [
-                    dataclasses.replace(p, fused=self.fused) for p in plans
-                ]
-            return plans
+            return [self.plan] * self.cfg.n_layers
         key = (bucket, feature_dim)
         plans = self._layer_plans.get(key)
         if plans is None and self.feedback is not None:
@@ -299,11 +281,6 @@ class MicroBatcher:
                 # override what was actually measured.
                 plan = self.plan_for_bucket(bucket, feature_dim)
                 plans = [plan] * self.cfg.n_layers
-                if self.fused is not None:
-                    plans = [
-                        dataclasses.replace(p, fused=self.fused)
-                        for p in plans
-                    ]
                 self._layer_plans[key] = plans
                 return plans
         if plans is None:
@@ -326,10 +303,6 @@ class MicroBatcher:
             plans = [
                 lp.spmm.resolve(schedulable=False) for lp in pplan.layers
             ]
-            if self.fused is not None:
-                plans = [
-                    dataclasses.replace(p, fused=self.fused) for p in plans
-                ]
             self._layer_plans[key] = plans
         return plans
 
@@ -464,8 +437,6 @@ class MicroBatcher:
             )
             x = feats.reshape(b * nodes_b, f_in)
             for i in range(cfg.n_layers):
-                # combination + aggregation under the layer plan's fusion
-                # decision: one launch when fused, the classic two when not.
                 x = execute_layer(
                     layer_plans[i], operands, x, qparams[f"layer_{i}"],
                     w_block_rows=cfg.block_rows,

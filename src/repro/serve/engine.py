@@ -93,7 +93,6 @@ class ServeEngine:
         ladder_growth=None,
         precision: str = "f32",
         accuracy_budget: float = 0.05,
-        fused: Optional[bool] = None,
         feedback=None,
     ):
         from repro.exec import quant
@@ -154,7 +153,6 @@ class ServeEngine:
             mesh=mesh,
             autoplan=autoplan,
             precision=self._static_precision,
-            fused=fused,
             feedback=feedback,
         )
         # repro.obs.feedback.PlanFeedback (or None): measured per-rung

@@ -297,11 +297,10 @@ def _launch_residency(plan, arrays: GraphArrays, n_layers: int,
 
 def _takes_arrays(plan) -> bool:
     """Does a step under ``plan`` read the graph only as arrays?  The
-    pipeline planner, fused launches and sharded splits plan on the host
-    graph."""
+    pipeline planner and sharded splits plan on the host graph."""
     from repro.exec import SpmmPlan
 
     if plan is None:
         return True
-    return (isinstance(plan, SpmmPlan) and not plan.fused
-            and not plan.sharded and not plan.feature_sharded)
+    return (isinstance(plan, SpmmPlan) and not plan.sharded
+            and not plan.feature_sharded)

@@ -3,10 +3,10 @@
 Covers the PR's acceptance criteria: `segment_accumulate` edge cases, the
 recorded (not silent) pallas_sparse degradation, one dispatch path behind
 both SpMM entry points, and sharded-vs-reference parity.  The sharded
-parametrization adapts to the available device count — on the 1-device
-tier-1 run only the trivial mesh executes in-process, and a subprocess
-test provides real 2-/4-device coverage; the CI multi-device job (8
-virtual devices) runs every cell in-process.
+parametrization adapts to the available device count: the root
+``conftest.py`` gives the suite four virtual devices, so every cell runs
+in-process, and a subprocess test provides 2-/4-device coverage that
+does not depend on the parent's device count.
 """
 
 import os
@@ -283,3 +283,48 @@ def test_gcn_forward_plan_matches_default():
                           plan=plan_for_config(cfg, mesh=_data_mesh(1)))
     np.testing.assert_allclose(np.asarray(planned), np.asarray(base),
                                rtol=1e-6, atol=1e-6)
+
+
+#: Bounds of a kernel forward against the reference impl, as fractions of
+#: the output's largest magnitude.  f32: the compiler blocks each f32
+#: contraction by its shapes, so sums may round differently (1e-5, about
+#: 80 ulps; measured 1.7e-7 at most).  bf16 and int8: the products and
+#: dequantization are the same, and only the order of the f32 adds
+#: differs, which moves the largest logit by under two ulps (2.5e-7;
+#: measured 7.4e-8 at most, over four graph seeds).
+KERNEL_VS_REFERENCE = {"f32": 1e-5, "bf16": 2.5e-7, "int8": 2.5e-7}
+
+
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("precision", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("impl", ["pallas", "pallas_sparse"])
+def test_kernel_forward_matches_reference_impl(impl, precision, jitted):
+    """``gcn_forward`` through a kernel at each storage precision equals
+    the ``reference`` impl at that precision, eager and compiled."""
+    import dataclasses
+
+    from repro.models.gcn import GCNConfig, GCNGraph, gcn_forward, init_params
+
+    n = 96
+    adj = random_power_law_csr(n, n, 700, seed=0)
+    feats = jnp.asarray(
+        np.random.default_rng(1).standard_normal((n, 12)), jnp.float32)
+    outs = {}
+    for which in ("reference", impl):
+        cfg = GCNConfig(in_dim=12, hidden_dim=64, out_dim=8, n_layers=2,
+                        tau=6, spmm_impl=which, block_rows=16, block_k=16,
+                        block_f=16)
+        graph = GCNGraph.build(adj, cfg)
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        plan = dataclasses.replace(plan_for_config(cfg), precision=precision)
+
+        def fwd(p, x, graph=graph, cfg=cfg, plan=plan):
+            return gcn_forward(p, graph, x, cfg, plan=plan)
+
+        outs[which] = np.asarray(
+            (jax.jit(fwd) if jitted else fwd)(params, feats))
+    ref, got = outs["reference"], outs[impl]
+    assert np.isfinite(got).all()
+    scale = float(np.abs(ref).max())
+    np.testing.assert_allclose(
+        got, ref, rtol=0, atol=KERNEL_VS_REFERENCE[precision] * scale)

@@ -457,6 +457,22 @@ def test_plan_feedback_save_load_round_trip(tmp_path):
     assert len(back) == 3
 
 
+def test_plan_feedback_loads_a_store_of_older_keys(tmp_path):
+    """A store whose plan keys carry a trailing segment no longer written
+    (``.../f32/unfused``) loads whole, and its entries simply miss."""
+    path = str(tmp_path / "old.json")
+    with open(path, "w") as f:
+        json.dump({"version": 1, "ewma": 0.3, "entries": {
+            "b1": {"reference/r128.k128.f128/w1/f32/unfused":
+                   {"seconds": 0.5, "count": 2}}}}, f)
+    fb = PlanFeedback.load(path)
+    assert len(fb) == 1 and fb.has_bucket("b1")
+    key = plan_key("reference", 128, 128, 128, 1, "f32")
+    assert key == "reference/r128.k128.f128/w1/f32"
+    assert fb.measured("b1", key) is None
+    assert not os.path.exists(path + ".corrupt")
+
+
 def test_plan_feedback_load_missing_and_corrupt(tmp_path):
     missing = str(tmp_path / "nope.json")
     assert len(PlanFeedback.load(missing)) == 0
@@ -526,7 +542,7 @@ def test_measured_latency_overrides_model_choice():
 
     # pick any other enumerated candidate as the measured winner
     rival = ("reference", 64, 64, 64)
-    rival_key = plan_key(*rival, 1, "f32", False)
+    rival_key = plan_key(*rival, 1, "f32")
     assert rival_key != base_key
 
     fb = PlanFeedback()
@@ -545,7 +561,7 @@ def test_never_worse_than_static_holds_in_measured_terms():
 
     stats = synthetic_stats(rows=512, n_out_rows=256, n_dense_rows=256,
                             nnz=2048, tau=8)
-    static_key = plan_key("reference", 128, 128, 128, 1, "f32", False)
+    static_key = plan_key("reference", 128, 128, 128, 1, "f32")
     fb = PlanFeedback()
     fb.record("bkt", static_key, seconds=1e-9)    # static: measured fastest
     choice = choose_plan(

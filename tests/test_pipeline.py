@@ -5,11 +5,11 @@ Covers the PR's acceptance criteria: the layout-cost terms
 (never costed worse than the static per-layer default, deterministic),
 hot-k-first and width selection in autoplan, bitwise parity of the
 pipelined chain against the per-layer-psum path on 1/2/4 devices for all
-three impls, the row-sharded ``gcn_forward`` output layout, the
+three impls at all three storage precisions, the row-sharded ``gcn_forward`` output layout, the
 collective ledger, and the zero-recompile invariant of the autoplanned
 batcher.  Like ``test_exec``, multi-device cells adapt to the available
-device count and a subprocess test supplies real 2-/4-device coverage on
-the 1-device tier-1 run.
+device count (four virtual devices, from the root ``conftest.py``) and a
+subprocess test supplies 2-/4-device coverage of its own.
 """
 
 import os
@@ -264,9 +264,10 @@ def test_ledger_records_and_resets():
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("precision", ["f32", "bf16", "int8"])
 @pytest.mark.parametrize("impl", IMPLS)
 @pytest.mark.parametrize("n_dev", [1, 2, 4])
-def test_pipeline_parity_bitwise(impl, n_dev):
+def test_pipeline_parity_bitwise(impl, n_dev, precision):
     if jax.device_count() < n_dev:
         pytest.skip(f"needs {n_dev} devices, have {jax.device_count()} "
                     f"(run under XLA_FLAGS=--xla_force_host_platform_"
@@ -279,12 +280,16 @@ def test_pipeline_parity_bitwise(impl, n_dev):
     mesh = _data_mesh(n_dev) if n_dev > 1 else None
     base = np.asarray(gcn_forward(
         params, g, feats, cfg,
-        plan=static_pipeline(cfg, mesh, pipelined=False)))
+        plan=static_pipeline(cfg, mesh, pipelined=False,
+                             precision=precision)))
     pipe = np.asarray(gcn_forward(
         params, g, feats, cfg,
-        plan=static_pipeline(cfg, mesh, pipelined=True)))
+        plan=static_pipeline(cfg, mesh, pipelined=True,
+                             precision=precision)))
     # the reduce-scatter epilogue performs the same per-row reduction as
-    # the psum, so the chained stack is bitwise-identical, not just close
+    # the psum, so the chained stack is bitwise-identical, not just close,
+    # at every storage precision
+    assert np.isfinite(pipe).all()
     np.testing.assert_array_equal(pipe, base)
 
 

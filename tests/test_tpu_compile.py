@@ -21,7 +21,7 @@ import os
 import pytest
 
 ROWS, TAU, K, K_REAL, F, F_IN = 25_856, 6, 19_840, 19_717, 128, 500
-VISITS, FUSED_STEPS, BLOCK = 14_281, 155, 128
+VISITS, BLOCK = 14_281, 128
 # reddit at Table III size (graph seed 0, tau 6, 128-row blocks)
 REDDIT_ROWS, REDDIT_K, REDDIT_VISITS = 4_205_568, 233_088, 17_685_868
 PRECISIONS = ("f32", "bf16", "int8")
@@ -186,62 +186,10 @@ def test_largest_bf16_slab_compiles(shape):
     assert "%flexvector_sparse_grid_rows" in text
 
 
-def _fused(shape, kind, precision, rows, f_in=F_IN):
-    import jax.numpy as jnp
-
-    from repro.kernels import flexvector_spmm as fv
-
-    vdt, adt = _dtypes(precision)
-    kw = dict(interpret=False, k_real=K_REAL,
-              cast_xw=None if precision == "f32" else jnp.bfloat16)
-    ell = (shape((rows, TAU), jnp.int32), shape((rows, TAU), vdt))
-    layer = (shape((K, f_in), adt), shape((f_in, F), adt),
-             shape((1, F), jnp.float32))
-    sc = _scales(shape, precision, rows)
-    if kind == "dense":
-        return _compile(
-            lambda c, v, x, w, b, *s: fv.spmm_ell_fused_dense_grid(
-                c, v, x, w, b, scales=s[0] if s else None, **kw),
-            *ell, *layer, sc)
-    return _compile(
-        lambda c, v, x, w, b, kb, *s: fv.spmm_ell_fused_sparse_grid(
-            c, v, x, w, b, kb, scales=s[0] if s else None, **kw),
-        *ell, *layer, shape((FUSED_STEPS,), jnp.int32), sc)
-
-
-@pytest.mark.parametrize("precision", PRECISIONS)
-@pytest.mark.parametrize("kind", ["dense", "sparse"])
-def test_fused_kernels_compile_at_pubmed_rows(shape, kind, precision):
-    assert "tpu_custom_call" in _fused(shape, kind, precision, ROWS)
-
-
-def test_largest_launch_fused_viable_admits_compiles(shape):
-    """The VMEM gate is sound at its edge: the largest row count
-    ``fused_viable`` admits at pubmed's input width compiles."""
-    from repro.plan import cost
-
-    rows = BLOCK
-    while cost.fused_viable(_Stats(rows + BLOCK), F_IN):
-        rows += BLOCK
-    assert rows > BLOCK
-    assert not cost.fused_viable(_Stats(ROWS), F_IN)   # pubmed does not fit
-    assert "tpu_custom_call" in _fused(shape, "dense", "f32", rows)
-
-
-class _Stats:
-    """The two ``GraphStats`` fields ``fused_viable`` reads."""
-
-    def __init__(self, rows):
-        self.padded_rows, self.tau = rows, TAU
-
-
-@pytest.mark.parametrize("fused", [False, True])
-def test_steps_name_their_kernels_and_scopes(shape, fused):
+def test_steps_name_their_kernels_and_scopes(shape):
     """The full-graph step (block-skipping grid) and a served bucket step
     (masked dense grid) carry the kernels' names and the steps' scopes
     into the compiled HLO, where the profiler's op names come from."""
-    import dataclasses
-
     import jax
     import numpy as np
 
@@ -259,22 +207,17 @@ def test_steps_name_their_kernels_and_scopes(shape, fused):
     feats = np.zeros((spec.nodes, spec.feature_dim), np.float32)
     cfg = GCNConfig(in_dim=32, hidden_dim=16, out_dim=5,
                     spmm_impl="pallas_sparse")
-    engine = ServeEngine(adj, feats, cfg, interpret=False, fused=fused,
-                         fanout=4, max_seeds=4, max_batch=2,
-                         base_bucket_nodes=128)
+    engine = ServeEngine(adj, feats, cfg, interpret=False, fanout=4,
+                         max_seeds=4, max_batch=2, base_bucket_nodes=128)
     on = lambda tree: jax.tree.map(  # noqa: E731
         lambda a: shape(a.shape, a.dtype), tree)
-    prefix = "flexvector_fused_" if fused else "flexvector_"
 
-    full = engine.registry.forward_step(
-        adj, cfg, plan=dataclasses.replace(engine.full_plan, fused=fused))
+    full = engine.registry.forward_step(adj, cfg, plan=engine.full_plan)
     text = full.lower(on(engine.params), shape(feats.shape, feats.dtype)
                       ).compile().as_text()
-    assert f"%{prefix}sparse_grid" in text
+    assert "%flexvector_sparse_grid_rows" in text
     assert "gcn_full_step/" in text
-    if not fused:
-        assert "%flexvector_sparse_grid_rows" in text
-        assert "gcn_full_step/combine/" in text
+    assert "gcn_full_step/combine/" in text
     assert "/aggregate/" in text and "/fold/" in text
 
     batcher = engine.batcher
@@ -282,7 +225,7 @@ def test_steps_name_their_kernels_and_scopes(shape, fused):
     avals = batcher._avals(engine.params, bucket, 2, spec.feature_dim)
     text = _compile(batcher._make_forward(bucket, spec.feature_dim),
                     *on(avals))
-    assert f"%{prefix}dense_grid" in text
+    assert "%flexvector_dense_grid" in text
     assert "gcn_bucket_step/" in text
     assert "/aggregate/" in text and "/fold/" in text
 
