@@ -66,8 +66,9 @@ class RegistryStats:
     mem_hits: int = 0
     disk_hits: int = 0
     builds: int = 0          # preprocessing actually ran
-    # The built full steps' sparse-grid launches, by where each keeps its
-    # dense operand (``FullStep.residency``).
+    # The built full steps' sparse-grid launches, by the body each runs
+    # (``FullStep.residency``: gather, gather_bf16 or a visit loop's
+    # residency).
     residency: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
@@ -253,8 +254,9 @@ class FullStep:
     """``step(params, features) -> logits``: a jitted full-graph forward
     ``jitted(params, features, arrays)`` with the graph's device arrays
     bound (``None`` where the step plans on the host graph).
-    ``residency`` holds each layer's sparse-grid launch's
-    ``sparse_grid_residency`` (empty where the step runs none)."""
+    ``residency`` holds the body each layer's sparse-grid launch runs,
+    ``sparse_grid_launch``'s name for it (empty where the step runs
+    none)."""
 
     jitted: Callable
     arrays: Optional[GraphArrays]
@@ -276,23 +278,26 @@ class FullStep:
 
 def _launch_residency(plan, arrays: GraphArrays, n_layers: int,
                       precision: str) -> Tuple[str, ...]:
-    """Where each layer's sparse-grid launch keeps its dense operand, from
-    the uploaded operands' shapes: every layer's operand has the graph's
-    node rows, in f32 or, under bf16/int8, bf16 (``quant.cast_dense``)."""
+    """The body each layer's sparse-grid launch runs
+    (``sparse_grid_launch``: whether it row-gathers, and where it keeps its
+    dense operand), from the uploaded operands' shapes: every layer's
+    operand has the graph's node rows padded to ``block_k``
+    (``pad_operands``), in f32 or, under bf16/int8, bf16
+    (``quant.cast_dense``)."""
     if plan.resolve(schedulable=True).effective_impl != "pallas_sparse":
         return ()
     import jax.numpy as jnp
 
-    from repro.kernels.flexvector_spmm import sparse_grid_residency
+    from repro.kernels.flexvector_spmm import sparse_grid_launch
 
     prec = precision if precision != "f32" else plan.precision
     k = -(-len(arrays.perm) // plan.block_k) * plan.block_k
-    residency = sparse_grid_residency(
+    launch = sparse_grid_launch(
         k, arrays.cols.shape[1],
         dtype=jnp.float32 if prec == "f32" else jnp.bfloat16,
         out_dtype=plan.out_dtype or jnp.float32, block_rows=plan.block_rows,
         block_k=plan.block_k, block_f=plan.block_f)
-    return (residency,) * n_layers
+    return (launch,) * n_layers
 
 
 def _takes_arrays(plan) -> bool:

@@ -545,23 +545,32 @@ def test_full_step_matches_a_plain_gcn(toy_graph, monkeypatch, resident):
     np.testing.assert_allclose(got, np.asarray(h), rtol=1e-4, atol=1e-4)
 
 
-def test_full_step_records_resident_launches_at_pubmed(tmp_path):
+def test_full_step_records_resident_launches_at_pubmed(tmp_path,
+                                                      monkeypatch):
     """pubmed's f32 slab fits VMEM: the full step's two layer launches
-    read ``resident``, and the registry counts them."""
+    row-gather it and read ``gather``.  Under no VMEM budget citeseer's
+    slab streams through the visit loop instead.  The registry counts
+    both kinds."""
     from repro.graphs.datasets import load_dataset
+    from repro.kernels import flexvector_spmm as fv
 
-    ds = load_dataset("pubmed", with_features=False)
-    cfg = GCNConfig(in_dim=500, hidden_dim=16, out_dim=3,
-                    spmm_impl="pallas_sparse")
     reg = ArtifactRegistry(cache_dir=str(tmp_path))
-    step = reg.forward_step(ds.adj_norm, cfg)
-    assert step.residency == ("resident", "resident")
-    assert reg.stats.residency == {"resident": 2}
+    for name, in_dim, classes, launch in [
+            ("pubmed", 500, 3, "gather"), ("citeseer", 3703, 6, "streamed")]:
+        if launch == "streamed":
+            monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", 0)
+        ds = load_dataset(name, with_features=False)
+        cfg = GCNConfig(in_dim=in_dim, hidden_dim=16, out_dim=classes,
+                        spmm_impl="pallas_sparse")
+        step = reg.forward_step(ds.adj_norm, cfg)
+        assert step.residency == (launch, launch)
+    assert reg.stats.residency == {"gather": 2, "streamed": 2}
 
 
 def test_full_forward_stamps_the_bf16_residency(toy_graph, monkeypatch):
-    """Under a budget between the toy slab's bf16 and f32 footprints the
-    full step's launches keep the slab in VMEM as bf16: the step and the
+    """Under a budget between the toy slab's packed-bf16 and f32
+    footprints the full step's launches keep the slab in VMEM as bf16,
+    packed two rows to a word for the row gather: the step and the
     registry say so, a full forward stamps it on its span, and its logits
     are those of the plain path within bf16 rounding."""
     import jax.numpy as jnp
@@ -576,18 +585,18 @@ def test_full_forward_stamps_the_bf16_residency(toy_graph, monkeypatch):
     need = {r: fv.sparse_grid_vmem_bytes(
         r, -(-SPEC.nodes // 32) * 32, cfg.tau, dtype=jnp.float32,
         out_dtype=jnp.float32, block_rows=32, block_k=32, block_f=32)
-        for r in ("resident", "resident_bf16")}
-    assert need["resident_bf16"] < need["resident"]
-    monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", need["resident_bf16"])
+        for r in ("gather", "gather_bf16")}
+    assert need["gather_bf16"] < need["gather"]
+    monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", need["gather_bf16"])
     engine = ServeEngine(adj_norm, feats, cfg, fanout=None, max_seeds=8,
                          base_bucket_nodes=64)
-    assert engine._full_step.residency == ("resident_bf16",) * 2
-    assert engine.registry.stats.residency == {"resident_bf16": 2}
+    assert engine._full_step.residency == ("gather_bf16",) * 2
+    assert engine.registry.stats.residency == {"gather_bf16": 2}
     trace = Tracer().trace("request")
     with use_span(trace.root):
         got = engine.full_forward()
     (sp,) = trace.find("full_forward")
-    assert sp.attributes["residency"] == ("resident_bf16",) * 2
+    assert sp.attributes["residency"] == ("gather_bf16",) * 2
     want = np.asarray(gcn_forward(engine.params, engine.graph, feats,
                                   _cfg()))
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)

@@ -4,6 +4,8 @@ Runs in interpret mode on CPU (the kernel body executes in Python); on a
 real TPU the same tests exercise the lowered kernel.
 """
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -190,14 +192,89 @@ def _schedule_oracle(cols, vals, dense, starts, kb_ids, br, bk, bf,
     return np.asarray(jnp.concatenate(blocks, axis=0))
 
 
+def _slot_order_oracle(cols, vals, dense, br, scales=None,
+                       bf16_products=False):
+    """The row gather's sums in plain jnp f32: per sub-row, over its ELL
+    slots in order, ``where(col != PAD_COL, v, 0) * dense[col]`` added to
+    +0, where ``v`` is the value widened to f32 and, under int8, times its
+    row block's scale; a pad slot gathers row 0, and a sub-row of pads
+    alone is +0.  With ``bf16_products`` both factors are rounded to bf16
+    first, as the gather compiled for the chip rounds them.  Jitted, as
+    the interpreted kernel is, so the CPU compiler fuses each multiply
+    and add alike on both sides."""
+    from repro.core.sparse_formats import PAD_COL
+    from repro.kernels.flexvector_spmm import _block_scales
+
+    @jax.jit
+    def sums(cols, vals, dense, scales):
+        r, tau = cols.shape
+        rows = dense.astype(jnp.float32)[jnp.maximum(cols, 0)]
+        v = vals.astype(jnp.float32)
+        out = jnp.zeros((r, rows.shape[-1]), jnp.float32)
+        for t in range(tau):
+            w = jnp.where(cols[:, t] != PAD_COL, v[:, t], 0)
+            if scales is not None:
+                w = w * jnp.repeat(_block_scales(scales, r, br), br)
+            g = rows[:, t]
+            if bf16_products:
+                w, g = (a.astype(jnp.bfloat16).astype(jnp.float32)
+                        for a in (w, g))
+            out = out + w[:, None] * g
+        real = (cols != PAD_COL).any(axis=1)
+        return jnp.where(real[:, None], out, 0)
+
+    return np.asarray(sums(jnp.asarray(cols), jnp.asarray(vals),
+                           jnp.asarray(dense), scales))
+
+
+def _run_body(fv, monkeypatch, body, c, v, d, starts, kb, br, bk,
+              scales=None):
+    """The sparse grid on these operands, running ``body``: the streamed
+    visit loop by no VMEM budget, else the row gather the shape rule
+    picks at the default budget (``gather``, or ``gather_bf16`` for a
+    16-bit slab)."""
+    if body == "streamed":
+        monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", 0)
+    blocks = dict(dtype=d.dtype, out_dtype=fv._acc_dtype(d.dtype),
+                  block_rows=br, block_k=bk, block_f=bk)
+    assert fv.sparse_grid_launch(d.shape[0], c.shape[1], **blocks) == body
+    out = np.asarray(fv.spmm_ell_sparse_grid(
+        c, v, d, jnp.asarray(starts), jnp.asarray(kb), block_rows=br,
+        block_k=bk, block_f=bk, interpret=True, scales=scales))
+    monkeypatch.undo()
+    return out
+
+
+def _agree(got, want, precision):
+    """Two summation orders of one product set agree within the kernels'
+    bound against the reference impl."""
+    from tests.test_exec import KERNEL_VS_REFERENCE
+
+    np.testing.assert_allclose(
+        got, want, rtol=0,
+        atol=KERNEL_VS_REFERENCE[precision] * np.abs(want).max())
+
+
+def _integer_operands(vals, k, f, seed):
+    """int8 values and an int8 dense operand, whose sums accumulate in
+    int32, where no gather exists."""
+    vals = np.clip(np.round(np.asarray(vals, np.float32) * 12), -127, 127)
+    dense = np.random.default_rng(seed).integers(-9, 9, (k, f))
+    return vals.astype(np.int8), jnp.asarray(dense, jnp.int8)
+
+
 @pytest.mark.parametrize("case", ["empty_row_blocks", "shard_padding"])
-@pytest.mark.parametrize("precision", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("precision", ["f32", "bf16", "int8", "integer"])
 def test_resident_and_streamed_launches_bitwise_equal(precision, case,
                                                       monkeypatch):
-    """Whether the dense slab is resident in VMEM or each visit's tile is
-    copied in, the sparse grid visits the same tiles in the same order
-    through the same expansion and dot: its sub-row products are bitwise
-    equal, to each other and to the schedule done one visit at a time."""
+    """Each body of the sparse grid equals its own summation order's oracle
+    bitwise: the visit loop, which a slab past the budget streams, visits
+    the tiles in the list's order through the same expansion and dot (the
+    schedule done one visit at a time); the row gather a resident float
+    slab takes adds each sub-row's slots in slot order (a 16-bit slab
+    packed two rows to a word).  The two orders agree within the kernels'
+    bound against the reference impl.  Under an integer accumulator the
+    visit loop runs whatever the slab's size, to the exact sums."""
     from repro.exec import quant
     from repro.kernels import flexvector_spmm as fv
 
@@ -206,49 +283,67 @@ def test_resident_and_streamed_launches_bitwise_equal(precision, case,
     for cols, vals, starts, kb, k in _parity_operands(case, br, bk, f):
         dense = np.random.default_rng(k).standard_normal((k, f))
         scales = None
-        if precision == "int8":
+        if precision == "integer":
+            vals, dense = _integer_operands(vals, k, f, k)
+        elif precision == "int8":
             vals, scales = quant.quantize_values(vals, br)
             scales = jnp.asarray(scales)
         elif precision == "bf16":
             vals = jnp.asarray(vals, jnp.bfloat16)
-        dense = quant.cast_dense(jnp.asarray(dense, jnp.float32), precision)
+        if precision != "integer":
+            dense = quant.cast_dense(jnp.asarray(dense, jnp.float32),
+                                     precision)
         c, v, d, _ = pad_operands(cols, vals, dense, br, bk, bk)
-        run = lambda: np.asarray(fv.spmm_ell_sparse_grid(  # noqa: E731
-            c, v, d, jnp.asarray(starts), jnp.asarray(kb), block_rows=br,
-            block_k=bk, block_f=bk, interpret=True, scales=scales))
-        resident = run()
-        monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", 0)
-        streamed = run()
-        monkeypatch.undo()
+        run = functools.partial(_run_body, fv, monkeypatch, c=c, v=v, d=d,
+                                starts=starts, kb=kb, br=br, bk=bk,
+                                scales=scales)
+        streamed = run("streamed")
         assert np.abs(streamed).max() > 0
-        np.testing.assert_array_equal(resident, streamed)
-        np.testing.assert_array_equal(resident, _schedule_oracle(
+        np.testing.assert_array_equal(streamed, _schedule_oracle(
             c, v, d, starts, kb, br, bk, bk, scales))
+        if precision == "integer":
+            assert fv.sparse_grid_launch(
+                d.shape[0], c.shape[1], dtype=d.dtype, out_dtype=jnp.int32,
+                block_rows=br, block_k=bk, block_f=bk) == "streamed"
+            continue
+        gathered = run("gather" if precision == "f32" else "gather_bf16")
+        np.testing.assert_array_equal(gathered, _slot_order_oracle(
+            c, v, d, br, scales))
+        _agree(gathered, streamed, precision)
 
 
 @pytest.mark.parametrize("visits_per_iter", [1, 2, 3])
 def test_resident_launch_keeps_the_visit_order(visits_per_iter,
                                                monkeypatch):
-    """However many visits one loop iteration runs, resident or streamed,
-    their products are added in the visit list's order: the result is
+    """However many visits one loop iteration runs, their products are
+    added in the visit list's order, float or integer: the result is
     bitwise equal to the schedule done one visit at a time, on row blocks
-    with odd numbers of visits and on lists longer than one window."""
+    with odd numbers of visits and on lists longer than one window.  The
+    row gather on the same float operands is its slot-order oracle
+    bitwise, and agrees with the visit loop within the kernels' bound."""
     from repro.kernels import flexvector_spmm as fv
 
     res, dense = _problem(96, 900, 6, 16, seed=4)
     g = plan_kernel_grid(res.ell, 16, block_rows=16, block_k=16, block_f=16)
     assert (np.diff(g.starts) % 2 == 1).any()
-    c, v, d, _ = pad_operands(res.ell.cols, res.ell.vals,
-                              jnp.asarray(dense), 16, 16, 16)
-    want = _schedule_oracle(c, v, d, g.starts, g.kb_ids, 16, 16, 16)
-    monkeypatch.setattr(fv, "_VISITS_PER_ITER", visits_per_iter)
-    monkeypatch.setattr(fv, "_KB_ALIGN", 8)
-    for budget in (fv.RESIDENT_VMEM_BUDGET, 0):
-        monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", budget)
-        got = np.asarray(fv.spmm_ell_sparse_grid(
-            c, v, d, jnp.asarray(g.starts), jnp.asarray(g.kb_ids),
-            block_rows=16, block_k=16, block_f=16, interpret=True))
-        np.testing.assert_array_equal(got, want)
+
+    def run(body, vals, dense):
+        c, v, d, _ = pad_operands(res.ell.cols, vals, dense, 16, 16, 16)
+        monkeypatch.setattr(fv, "_VISITS_PER_ITER", visits_per_iter)
+        monkeypatch.setattr(fv, "_KB_ALIGN", 8)
+        got = _run_body(fv, monkeypatch, body, c, v, d, g.starts, g.kb_ids,
+                        16, 16)
+        return got, _schedule_oracle(c, v, d, g.starts, g.kb_ids, 16, 16, 16)
+
+    streamed, want = run("streamed", res.ell.vals, jnp.asarray(dense))
+    np.testing.assert_array_equal(streamed, want)
+    np.testing.assert_array_equal(*run("streamed", *_integer_operands(
+        res.ell.vals, dense.shape[0], 16, 4)))
+    gathered, _ = run("gather", res.ell.vals, jnp.asarray(dense))
+    c, v, d, _ = pad_operands(res.ell.cols, res.ell.vals, jnp.asarray(dense),
+                              16, 16, 16)
+    np.testing.assert_array_equal(gathered, _slot_order_oracle(c, v, d, 16))
+    _agree(gathered, want, "f32")
 
 
 @pytest.mark.parametrize("resident", [True, False])
@@ -278,30 +373,27 @@ def test_sparse_grid_matches_the_reference_impl(precision, resident,
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("visits_per_iter", [1, 2, 3])
+@pytest.mark.parametrize("fill_tiles", [1, 2, 3])
 def test_bf16_slab_launch_is_the_launch_on_the_rounded_operand(
-        visits_per_iter, monkeypatch):
-    """An f32 slab that fits the budget only rounded to bf16 is filled
-    into VMEM in chunks (the last one clamped to the slab's end) and
-    rounded on the way: the launch is bitwise the ``resident`` and
-    ``streamed`` launches, and the one-visit-at-a-time schedule, run on
-    the bf16-rounded operand, with every product exact as on the CPU.
-    Row blocks with odd numbers of visits, two f-tiles, lists longer than
-    one window."""
+        fill_tiles, monkeypatch):
+    """An f32 slab that fits the budget only rounded to bf16 is row-gathered
+    packed two rows to a word, filled from both halves of the slab a chunk
+    at a time (``fill_tiles`` k-tiles a chunk, the last one clamped to the
+    halves' ends where it overhangs) and rounded on the way.  It is
+    bitwise the 32-bit gather and the slot-order oracle on the
+    bf16-rounded operand, with every product exact as on the CPU, and
+    agrees within the kernels' bound with the visit loop on that operand,
+    itself the one-visit-at-a-time schedule.  Two f-tiles."""
     from repro.kernels import flexvector_spmm as fv
 
     res, dense = _problem(300, 3000, 6, 32, seed=4)
     g = plan_kernel_grid(res.ell, 16, block_rows=16, block_k=16, block_f=16)
-    assert (np.diff(g.starts) % 2 == 1).any()
     c, v, d, _ = pad_operands(res.ell.cols, res.ell.vals,
                               jnp.asarray(dense), 16, 16, 16)
     rounded = d.astype(jnp.bfloat16).astype(jnp.float32)
-    monkeypatch.setattr(fv, "_VISITS_PER_ITER", visits_per_iter)
-    monkeypatch.setattr(fv, "_KB_ALIGN", 8)
-    monkeypatch.setattr(fv, "_FILL_TILES", 3)
+    monkeypatch.setattr(fv, "_FILL_TILES", fill_tiles)
     k = d.shape[0]
-    assert len(g.kb_ids) > fv._round_up(k // 16 + 8 - 1, 8)   # one window
-    assert k % (3 * 16)                # the last chunk is clamped
+    assert ((k // 2) % (fill_tiles * 16 // 2) > 0) == (fill_tiles > 1)
     blocks = dict(dtype=jnp.float32, out_dtype=jnp.float32, block_rows=16,
                   block_k=16, block_f=16)
 
@@ -311,39 +403,154 @@ def test_bf16_slab_launch_is_the_launch_on_the_rounded_operand(
             c, v, dense, jnp.asarray(g.starts), jnp.asarray(g.kb_ids),
             block_rows=16, block_k=16, block_f=16, interpret=True))
 
-    bf16_need = fv.sparse_grid_vmem_bytes("resident_bf16", k, 6, **blocks)
-    assert bf16_need < fv.sparse_grid_vmem_bytes("resident", k, 6, **blocks)
-    monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", bf16_need)
-    assert fv.sparse_grid_residency(k, 6, **blocks) == "resident_bf16"
-    got = run(d, bf16_need)
-    assert np.abs(got).max() > 0
-    np.testing.assert_array_equal(got, run(rounded, 2**30))
-    np.testing.assert_array_equal(got, run(rounded, 0))
-    np.testing.assert_array_equal(got, _schedule_oracle(
+    packed_need = fv.sparse_grid_vmem_bytes("gather_bf16", k, 6, **blocks)
+    assert packed_need < fv.sparse_grid_vmem_bytes("gather", k, 6, **blocks)
+    monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", packed_need)
+    assert fv.sparse_grid_launch(k, 6, **blocks) == "gather_bf16"
+    packed = run(d, packed_need)
+    assert np.abs(packed).max() > 0
+    np.testing.assert_array_equal(packed, run(rounded, 2**30))
+    np.testing.assert_array_equal(packed, _slot_order_oracle(c, v, rounded,
+                                                             16))
+    visit_loop = run(rounded, 0)
+    np.testing.assert_array_equal(visit_loop, _schedule_oracle(
         c, v, rounded, g.starts, g.kb_ids, 16, 16, 16))
+    _agree(packed, visit_loop, "f32")
 
 
-@pytest.mark.parametrize("nodes,residency,need", [
-    (19_717, "resident", 10_158_080 + 1_179_648),           # pubmed
-    (232_965, "resident_bf16", 59_670_528 + 1_048_576 + 1_179_648),  # reddit
-    (2_449_029, "streamed", 524_288 + 1_179_648),       # ogbn-products
+@pytest.mark.parametrize("launch,dtype", [
+    ("gather", jnp.float32), ("gather_bf16", jnp.float32),
+    ("gather_bf16", jnp.bfloat16)])
+def test_gather_reads_the_pair_packing_edges_and_pads(launch, dtype,
+                                                      monkeypatch):
+    """Columns 0, K/2 - 1, K/2 and K - 1, the rows at either edge of each
+    half of a packed slab, come back exactly (the bf16-rounded slab's
+    rows); a sub-row of pad slots sums to +0 though its pads gather a row
+    of negatives; and the launch agrees with the visit loop."""
+    from repro.core.sparse_formats import PAD_COL
+    from repro.kernels import flexvector_spmm as fv
+
+    br = bk = 16
+    k, r, tau = 256, 32, 4
+    edges = [0, k // 2 - 1, k // 2, k - 1]
+    rng = np.random.default_rng(3)
+    cols = np.full((r, tau), PAD_COL, np.int32)
+    cols[0], cols[1], cols[17] = edges, edges[::-1], [edges[2], PAD_COL,
+                                                      5, edges[1]]
+    cols[2:5, 0] = edges[1:]
+    vals = np.where(cols != PAD_COL, rng.standard_normal((r, tau)), 0)
+    dense = rng.standard_normal((k, 2 * br))
+    dense[0] = -np.abs(dense[0]) - 1
+    c, v = jnp.asarray(cols), jnp.asarray(vals, jnp.float32)
+    d = jnp.asarray(dense, dtype)
+    starts = np.array([0, k // bk, 2 * k // bk], np.int32)
+    kb = np.tile(np.arange(k // bk, dtype=np.int32), 2)
+    blocks = dict(dtype=d.dtype, out_dtype=jnp.float32, block_rows=br,
+                  block_k=bk, block_f=br)
+    monkeypatch.setattr(fv, "_FILL_TILES", 1)
+    if launch == "gather_bf16" and dtype == jnp.float32:
+        monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET",
+                            fv.sparse_grid_vmem_bytes("gather_bf16", k, tau,
+                                                      **blocks))
+    assert fv.sparse_grid_launch(k, tau, **blocks) == launch
+    got = np.asarray(fv.spmm_ell_sparse_grid(
+        c, v, d, jnp.asarray(starts), jnp.asarray(kb), block_rows=br,
+        block_k=bk, block_f=br, interpret=True))
+    rounded = d if launch == "gather" else d.astype(jnp.bfloat16)
+    np.testing.assert_array_equal(got, _slot_order_oracle(c, v, rounded, br))
+    single = np.asarray(rounded, np.float32)
+    for i, col in zip(range(2, 5), edges[1:]):
+        np.testing.assert_array_equal(got[i], vals[i, 0].astype(np.float32)
+                                      * single[col])
+    pads = np.all(cols == PAD_COL, axis=1)
+    assert pads.sum() > 1
+    assert (got[pads] == 0).all() and not np.signbit(got[pads]).any()
+    monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", 0)
+    visit_loop = np.asarray(fv.spmm_ell_sparse_grid(
+        c, v, d if dtype == jnp.bfloat16 else rounded.astype(jnp.float32),
+        jnp.asarray(starts), jnp.asarray(kb), block_rows=br, block_k=bk,
+        block_f=br, interpret=True))
+    _agree(got, visit_loop, "f32")
+
+
+@pytest.mark.parametrize("precision", ["f32", "int8"])
+def test_gather_multiplies_bf16_operands_on_the_chip(precision,
+                                                     monkeypatch):
+    """Under the chip's precision rule (``_mxu_factor`` as compiled: both
+    factors of each product rounded to bf16, the MXU's operand form at the
+    default precision), ``spmm_ell_sparse_grid`` run interpreted
+    row-gathers to the slot-order oracle of rounded factors bitwise, and
+    away from the f32 products it forms interpreted.  At f32 it is the
+    gather of f32 products on pre-rounded operands, and the visit loop
+    under the same rule, the schedule on those operands, agrees with it
+    within the kernels' bound."""
+    from repro.exec import quant
+    from repro.kernels import flexvector_spmm as fv
+
+    br = bk = 16
+    res, dense = _problem(96, 900, 6, 32, seed=8)
+    g = plan_kernel_grid(res.ell, 32, block_rows=br, block_k=bk,
+                         block_f=bk)
+    vals, scales = res.ell.vals, None
+    if precision == "int8":
+        vals, scales = quant.quantize_values(vals, br)
+        scales = jnp.asarray(scales)
+    c, v, d, _ = pad_operands(res.ell.cols, vals, jnp.asarray(dense), br, bk,
+                              bk)
+    factor = fv._mxu_factor
+
+    def run(v, d, *, on_chip, budget=None):
+        if on_chip:
+            monkeypatch.setattr(fv, "_mxu_factor",
+                                lambda x, interpret: factor(x, False))
+        if budget is not None:
+            monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", budget)
+        out = np.asarray(fv.spmm_ell_sparse_grid(
+            c, v, d, jnp.asarray(g.starts), jnp.asarray(g.kb_ids),
+            block_rows=br, block_k=bk, block_f=bk, interpret=True,
+            scales=scales))
+        monkeypatch.undo()
+        return out
+
+    assert fv.sparse_grid_launch(d.shape[0], c.shape[1], dtype=d.dtype,
+                                 out_dtype=jnp.float32, block_rows=br,
+                                 block_k=bk, block_f=bk) == "gather"
+    got = run(v, d, on_chip=True)
+    np.testing.assert_array_equal(got, _slot_order_oracle(
+        c, v, d, br, scales, bf16_products=True))
+    assert not np.array_equal(got, run(v, d, on_chip=False))
+    if precision == "f32":
+        rv, rd = (a.astype(jnp.bfloat16).astype(jnp.float32) for a in (v, d))
+        np.testing.assert_array_equal(got, run(rv, rd, on_chip=False))
+        visit_loop = run(v, d, on_chip=True, budget=0)
+        np.testing.assert_array_equal(visit_loop, _schedule_oracle(
+            c, rv, rd, g.starts, g.kb_ids, br, bk, bk))
+        _agree(got, visit_loop, "f32")
+
+
+@pytest.mark.parametrize("nodes,dtype,launch,need", [
+    (19_717, jnp.float32, "gather", 10_158_080 + 786_432),       # pubmed
+    (3_327, jnp.float32, "gather", 1_703_936 + 786_432),         # citeseer
+    (232_965, jnp.float32, "gather_bf16",                        # reddit
+     59_670_528 + 1_048_576 + 786_432),
+    (19_717, jnp.bfloat16, "gather_bf16", 5_079_040 + 524_288 + 786_432),
+    (2_449_029, jnp.float32, "streamed", 524_288 + 1_179_648),   # products
+    (19_717, jnp.int8, "streamed", 131_072 + 1_179_648),
 ])
-def test_sparse_grid_launch_follows_the_dense_slab(nodes, residency, need):
-    """pubmed's f32 dense slab fits the VMEM budget and runs resident;
-    reddit's 119 MB slab fits only rounded to bf16 (60 MB, with two 512
-    KiB staging chunks); a slab past both streams its tiles.  The bytes
-    are the module docstring's figures."""
+def test_sparse_grid_launch_follows_the_dense_slab(nodes, dtype, launch,
+                                                   need):
+    """Under a float accumulator every slab that fits the VMEM budget is
+    row-gathered: pubmed's and citeseer's f32 slabs where they lie,
+    reddit's 119 MB one only rounded to bf16 and packed (60 MB, with two
+    pairs of 256 KiB staging chunks), as a bf16 operand is.  A slab past
+    the budget (ogbn-products), or any under an integer accumulator, has
+    the visit loop stream its tiles.  The bytes are the module
+    docstring's figures."""
     from repro.kernels import flexvector_spmm as fv
 
     k = -(-nodes // 128) * 128
-    blocks = dict(dtype=jnp.float32, out_dtype=jnp.float32, block_rows=128,
-                  block_k=128, block_f=128)
-    assert fv.sparse_grid_residency(k, 6, **blocks) == residency
-    assert fv.sparse_grid_vmem_bytes(residency, k, 6, **blocks) == need
+    blocks = dict(dtype=dtype, out_dtype=fv._acc_dtype(dtype),
+                  block_rows=128, block_k=128, block_f=128)
+    assert fv.sparse_grid_launch(k, 6, **blocks) == launch
+    assert fv.sparse_grid_vmem_bytes(launch, k, 6, **blocks) == need
     assert need <= fv.RESIDENT_VMEM_BUDGET
-    # an integer accumulator, or a slab already bf16, never takes the
-    # bf16 residency
-    assert fv.sparse_grid_residency(
-        k, 6, **dict(blocks, out_dtype=jnp.int32)) != "resident_bf16"
-    assert fv.sparse_grid_residency(
-        k, 6, **dict(blocks, dtype=jnp.bfloat16)) != "resident_bf16"
