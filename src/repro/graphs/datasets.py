@@ -35,7 +35,9 @@ DATASETS: Dict[str, DatasetSpec] = {
     "citeseer": DatasetSpec("citeseer", 3_327, 4_732, 3_703, 6),
     "pubmed": DatasetSpec("pubmed", 19_717, 44_338, 500, 3),
     "reddit": DatasetSpec("reddit", 232_965, 11_606_919, 602, 41),
-    "yelp": DatasetSpec("yelp", 716_847, 13_954_819, 300, 100),
+    # GraphSAINT's undirected count (arXiv:1907.04931, Table 1), as for
+    # the others; 13,954,819 is the same edges counted in each direction.
+    "yelp": DatasetSpec("yelp", 716_847, 6_977_410, 300, 100),
 }
 
 

@@ -67,9 +67,10 @@ class RegistryStats:
     disk_hits: int = 0
     builds: int = 0          # preprocessing actually ran
     # The built full steps' sparse-grid launches, by the body each runs
-    # (``FullStep.residency``: gather, gather_bf16 or a visit loop's
-    # residency).
+    # (``FullStep.residency``: gather, gather_bf16 or streamed), and
+    # their visits (``FullStep.visits``) summed by the same body.
     residency: Dict[str, int] = dataclasses.field(default_factory=dict)
+    visits: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 def graph_key(adj: CSRMatrix, cfg: GCNConfig) -> str:
@@ -172,7 +173,7 @@ class ArtifactRegistry:
             step_plan = plan_pipeline(cfg, graph.pre.ell,
                                       precision=precision,
                                       interpret=interpret)
-        arrays, residency = None, ()
+        arrays, residency, visits = None, (), ()
         if _takes_arrays(step_plan):
             from repro.exec import plan_for_config
 
@@ -180,8 +181,10 @@ class ArtifactRegistry:
             arrays = self._upload(gkey, graph, static)
             residency = _launch_residency(static, arrays, cfg.n_layers,
                                           precision)
-            for r in residency:
+            visits = (int(arrays.grid.kb_ids.shape[0]),) * len(residency)
+            for r, v in zip(residency, visits):
                 self.stats.residency[r] = self.stats.residency.get(r, 0) + 1
+                self.stats.visits[r] = self.stats.visits.get(r, 0) + v
 
         def gcn_full_step(params, feats, arrays):
             with jax.named_scope("gcn_full_step"):
@@ -189,7 +192,7 @@ class ArtifactRegistry:
                                    feats, cfg, plan=step_plan,
                                    precision=precision)
 
-        fwd = FullStep(jax.jit(gcn_full_step), arrays, residency)
+        fwd = FullStep(jax.jit(gcn_full_step), arrays, residency, visits)
         self._forwards[key] = fwd
         return fwd
 
@@ -255,12 +258,14 @@ class FullStep:
     ``jitted(params, features, arrays)`` with the graph's device arrays
     bound (``None`` where the step plans on the host graph).
     ``residency`` holds the body each layer's sparse-grid launch runs,
-    ``sparse_grid_launch``'s name for it (empty where the step runs
-    none)."""
+    ``sparse_grid_launch``'s name for it, and ``visits`` each launch's
+    visits, the planned grid's (row block, k-tile) list before the
+    kernel pads it (both empty where the step runs no sparse grid)."""
 
     jitted: Callable
     arrays: Optional[GraphArrays]
     residency: Tuple[str, ...] = ()
+    visits: Tuple[int, ...] = ()
 
     def __call__(self, params, features):
         return self.jitted(params, features, self.arrays)

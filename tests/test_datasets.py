@@ -18,6 +18,20 @@ def test_table3_statistics(name):
     assert ds.features.shape == (spec.nodes, spec.feature_dim)
 
 
+def test_yelp_edge_count_is_undirected():
+    """yelp's ``edges`` is an undirected count, as every other graph's is
+    (GraphSAINT's 6,977,410, not the 13,954,819 of both directions): its
+    symmetric adjacency holds about twice that many nonzeros.  Adjacency
+    only: yelp's features alone are 860 MB."""
+    spec = DATASETS["yelp"]
+    assert (spec.nodes, spec.edges, spec.feature_dim, spec.classes) == (
+        716_847, 6_977_410, 300, 100)
+    adj = synthesize_adjacency(spec, seed=0)
+    assert adj.rows == spec.nodes
+    edges = adj.nnz / 2
+    assert abs(edges - spec.edges) / spec.edges < 0.25
+
+
 def test_power_law_degree_shape():
     """A small set of supernodes, a long tail (Fig 2)."""
     ds = load_dataset("pubmed", with_features=False)

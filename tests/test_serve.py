@@ -512,6 +512,22 @@ def test_full_step_carries_no_graph_constant(tmp_path):
     assert sum(_constant_bytes(closed)) > 2**20   # the check sees them
 
 
+def _plain_gcn(adj_norm, feats, params):
+    """A plain f32 jax.numpy GCN over the dense adjacency, every product
+    at the highest precision."""
+    import jax.numpy as jnp
+
+    a = jnp.asarray(adj_norm.to_scipy().toarray())
+    with jax.default_matmul_precision("highest"):
+        h = jnp.asarray(feats)
+        for i in range(len(params)):
+            p = params[f"layer_{i}"]
+            h = a @ (h @ p["w"] + p["b"])
+            if i < len(params) - 1:
+                h = jax.nn.relu(h)
+    return np.asarray(h)
+
+
 @pytest.mark.parametrize("resident", [True, False])
 def test_full_step_matches_a_plain_gcn(toy_graph, monkeypatch, resident):
     """The full-graph step through the sparse grid, its slab resident or
@@ -534,15 +550,94 @@ def test_full_step_matches_a_plain_gcn(toy_graph, monkeypatch, resident):
     plan = plan_for_config(cfg, interpret=True).resolve(schedulable=True)
     got = np.asarray(ArtifactRegistry().forward_step(
         adj_norm, cfg, plan=plan)(params, feats))
-    a = jnp.asarray(adj_norm.to_scipy().toarray())
-    with jax.default_matmul_precision("highest"):
-        h = jnp.asarray(feats)
-        for i in range(cfg.n_layers):
-            p = params[f"layer_{i}"]
-            h = a @ (h @ p["w"] + p["b"])
-            if i < cfg.n_layers - 1:
-                h = jax.nn.relu(h)
-    np.testing.assert_allclose(got, np.asarray(h), rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(got, _plain_gcn(adj_norm, feats, params),
+                               rtol=1e-4, atol=1e-4)
+
+
+def _grid_visits(cols, block_rows=128, block_k=128):
+    """The sparse grid's visits counted from the ELL alone: each row
+    block's distinct k-tiles, and one visit for a row block with none."""
+    rows, slots = np.nonzero(cols != PAD_COL)
+    rb = rows // block_rows
+    pairs = np.unique(np.stack([rb, cols[rows, slots] // block_k], axis=1),
+                      axis=0)
+    n_rb = -(-cols.shape[0] // block_rows)
+    return len(pairs) + n_rb - len(np.unique(rb))
+
+
+# Yelp's widths and average degree (GraphSAINT: 716,847 nodes, 6,977,410
+# undirected edges, about 21 nonzeros a row with self loops, 300 features,
+# 100 classes) on a few thousand nodes.
+YELP_SHAPED = DatasetSpec("yelp-shaped", nodes=3_000, edges=29_200,
+                          feature_dim=300, classes=100)
+
+
+def test_full_forward_streams_a_yelp_shaped_graph(monkeypatch):
+    """A yelp-shaped graph through ``ServeEngine.full_forward`` with its
+    slab past the VMEM budget (here none, as yelp's 717k-row slab is past
+    the real one): both layers' launches stream their tiles through the
+    visit loop, the step, the registry and the forward's span count their
+    visits, and the logits are a plain f32 jax.numpy GCN's on seeded
+    weights and biases.
+
+    Tolerance: interpreted, the kernel multiplies f32 exactly (its bf16
+    rounding is the chip's rule), so the two differ only in the order of
+    f32 adds over 300-term combinations and about 21-term aggregations
+    (4e-7 of the largest logit); 1e-4 of it allows that, where the
+    launches' factors rounded to bf16 (the chip's rule) put it 6e-4 off."""
+    import jax.numpy as jnp
+
+    from repro.kernels import flexvector_spmm as fv
+    from repro.obs import Tracer, use_span
+
+    adj_norm = gcn_normalize(synthesize_adjacency(YELP_SHAPED, seed=0))
+    assert 19 < (adj_norm.nnz / YELP_SHAPED.nodes) < 23
+    rng = np.random.default_rng(5)
+    feats = rng.standard_normal(
+        (YELP_SHAPED.nodes, YELP_SHAPED.feature_dim)).astype(np.float32)
+    cfg = GCNConfig(in_dim=300, hidden_dim=16, out_dim=100,
+                    spmm_impl="pallas_sparse")
+    params = init_params(cfg, jax.random.PRNGKey(5))
+    for layer in params.values():
+        layer["b"] = jnp.asarray(rng.normal(0, 0.1, layer["b"].shape),
+                                 jnp.float32)
+    monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", 0)
+    engine = ServeEngine(adj_norm, feats, cfg, params=params, fanout=None,
+                         max_seeds=8, base_bucket_nodes=256)
+    visits = _grid_visits(engine.graph.pre.ell.cols)
+    assert engine._full_step.residency == ("streamed",) * 2
+    assert engine._full_step.visits == (visits,) * 2
+    assert engine.registry.stats.visits == {"streamed": 2 * visits}
+    trace = Tracer().trace("request")
+    with use_span(trace.root):
+        got = engine.full_forward()
+    (sp,) = trace.find("full_forward")
+    assert sp.attributes["residency"] == ("streamed",) * 2
+    assert sp.attributes["visits"] == (visits,) * 2
+    want = _plain_gcn(adj_norm, feats, params)
+    np.testing.assert_allclose(got, want, rtol=1e-4,
+                               atol=1e-4 * np.abs(want).max())
+
+
+def test_full_step_counts_the_visits_of_each_launch(toy_graph, tmp_path,
+                                                    monkeypatch):
+    """A full step records each layer launch's visits, the planned grid's
+    list before the kernel pads it, for a gathered launch and a streamed
+    one alike, and the registry sums them by body."""
+    from repro.kernels import flexvector_spmm as fv
+
+    adj_norm, _ = toy_graph
+    reg = ArtifactRegistry(cache_dir=str(tmp_path))
+    for hidden, launch in [(8, "gather"), (16, "streamed")]:
+        if launch == "streamed":
+            monkeypatch.setattr(fv, "RESIDENT_VMEM_BUDGET", 0)
+        cfg = _cfg(hidden_dim=hidden, spmm_impl="pallas_sparse")
+        step = reg.forward_step(adj_norm, cfg)
+        visits = _grid_visits(reg.get_or_build(adj_norm, cfg).pre.ell.cols)
+        assert visits == len(step.arrays.grid.kb_ids)
+        assert step.residency == (launch, launch)
+        assert step.visits == (visits, visits)
+    assert reg.stats.visits == {"gather": 2 * visits, "streamed": 2 * visits}
 
 
 def test_full_step_records_resident_launches_at_pubmed(tmp_path,

@@ -10,7 +10,9 @@ padded to 19,840, 500 input features, 128-wide feature tiles, and the
 at reddit's: 4,205,568 rows, 233,088 dense rows, 17,685,868 visits over
 32,856 row blocks.  Both graphs' dense slabs fit VMEM, so the sparse
 grid row-gathers them; its visit loop, which streams a slab past the
-budget, is compiled at both sizes too.
+budget, is compiled at both sizes too, and at yelp's: 3,046,016 rows,
+716,928 dense rows, 11,230,361 visits over 23,797 row blocks, a slab
+that only streams.
 
 The topology is described inside a fixture, never at import, so only
 the test worker that runs this file loads the TPU compiler; the
@@ -26,6 +28,8 @@ ROWS, TAU, K, K_REAL, F, F_IN = 25_856, 6, 19_840, 19_717, 128, 500
 VISITS, BLOCK = 14_281, 128
 # reddit at Table III size (graph seed 0, tau 6, 128-row blocks)
 REDDIT_ROWS, REDDIT_K, REDDIT_VISITS = 4_205_568, 233_088, 17_685_868
+# yelp at its published size, as the chip's host preprocesses it
+YELP_ROWS, YELP_K, YELP_VISITS = 3_046_016, 716_928, 11_230_361
 PRECISIONS = ("f32", "bf16", "int8")
 
 
@@ -163,6 +167,17 @@ def test_sparse_grid_compiles_at_reddit_size(shape, precision, launch,
         "flexvector_sparse_grid_rows")
     assert f"%{name}" in _sparse_grid(shape, precision, k=REDDIT_K,
                                       rows=REDDIT_ROWS, visits=REDDIT_VISITS)
+
+
+@pytest.mark.parametrize("precision", PRECISIONS)
+def test_sparse_grid_streams_at_yelp_size(shape, precision):
+    """At yelp's size the dense slab (351 MiB as f32, 177 MiB as bf16)
+    is past the VMEM budget at every precision, with no budget lowered:
+    the visit loop streams each visit's tile from HBM, its 23,798 run
+    offsets and two windows of the visit list in SMEM, and compiles."""
+    assert _launch(precision, k=YELP_K) == "streamed"
+    assert "%flexvector_sparse_grid_rows" in _sparse_grid(
+        shape, precision, k=YELP_K, rows=YELP_ROWS, visits=YELP_VISITS)
 
 
 def test_a_16_bit_slab_is_gathered_as_32_bit_words(shape):
